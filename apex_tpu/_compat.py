@@ -1,91 +1,47 @@
-"""Version-tolerance shims for JAX API drift.
+"""The three JAX spellings the package routes through one place.
 
-The package pins no exact jax version; the APIs it leans on have moved
-across the releases we must run under:
+Written for the one installation there is (jax 0.9): Pallas TPU
+compiler params are ``pltpu.CompilerParams``, ``shard_map`` is
+``jax.shard_map`` with ``check_vma``, and the bound-axis size is
+``jax.lax.axis_size``. The names stay because every kernel and every
+``shard_map`` call site imports them from here — the next rename is a
+one-file edit — but there is no branch for an API the installed JAX
+lacks: if one of them moves, the failure is an ``AttributeError`` at the
+call, not a silent fallback.
 
-- Pallas TPU compiler params: ``pltpu.TPUCompilerParams`` (jax <= 0.4.x /
-  0.5.x) was renamed ``pltpu.CompilerParams`` (jax >= 0.6). Building
-  either at module import time turns an API rename into an
-  ``AttributeError`` that takes out every importer at *collection* —
-  exactly what broke 13 test files in the seed. ``tpu_compiler_params``
-  resolves the name at call time, so importers stay importable and the
-  failure (if any) surfaces where a kernel is actually launched.
-- ``shard_map``: top-level ``jax.shard_map`` (new) vs
-  ``jax.experimental.shard_map.shard_map`` (0.4.x), with the replication
-  check keyword renamed ``check_rep`` -> ``check_vma`` along the way.
-
-Import-time rule (enforced by ``apex_tpu.lint`` APX001): this module may
-*locate* the symbols lazily but must not construct JAX objects or touch a
-backend at import.
+Import-time rule (enforced by ``apex_tpu.lint`` APX001): nothing here
+constructs a JAX object or touches a backend at import.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 
-@functools.lru_cache(maxsize=None)
-def _compiler_params_cls():
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:  # pragma: no cover - pallas too old/new to support
-        raise AttributeError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams "
-            "nor TPUCompilerParams; unsupported jax version")
-    return cls
-
-
 def tpu_compiler_params(**kwargs: Any):
-    """Build Pallas TPU compiler params under whichever name this jax
-    ships (``CompilerParams`` vs ``TPUCompilerParams``).
+    """``pltpu.CompilerParams(**kwargs)``.
 
     Call it inside the function that issues the ``pallas_call`` — never at
     module level (APX001).
     """
-    return _compiler_params_cls()(**kwargs)
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.CompilerParams(**kwargs)
 
 
 def axis_size(axis_name):
-    """``jax.lax.axis_size`` (new) with a pre-rename fallback.
-
-    Older jax has no ``lax.axis_size``; ``psum`` of a unit Python scalar
-    is statically folded to the axis size by the axis env (an ``int`` at
-    trace time, verified), and raises the same ``NameError`` on an
-    unbound axis — so the two spellings are interchangeable.
-    """
+    """``jax.lax.axis_size(axis_name)`` (a Python int at trace time;
+    ``NameError`` on an unbound axis)."""
     import jax
 
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-@functools.lru_cache(maxsize=None)
-def _shard_map_impl():
-    import jax
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, "check_vma"
-    from jax.experimental.shard_map import shard_map as fn
-
-    return fn, "check_rep"
+    return jax.lax.axis_size(axis_name)
 
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None, **kwargs):
-    """``jax.shard_map`` with the replication-check keyword bridged.
+    """``jax.shard_map`` taking mesh/in_specs/out_specs positionally,
+    as the package's call sites pass them (``jax.shard_map`` itself is
+    keyword-only after ``f``)."""
+    import jax
 
-    Accepts either ``check_vma`` (new spelling) or ``check_rep`` (old) and
-    forwards whichever the underlying jax understands.
-    """
-    impl, check_kw = _shard_map_impl()
-    check = kwargs.pop("check_vma", kwargs.pop("check_rep", None))
-    if check is not None:
-        kwargs[check_kw] = check
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **kwargs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

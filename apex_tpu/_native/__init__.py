@@ -2,18 +2,28 @@
 
 The reference builds ~20 pybind11 extensions via setup.py flags
 (``setup.py:53-522``); here the single host-side shared library is built
-lazily with g++ on first use and cached under ``csrc/build/``. Everything
-has a pure-python fallback, mirroring apex's "Python-only build"
-(reference ``README.md:130-139``): ``lib()`` returns None when no
-compiler is available, and callers degrade gracefully.
+lazily with g++ on first use, on the machine that loads it, under
+``csrc/build/``. The output name carries a hash of the source and the
+compiler flags, so a binary is reused only if it was built from exactly
+this source with exactly these flags, and the flags name no host ISA
+(no ``-march=native``): a build directory that travels with a copied
+tree cannot hand this host code it cannot execute.
+
+Everything has a pure-python fallback, mirroring apex's "Python-only
+build" (reference ``README.md:130-139``): ``lib()`` returns None on a
+machine with no compiler and callers take the numpy path. A build that
+was attempted and failed is different — it warns with the compiler's
+stderr before falling back.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
+import warnings
 
 _lock = threading.Lock()
 _lib = None
@@ -22,31 +32,42 @@ _tried = False
 _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), "csrc", "apex_tpu_native.cpp")
 _BUILD_DIR = os.path.join(os.path.dirname(_SRC), "build")
-_SO = os.path.join(_BUILD_DIR, "libapex_tpu_native.so")
+_FLAGS = ("-O3", "-funroll-loops", "-std=c++17", "-shared", "-fPIC",
+          "-pthread")
+
+
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read() + " ".join(_FLAGS).encode())
+    return os.path.join(
+        _BUILD_DIR, f"libapex_tpu_native-{digest.hexdigest()[:16]}.so")
 
 
 def _build() -> str | None:
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
+    so = _so_path()
+    if os.path.exists(so):
+        return so
     os.makedirs(_BUILD_DIR, exist_ok=True)
     # per-process tmp name: concurrent builders (pytest-xdist, multi-host
     # on a shared FS) each write their own file; os.replace stays atomic
     # and last-writer-wins with a complete .so
-    tmp = f"{_SO}.{os.getpid()}.tmp"
-    # built lazily on the machine that runs it, so -march=native is safe
-    cmd = ["g++", "-O3", "-march=native", "-funroll-loops", "-std=c++17",
-           "-shared", "-fPIC", "-pthread", _SRC, "-o", tmp]
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=300)
-    except (OSError, subprocess.SubprocessError):
-        try:  # portable fallback flags
-            subprocess.run(["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-                            "-pthread", _SRC, "-o", tmp],
-                           check=True, capture_output=True, timeout=300)
-        except (OSError, subprocess.SubprocessError):
-            return None
-    os.replace(tmp, _SO)
-    return _SO
+        subprocess.run(["g++", *_FLAGS, _SRC, "-o", tmp], check=True,
+                       capture_output=True, text=True, timeout=300)
+    except FileNotFoundError:
+        return None                     # no compiler here: numpy path
+    except subprocess.CalledProcessError as e:
+        warnings.warn(f"apex_tpu native build failed (numpy fallback in "
+                      f"use): g++ exited {e.returncode}:\n{e.stderr}",
+                      RuntimeWarning, stacklevel=3)
+        return None
+    except (OSError, subprocess.TimeoutExpired) as e:
+        warnings.warn(f"apex_tpu native build failed (numpy fallback in "
+                      f"use): {e!r}", RuntimeWarning, stacklevel=3)
+        return None
+    os.replace(tmp, so)
+    return so
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -74,7 +95,8 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
 
 
 def lib() -> ctypes.CDLL | None:
-    """The loaded native library, or None if it can't be built here."""
+    """The loaded native library, or None if it can't be built here
+    (no compiler: silently; failed build or load: after a warning)."""
     global _lib, _tried
     if _lib is not None or _tried:
         return _lib
@@ -85,8 +107,10 @@ def lib() -> ctypes.CDLL | None:
             if so is not None:
                 try:
                     _lib = _bind(ctypes.CDLL(so))
-                except OSError:
-                    _lib = None
+                except OSError as e:
+                    warnings.warn(f"apex_tpu native library {so} did not "
+                                  f"load (numpy fallback in use): {e}",
+                                  RuntimeWarning, stacklevel=2)
     return _lib
 
 

@@ -48,7 +48,7 @@ from __future__ import annotations
 from apex_tpu.lint.core import Finding
 from apex_tpu.lint.jaxpr_checks import (_COLLECTIVE_AXIS_PARAMS,
                                         collective_axis_names)
-from apex_tpu.lint.semantic import (_as_jaxpr, _axes_in_names, _str_axes,
+from apex_tpu.lint.semantic import (_as_jaxpr, _shard_map_axes, _str_axes,
                                     _sub_jaxprs, _VARIANCE_KEEPING,
                                     _VARIANCE_REMOVING)
 
@@ -199,14 +199,9 @@ def _interp(jaxpr, in_var: list, ctx: frozenset, st: _State) -> list:
                     [a | b_ for a, b_ in zip(outs, res)]
         elif name == "shard_map":
             body = _as_jaxpr(eqn.params["jaxpr"])
-            mesh = eqn.params.get("mesh")
-            manual = set(getattr(mesh, "axis_names", ()) or ())
-            manual -= set(eqn.params.get("auto", ()) or ())
-            b_in = [_axes_in_names(n) & manual
-                    for n in eqn.params["in_names"]]
+            manual, b_in, out_axes = _shard_map_axes(eqn)
             _interp(body, b_in, ctx, st)
-            outs = [_axes_in_names(n) & manual
-                    for n in eqn.params["out_names"]]
+            outs = [axes & manual for axes in out_axes]
         else:
             subs = _sub_jaxprs(eqn)
             body = next((s for s in subs

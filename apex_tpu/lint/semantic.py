@@ -194,12 +194,24 @@ def _propagate(jaxpr, in_var: list) -> list:
     return [get(v) for v in jaxpr.outvars]
 
 
-def _axes_in_names(names: dict) -> set:
+def _axes_in_spec(spec) -> set:
+    """Mesh axes named by one ``PartitionSpec`` of a ``shard_map``
+    equation's ``in_specs`` / ``out_specs`` (entries are None, an axis
+    name, or a tuple of names)."""
     out: set = set()
-    for axes in names.values():
+    for axes in spec:
         axes = axes if isinstance(axes, (tuple, list)) else (axes,)
         out.update(a for a in axes if isinstance(a, str))
     return out
+
+
+def _shard_map_axes(eqn) -> tuple:
+    """``(manual axes, per-input axis sets, per-output axis sets)`` of a
+    ``shard_map`` equation."""
+    manual = set(eqn.params["manual_axes"])
+    return (manual,
+            [_axes_in_spec(sp) & manual for sp in eqn.params["in_specs"]],
+            [_axes_in_spec(sp) for sp in eqn.params["out_specs"]])
 
 
 def check_unreduced_outputs(closed, *, label: str = "<jaxpr>") -> list:
@@ -209,15 +221,10 @@ def check_unreduced_outputs(closed, *, label: str = "<jaxpr>") -> list:
         if eqn.primitive.name != "shard_map":
             continue
         body = _as_jaxpr(eqn.params["jaxpr"])
-        in_names = eqn.params["in_names"]
-        out_names = eqn.params["out_names"]
-        mesh = eqn.params.get("mesh")
-        manual = set(getattr(mesh, "axis_names", ()) or ())
-        manual -= set(eqn.params.get("auto", ()) or ())
-        in_var = [_axes_in_names(n) & manual for n in in_names]
+        manual, in_var, out_axes = _shard_map_axes(eqn)
         out_var = _propagate(body, in_var)
-        for j, (names, varies) in enumerate(zip(out_names, out_var)):
-            leaked = (varies & manual) - _axes_in_names(names)
+        for j, (named, varies) in enumerate(zip(out_axes, out_var)):
+            leaked = (varies & manual) - named
             if leaked:
                 ax = ", ".join(sorted(leaked))
                 findings.append(_finding(
@@ -443,7 +450,7 @@ def check_donation(closed, *, label: str = "<jaxpr>") -> list:
 
     findings: list = []
     for eqn, (_, _, owner) in _walk_eqns(_as_jaxpr(closed)):
-        if eqn.primitive.name != "pjit":
+        if eqn.primitive.name != "jit":    # the jax.jit equation (ex-pjit)
             continue
         donated = eqn.params.get("donated_invars")
         if donated is None:

@@ -319,6 +319,11 @@ def main(argv=None) -> int:
         from apex_tpu.monitor import fleet as fleet_mod
         return fleet_mod.main(args)
 
+    if args.cmd in ("profile", "memory"):
+        # the two subcommands that compile a model
+        from apex_tpu.utils import compile_cache
+        compile_cache.enable()
+
     if args.cmd == "profile":
         return _run_profile(args)
 

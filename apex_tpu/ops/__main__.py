@@ -149,6 +149,8 @@ def main(argv=None) -> int:
                    help="print the cache contents and exit")
     t.set_defaults(fn=_cmd_tune)
     args = p.parse_args(argv)
+    from apex_tpu.utils import compile_cache
+    compile_cache.enable()
     return args.fn(args)
 
 

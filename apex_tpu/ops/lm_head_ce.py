@@ -73,9 +73,7 @@ from apex_tpu.tune.vmem import LM_HEAD_VMEM_LIMIT as _VMEM_LIMIT
 
 
 def _compiler_params():
-    # resolved at call time: the params class name drifted across jax
-    # releases (CompilerParams vs TPUCompilerParams) and constructing it
-    # at import broke every importer on the other side of the rename
+    # built at call time, never at import (APX001)
     return tpu_compiler_params(vmem_limit_bytes=_VMEM_LIMIT)
 
 

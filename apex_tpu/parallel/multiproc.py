@@ -1,13 +1,18 @@
-"""Single-node multi-process launcher.
+"""Multi-process launcher for CPU simulation and multi-host bring-up.
 
 Reference: ``apex/parallel/multiproc.py:12-35`` — spawn one training
 process per GPU with ``--rank``/``--world-size`` appended.
 
-TPU reality: one process drives all local chips (SPMD), and multi-host
-jobs are launched by the TPU infrastructure with
-``jax.distributed.initialize()``. This launcher exists for parity and for
-multi-process CPU simulation: it spawns ``world_size`` processes with the
-coordinator env set so ``jax.distributed.initialize`` connects them.
+On one TPU host this launcher is NOT how the chips are used: one
+process drives all local chips (SPMD over a mesh), a chip belongs to one
+process at a time, and a second process that asks for it fails or
+hangs. The launcher is for multi-process *CPU* simulation (each child
+gets its own virtual host devices) and for standing in for the
+per-host launch of a multi-host job: it spawns ``world_size`` processes
+with the coordinator env set so ``jax.distributed.initialize`` connects
+them. ``main`` calls nothing in jax, so the parent initialises no
+backend and never holds a device its children need; ``--world-size``
+defaults to 1 and is otherwise stated by the caller.
 
 Usage: ``python -m apex_tpu.parallel.multiproc [--world-size N] script.py args...``
 """
@@ -60,19 +65,13 @@ def init_distributed(coordinator_address: str | None = None,
 
 def main(argv=None):
     argv = list(sys.argv[1:] if argv is None else argv)
-    world_size = None
+    world_size = 1
     if argv and argv[0] == "--world-size":
         world_size = int(argv[1])
         argv = argv[2:]
     if not argv:
         print(__doc__)
         return 1
-    if world_size is None:
-        try:
-            import jax
-            world_size = jax.local_device_count()
-        except Exception:
-            world_size = 1
 
     port = int(os.environ.get("APEX_TPU_COORD_PORT", "12355"))
     procs = []

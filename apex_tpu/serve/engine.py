@@ -262,11 +262,23 @@ class ServeEngine:
 
         if self.tp > 1:
             mesh = ps.get_mesh()
-            from jax.sharding import PartitionSpec as P
+            from jax.sharding import NamedSharding, PartitionSpec as P
             pspec = rules_mod.match_serve_rules(
                 rules_mod.GPT_PARAM_RULES, self.params, world=self.tp)
             cspec = rules_mod.match_serve_rules(
                 rules_mod.CACHE_RULES, self.state, world=self.tp)
+
+            def place(tree, spec):
+                # once, in the layout the step programs' in_specs name:
+                # left where the caller had them (one device), the
+                # weights would be re-scattered over the mesh by every
+                # prefill and decode call
+                return jax.device_put(tree, jax.tree.map(
+                    lambda sp: NamedSharding(mesh, sp), spec,
+                    is_leaf=lambda x: isinstance(x, P)))
+
+            self.params = place(self.params, pspec)
+            self.state = place(self.state, cspec)
             decode = shard_map(
                 decode, mesh=mesh,
                 in_specs=(pspec, cspec, P(), P(), P(), P()),
@@ -282,6 +294,8 @@ class ServeEngine:
                 dcspec = rules_mod.match_serve_rules(
                     rules_mod.CACHE_RULES, self.draft_state,
                     world=self.tp)
+                self.draft_params = place(self.draft_params, dpspec)
+                self.draft_state = place(self.draft_state, dcspec)
                 draft = shard_map(
                     draft, mesh=mesh,
                     in_specs=(dpspec, dcspec, P(), P(), P(), P()),

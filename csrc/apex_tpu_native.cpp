@@ -10,7 +10,7 @@
 //    pure-python loader a bottleneck — so this work happens on C++ threads.
 //
 // Exposed as a plain C ABI consumed via ctypes (no pybind11 in this image).
-// Build: g++ -O3 -march=native -std=c++17 -shared -fPIC -pthread.
+// Build: g++ -O3 -funroll-loops -std=c++17 -shared -fPIC -pthread (apex_tpu/_native).
 
 #include <atomic>
 #include <condition_variable>

@@ -23,6 +23,7 @@ from apex_tpu import amp
 from apex_tpu.amp import scaler as scaler_mod
 from apex_tpu.models import Discriminator, Generator
 from apex_tpu.optimizers import FusedAdam
+from apex_tpu.utils import compile_cache
 
 
 def bce_with_logits(logits, target):
@@ -43,6 +44,7 @@ def main():
     p.add_argument("--steps", type=int, default=50)
     p.add_argument("--opt-level", default="O2", choices=["O0", "O1", "O2", "O3"])
     args = p.parse_args()
+    compile_cache.enable()
 
     dtype = jnp.bfloat16 if args.opt_level in ("O2", "O3") else jnp.float32
     netG = Generator(nz=args.nz, ngf=args.ngf, dtype=dtype)

@@ -39,6 +39,7 @@ from apex_tpu.parallel import allreduce_gradients
 from apex_tpu.transformer import parallel_state as ps
 from apex_tpu.transformer.tensor_parallel import (
     mappings as tp_mappings, vocab_parallel_cross_entropy)
+from apex_tpu.utils import compile_cache
 
 
 def synthetic_batch(rng, batch, seq, vocab):
@@ -47,39 +48,12 @@ def synthetic_batch(rng, batch, seq, vocab):
     return jnp.asarray(ids), jnp.asarray(labels)
 
 
-def main():
-    p = argparse.ArgumentParser()
-    p.add_argument("--tp", type=int, default=2)
-    p.add_argument("--steps", type=int, default=30)
-    p.add_argument("--batch", type=int, default=8, help="global batch")
-    p.add_argument("--seq", type=int, default=128)
-    p.add_argument("--vocab", type=int, default=2048)
-    p.add_argument("--hidden", type=int, default=256)
-    p.add_argument("--layers", type=int, default=4)
-    p.add_argument("--heads", type=int, default=8)
-    p.add_argument("--no-sp", action="store_true",
-                   help="disable Megatron sequence parallelism")
-    args = p.parse_args()
-
-    n_dev = jax.device_count()
-    if n_dev % args.tp:
-        raise SystemExit(f"device count {n_dev} not divisible by tp={args.tp}")
-    dp = n_dev // args.tp
-    if args.batch % dp:
-        raise SystemExit(f"global batch {args.batch} not divisible by dp={dp}")
-
-    ps.destroy_model_parallel()
-    mesh = ps.initialize_model_parallel(tensor_model_parallel_size_=args.tp)
-    cfg = GPTConfig(vocab_size=args.vocab, max_seq_len=args.seq,
-                    hidden_size=args.hidden, num_layers=args.layers,
-                    num_heads=args.heads, dtype=jnp.bfloat16,
-                    sequence_parallel=not args.no_sp)
-    model = GPT(cfg)
-    opt = FusedAdam(lr=3e-4, master_weights=True)
-
-    rng = np.random.RandomState(0)
-    ids, labels = synthetic_batch(rng, args.batch, args.seq, args.vocab)
-
+def make_step_fns(mesh, model, opt):
+    """``(init_f, step_f)`` for ``model`` on ``mesh``: the jitted
+    ``shard_map`` programs of this example (rank-aware init; dp x tp
+    train step with Megatron-SP grad reduction, vocab-parallel CE and a
+    dynamic loss scaler). ``chip_smoke.py --chips 4`` runs exactly these
+    on real chips."""
     def init_state(ids):
         """Rank-aware init inside shard_map: each tp rank initializes its
         own weight shards (the reference's per-rank RNG offsets)."""
@@ -117,6 +91,44 @@ def main():
         train_step, mesh=mesh,
         in_specs=(P(), P(), P(), P(ps.DATA_AXIS), P(ps.DATA_AXIS)),
         out_specs=(P(), P(), P(), P()), check_vma=False))
+    return init_f, step_f
+
+
+def main():
+    p = argparse.ArgumentParser()
+    p.add_argument("--tp", type=int, default=2)
+    p.add_argument("--steps", type=int, default=30)
+    p.add_argument("--batch", type=int, default=8, help="global batch")
+    p.add_argument("--seq", type=int, default=128)
+    p.add_argument("--vocab", type=int, default=2048)
+    p.add_argument("--hidden", type=int, default=256)
+    p.add_argument("--layers", type=int, default=4)
+    p.add_argument("--heads", type=int, default=8)
+    p.add_argument("--no-sp", action="store_true",
+                   help="disable Megatron sequence parallelism")
+    args = p.parse_args()
+    compile_cache.enable()
+
+    n_dev = jax.device_count()
+    if n_dev % args.tp:
+        raise SystemExit(f"device count {n_dev} not divisible by tp={args.tp}")
+    dp = n_dev // args.tp
+    if args.batch % dp:
+        raise SystemExit(f"global batch {args.batch} not divisible by dp={dp}")
+
+    ps.destroy_model_parallel()
+    mesh = ps.initialize_model_parallel(tensor_model_parallel_size_=args.tp)
+    cfg = GPTConfig(vocab_size=args.vocab, max_seq_len=args.seq,
+                    hidden_size=args.hidden, num_layers=args.layers,
+                    num_heads=args.heads, dtype=jnp.bfloat16,
+                    sequence_parallel=not args.no_sp)
+    model = GPT(cfg)
+    opt = FusedAdam(lr=3e-4, master_weights=True)
+
+    rng = np.random.RandomState(0)
+    ids, labels = synthetic_batch(rng, args.batch, args.seq, args.vocab)
+
+    init_f, step_f = make_step_fns(mesh, model, opt)
 
     variables, opt_state, sstate = init_f(ids)
     first = last = None

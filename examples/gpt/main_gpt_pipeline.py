@@ -39,6 +39,7 @@ from apex_tpu.models import GPTConfig
 from apex_tpu.models.gpt_pipeline import PipelinedGPT
 from apex_tpu.transformer import build_num_microbatches_calculator
 from apex_tpu.transformer import parallel_state as ps
+from apex_tpu.utils import compile_cache
 
 
 def main():
@@ -64,6 +65,7 @@ def main():
     p.add_argument("--layers", type=int, default=4)
     p.add_argument("--heads", type=int, default=8)
     args = p.parse_args()
+    compile_cache.enable()
 
     n_dev = jax.device_count()
     if n_dev % (args.tp * args.pp):

@@ -42,6 +42,7 @@ from apex_tpu.models import ResNet18, ResNet50, ResNet101
 from apex_tpu.optimizers import FusedSGD
 from apex_tpu.ops import softmax_cross_entropy_with_smoothing
 from apex_tpu.parallel import SyncBatchNorm, allreduce_gradients
+from apex_tpu.utils import compile_cache
 
 ARCHS = {"resnet18": ResNet18, "resnet50": ResNet50, "resnet101": ResNet101}
 
@@ -96,6 +97,7 @@ def synthetic_batches(args, n_dev, seed=0):
 
 def main():
     args = parse_args()
+    compile_cache.enable()
     n_dev = jax.device_count()
     mesh = Mesh(np.array(jax.devices()), ("data",))
     print(f"=> {args.arch} O{args.opt_level[-1]} devices={n_dev} "

@@ -49,6 +49,8 @@ def main():
     import jax.numpy as jnp
     from apex_tpu import monitor, serve
     from apex_tpu.models.gpt import GPT, GPTConfig
+    from apex_tpu.utils import compile_cache
+    compile_cache.enable()
 
     cfg = GPTConfig(vocab_size=128, max_seq_len=128, hidden_size=64,
                     num_layers=2, num_heads=4, dtype=jnp.float32)

@@ -25,6 +25,7 @@ from apex_tpu import amp
 from apex_tpu.models import SimpleMLP
 from apex_tpu.optimizers import FusedSGD
 from apex_tpu.parallel import allreduce_gradients
+from apex_tpu.utils import compile_cache
 
 
 def main():
@@ -39,6 +40,7 @@ def main():
                         "per-step telemetry here (render with "
                         "`python -m apex_tpu.monitor report RUN_JSONL`)")
     args = p.parse_args()
+    compile_cache.enable()
 
     n_dev = jax.device_count()
     mesh = Mesh(np.array(jax.devices()), ("data",))

@@ -64,7 +64,9 @@
 #                    the tune/vmem calibration rows
 #   5. regress     — python -m apex_tpu.monitor regress: the smoke
 #                    stream must load as an evidence round, and the
-#                    committed BENCH_r01-r10 rounds must degrade exactly
+#                    r01-r10 rounds (r01-r05 as the cut fixtures under
+#                    tests/fixtures/regress/, r06-r10 as committed at
+#                    the root) must degrade exactly
 #                    as documented (r05 no-evidence, r01 incomparable,
 #                    cpu-host rounds unit-marked, memory byte keys
 #                    registered lower-better) with no false regression
@@ -402,7 +404,9 @@ echo "== ci: bench-trajectory regression gate (monitor.regress) =="
 #    are exercised on every CI run)
 python -m apex_tpu.monitor regress /tmp/ci_bench_smoke_stream.jsonl \
     --json > /tmp/ci_regress_smoke.json || fail=1
-# 2) the committed rounds r01-r10 must degrade exactly as documented:
+# 2) the rounds r01-r10 (r01-r05: tests/fixtures/regress/round_r0N.json,
+#    the driver wrappers cut to rc + parsed; r06-r10: BENCH_rNN.json at
+#    the root) must degrade exactly as documented:
 #    r05 is a no-evidence row (rc=124), r01 is incomparable with r02+
 #    (the unit-methodology change), the cpu-host rounds (r06-r10) are
 #    unit-marked so platform-bound metrics never cross-compare, and no
@@ -411,7 +415,8 @@ python - <<'EOF' || fail=1
 import json, subprocess, sys
 p = subprocess.run(
     [sys.executable, "-m", "apex_tpu.monitor", "regress",
-     *[f"BENCH_r{i:02d}.json" for i in range(1, 11)], "--json"],
+     *[f"tests/fixtures/regress/round_r{i:02d}.json" for i in range(1, 6)],
+     *[f"BENCH_r{i:02d}.json" for i in range(6, 11)], "--json"],
     capture_output=True, text=True)
 if p.returncode != 0:
     print(f"ci: regress over committed rounds exited {p.returncode}:\n"

@@ -453,7 +453,7 @@ def test_initialize_enabled_false_keeps_fp8_surface():
 def _bucket_bytes(grads, compress, message_size=2048):
     from apex_tpu.parallel.overlap import bucketed_allreduce
     rec = monitor.Recorder(name="fp8-bytes", capacity=256)
-    am = AbstractMesh((("data", 8),))
+    am = AbstractMesh((8,), ("data",))
     fn = shard_map(
         lambda g: bucketed_allreduce(g, "data", message_size=message_size,
                                      compress=compress),
@@ -595,7 +595,7 @@ def test_zero_optimizer_compress_allgather_scaled_knob():
 def test_fp8_bucket_jaxpr_pure_when_detached():
     from apex_tpu.parallel.overlap import bucketed_allreduce
     g = {"w": jnp.ones((32,), jnp.float32)}
-    am = AbstractMesh((("data", 8),))
+    am = AbstractMesh((8,), ("data",))
 
     def trace():
         return str(jax.make_jaxpr(shard_map(

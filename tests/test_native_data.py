@@ -4,6 +4,8 @@ Doctrine (SURVEY §4a): the native path is always compared against the
 pure-python reference implementation in the same process.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -169,3 +171,34 @@ def test_dataloader_validates_crop_and_small_dataset():
                     augment=False, shuffle=False)
     batches = list(dl)
     assert len(batches) == 1 and len(batches[0][0]) == 3
+
+
+def test_build_output_is_keyed_on_source_content_not_mtime(tmp_path,
+                                                           monkeypatch):
+    """A binary is reused only if built from exactly this source with
+    exactly these flags: the name carries their hash, so touching the
+    mtime changes nothing and editing a byte names a new file."""
+    src = tmp_path / "native.cpp"
+    src.write_text("extern \"C\" int atp_version() { return 1; }\n")
+    monkeypatch.setattr(_native, "_SRC", str(src))
+    a = _native._so_path()
+    os.utime(src, (1, 1))
+    assert _native._so_path() == a
+    src.write_text("extern \"C\" int atp_version() { return 2; }\n")
+    b = _native._so_path()
+    assert b != a
+    monkeypatch.setattr(_native, "_FLAGS", _native._FLAGS + ("-g",))
+    assert _native._so_path() not in (a, b)
+    assert "-march=native" not in _native._FLAGS
+
+
+def test_failed_build_warns_with_compiler_stderr(tmp_path, monkeypatch):
+    """A build that was attempted and failed is reported, not swallowed;
+    the caller still gets None and takes the numpy path."""
+    src = tmp_path / "broken.cpp"
+    src.write_text("this is not C++\n")
+    monkeypatch.setattr(_native, "_SRC", str(src))
+    monkeypatch.setattr(_native, "_BUILD_DIR", str(tmp_path / "build"))
+    with pytest.warns(RuntimeWarning, match="(?s)native build failed.*error"):
+        assert _native._build() is None
+    assert not list((tmp_path / "build").glob("*.so"))

@@ -2,10 +2,12 @@
 
 Round-loading robustness matrix (killed rc=124 round, corrupt JSON,
 missing file, evidence streams, unit mismatch), legacy unit inference
-over the REAL committed BENCH_r01-r05 files (the fixture the module
-exists for: r05 must load as ``no-evidence`` and r01 must be
-``incomparable`` with r02+ instead of a fake 50x regression), and
-MAD-band verdict arithmetic on synthetic trajectories.
+over the r01-r05 driver rounds (``tests/fixtures/regress/``: each
+round's ``n``/``rc`` and the ``parsed`` keys the loader reads, without
+the captured stderr — the case the module exists for: r05 must load as
+``no-evidence`` and r01 must be ``incomparable`` with r02+ instead of a
+fake 50x regression), and MAD-band verdict arithmetic on synthetic
+trajectories.
 """
 
 import json
@@ -17,10 +19,9 @@ from apex_tpu.monitor import regress
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# the real evidence rounds are the fixture: committed at the repo root,
-# exactly the files `python -m apex_tpu.monitor regress BENCH_r0*.json`
-# is pointed at
-ROUNDS = [os.path.join(REPO, f"BENCH_r0{i}.json") for i in range(1, 6)]
+# the r01-r05 driver wrappers, cut to what load_round reads
+ROUNDS = [os.path.join(REPO, "tests", "fixtures", "regress",
+                       f"round_r0{i}.json") for i in range(1, 6)]
 
 
 def _mk_round(tmp_path, name, metrics, units=None, schema=2):

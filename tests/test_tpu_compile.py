@@ -1,0 +1,234 @@
+"""Kernels that compile for the chip, kept as tests.
+
+The TPU's compiler is installed even where no TPU is attached, and it
+compiles for a chip that is *described* (on-chip-measurement guide §2.3).
+Every case here lowers a main-path Pallas kernel with ``interpret=False``
+at the widths ``chip_smoke.py`` runs (GPT 12L / h1024 / 16 heads / V32768,
+b8 s1024) on ``ShapeDtypeStruct``s placed on a described v5e, and requires a
+``tpu_custom_call`` in the compiled text: what Mosaic would refuse on the
+chip — a misaligned slice, too much VMEM — fails here, at no chip time,
+where interpret-mode tests pass. A compile that passes is not a chip run.
+
+``interpret=False`` is passed explicitly: left to themselves the kernels ask
+``jax.default_backend()``, see the CPU and interpret (zero custom calls).
+The two ``slow`` cases compile whole programs that take no such argument —
+the train step ``chip_smoke.py`` builds and the ``ServeEngine`` programs —
+and steer that rule by monkeypatch, here in the test.
+"""
+
+import functools
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from apex_tpu.amp import fp8 as fp8_mod
+from apex_tpu.ops.flash_attention import (flash_attention,
+                                          paged_decode_attention)
+from apex_tpu.ops.fp8_matmul import fp8_dequant_matmul
+from apex_tpu.ops.fused_ce import softmax_cross_entropy_with_smoothing
+from apex_tpu.ops.layer_norm import fused_layer_norm_affine
+from apex_tpu.ops.lm_head_ce import fused_lm_head_cross_entropy
+from apex_tpu.serve import cache as cache_mod
+from apex_tpu.zero.fused_update import fused_shard_update
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+B, H, D, HID, V = 8, 16, 64, 1024, 32768
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip (of a 2x2 host), or skip the module."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no libtpu / no TPU compiler on this machine
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def chip_matmul_precision():
+    """conftest pins ``jax_default_matmul_precision="highest"`` for CPU
+    accuracy; the kernels' in-VMEM dots inherit it, and Mosaic refuses a
+    bf16 dot at fp32 contract precision ("Bad lhs type"). Compile as a
+    program on the chip would: at the default precision."""
+    with jax.default_matmul_precision("default"):
+        yield
+
+
+def _sds(chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+
+def _place(chip, tree):
+    return jax.tree.map(lambda x: _sds(chip, x.shape, x.dtype), tree)
+
+
+def _sum_grad(fn, argnums):
+    """fwd+bwd of ``fn`` through a scalar."""
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(F32)), argnums=argnums)
+
+
+def _flash(s, b):
+    def build(chip):
+        q = _sds(chip, (b, H, s, D), BF16)
+        fn = _sum_grad(functools.partial(flash_attention, causal=True,
+                                         interpret=False), (0, 1, 2))
+        return fn, (q, q, q)
+    return build
+
+
+def _lm_head_ce(chip):
+    fn = _sum_grad(lambda x, e, t: fused_lm_head_cross_entropy(
+        x, e, t, interpret=False), (0, 1))
+    return fn, (_sds(chip, (B * 1024, HID), BF16),
+                _sds(chip, (V, HID), BF16), _sds(chip, (B * 1024,), I32))
+
+
+def _paged_decode(fp8):
+    def build(chip):
+        page = cache_mod.resolve_page_size(
+            kv_heads=H, head_dim=D, context_len=1024, dtype=BF16, fp8=fp8,
+            batch=B)
+        pool = _sds(chip, (H, 64, page, D),
+                    fp8_mod.E4M3 if fp8 else BF16)
+        args = [_sds(chip, (B, H, 1, D), BF16), pool, pool,
+                _sds(chip, (B, 1024 // page), I32), _sds(chip, (B,), I32)]
+        if fp8:
+            scales = _sds(chip, (H, 64), F32)
+            return (lambda q, k, v, bt, sl, ks, vs: paged_decode_attention(
+                q, k, v, bt, sl, k_scales=ks, v_scales=vs,
+                interpret=False)), args + [scales, scales]
+        return functools.partial(paged_decode_attention,
+                                 interpret=False), args
+    return build
+
+
+def _layer_norm(chip):
+    fn = _sum_grad(lambda x, w, b: fused_layer_norm_affine(
+        x, w, b, (HID,), out_dtype=BF16, block_r=256, interpret=False),
+        (0, 1, 2))
+    return fn, (_sds(chip, (B * 1024, HID), BF16),
+                _sds(chip, (HID,), F32), _sds(chip, (HID,), F32))
+
+
+def _fused_ce(chip):
+    fn = _sum_grad(lambda lg, t: softmax_cross_entropy_with_smoothing(
+        lg, t, block_t=256, block_v=2048, interpret=False), (0,))
+    return fn, (_sds(chip, (B * 1024, V), BF16),
+                _sds(chip, (B * 1024,), I32))
+
+
+def _fp8_matmul(chip):
+    """The four block linears of an h1024 layer, decode batch of 8."""
+    shapes = ((HID, 3 * HID), (HID, HID), (HID, 4 * HID), (4 * HID, HID))
+
+    def fn(x1, x4, s, *qs):
+        return [fp8_dequant_matmul(x4 if q.shape[0] == 4 * HID else x1, q,
+                                   s, block_k=512, block_n=512,
+                                   interpret=False) for q in qs]
+    return fn, (_sds(chip, (B, HID), BF16), _sds(chip, (B, 4 * HID), BF16),
+                _sds(chip, (), F32),
+                *[_sds(chip, kn, fp8_mod.E4M3) for kn in shapes])
+
+
+def _shard_update(chip):
+    n = 12 * HID * HID        # one layer's block linears, flat, fp32
+    fn = functools.partial(
+        fused_shard_update, kind="adam", lr=1e-3, betas=(0.9, 0.999),
+        eps=1e-8, weight_decay=0.0, adam_w_mode=True, bias_correction=True,
+        block_n=64 * 1024, interpret=False)
+    x = _sds(chip, (n,), F32)
+    return fn, (x, x, x, x, _sds(chip, (), I32))
+
+
+CASES = {
+    "flash_fwd_bwd_b8_s1024": _flash(1024, 8),
+    "flash_fwd_bwd_b2_s4096": _flash(4096, 2),
+    "lm_head_ce_fwd_bwd_n8192_v32768": _lm_head_ce,
+    "paged_decode_bf16": _paged_decode(False),
+    "paged_decode_fp8_kv": _paged_decode(True),
+    "layer_norm_fwd_bwd_8192x1024": _layer_norm,
+    "fused_ce_fwd_bwd_8192x32768": _fused_ce,
+    "fp8_dequant_matmul_h1024_linears": _fp8_matmul,
+    "fused_shard_update_adam": _shard_update,
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_kernel_compiles_for_v5e(chip, case):
+    fn, args = CASES[case](chip)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# whole programs (slow): the train step and the serve programs of chip_smoke
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def no_interpret(monkeypatch):
+    """The kernels' backend rule, steered for a described chip."""
+    def rule(interpret):
+        return False if interpret is None else interpret
+    monkeypatch.setattr(
+        importlib.import_module("apex_tpu.ops.flash_attention"),
+        "_resolve_interpret", rule)
+    monkeypatch.setattr(importlib.import_module("apex_tpu.ops.lm_head_ce"),
+                        "_resolve_interpret", rule)
+
+
+@pytest.mark.slow
+def test_train_step_compiles_for_v5e(chip, no_interpret, chip_smoke):
+    """``amp.make_train_step`` (O2 + FusedAdam) on the full GPT — the step
+    chip_smoke builds, on shapes only (nothing full-width is materialised
+    on the host); must fit one chip's 16 GB."""
+    smoke = chip_smoke
+    sz = smoke.FULL
+    _, step, init_state = smoke.build_train(sz)
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0),
+                           jax.ShapeDtypeStruct((1, sz.seq), I32))
+    ids = _sds(chip, (sz.batch, sz.seq), I32)
+    compiled = step._jitted.lower(False, *_place(chip, state), ids,
+                                  ids).compile()
+    smoke._require_kernels(smoke._kernel_calls(compiled.as_text()),
+                           flash_attention=2, lm_head_ce=2)
+    mem = compiled.memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15e9
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("fp8_kv", [False, True])
+def test_serve_programs_compile_for_v5e(chip, chip_smoke, fp8_kv):
+    """The ``ServeEngine``'s own decode and prefill programs at the shapes
+    chip_smoke serves, with the kernel paths the engine picks on a TPU
+    (named here: on this host its default is the XLA reference)."""
+    from apex_tpu import serve
+    from apex_tpu.models import GPT
+    smoke = chip_smoke
+    sz = smoke.FULL
+    cfg = smoke._gpt_config(sz)
+    params = jax.eval_shape(
+        lambda: GPT(cfg).init(jax.random.PRNGKey(0),
+                              jnp.zeros((1, 8), I32))["params"])
+    eng = serve.ServeEngine(
+        cfg, jax.tree.map(lambda x: _sds(chip, x.shape, BF16), params),
+        num_pages=sz.num_pages, max_seq_len=sz.max_seq_len,
+        max_prompt_len=sz.max_prompt_len, max_batch=sz.max_batch,
+        fp8_kv=fp8_kv, paged_impl="kernel", attention_impl="flash",
+        interpret=False)
+    eng.state = _place(chip, eng.state)
+    decode, prefill = smoke._serve_programs(eng, sharding=chip)
+    smoke._require_kernels(
+        smoke._kernel_calls(decode.compile().as_text()), paged_attn=1)
+    smoke._require_kernels(
+        smoke._kernel_calls(prefill.compile().as_text()),
+        flash_attention=1)
