@@ -13,6 +13,16 @@ Design rules (the monitor purity contract, serve-grade):
   imports jax, inserts ops, or touches traced code. A jitted program
   traced with spans active is byte-identical to one traced without
   (asserted by ``tests/test_serve_telemetry.py``).
+- **two sinks, one clock**: with a recorder attached and jax already
+  imported, a block-shaped :func:`span` also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name carrying the span
+  id (stat ``span``), so under a profiler session the span is an event
+  on the ``/host:CPU`` plane of the same ``.xplane.pb`` as the device
+  operations. The id in both sinks is the clock link: the median of
+  (annotation start - recorder start) over the joined spans places any
+  recorder event on the profiler's time base. :func:`start`/:func:`end`
+  spans that outlive a block (``serve/request``, ``serve/queue_wait``)
+  stay recorder-only.
 - **detached = free**: every entry point's first action is one global
   read; with no recorder attached :func:`start` returns ``None`` and
   :func:`end`/:func:`annotate` on ``None`` return immediately — no id
@@ -42,6 +52,7 @@ from __future__ import annotations
 
 import contextlib
 import math
+import sys
 import threading
 import time
 from typing import Optional
@@ -109,22 +120,29 @@ def span(name: str, parent: Optional[int] = None, **attrs):
     """Block-shaped span. Nests implicitly: with no explicit
     ``parent``, the innermost open :func:`span` on this thread is the
     parent. An exception unwinds the span with ``error=<type name>``
-    before re-raising."""
+    before re-raising. Attached, the block also runs inside a profiler
+    annotation ``name`` with the stat ``span=<id>`` (module docstring);
+    detached it is one global read: no id, no event, no annotation."""
     st = _nesting_stack()
     if parent is None and st:
         parent = st[-1]
     sid = start(name, parent=parent, **attrs)
-    if sid is not None:
-        st.append(sid)
+    if sid is None:
+        yield None
+        return
+    jax = sys.modules.get("jax")
+    st.append(sid)
     try:
-        yield sid
+        with (jax.profiler.TraceAnnotation(name, span=sid)
+              if jax is not None else contextlib.nullcontext()):
+            yield sid
     except BaseException as e:
         end(sid, error=type(e).__name__)
         raise
     else:
         end(sid)
     finally:
-        if sid is not None and st and st[-1] == sid:
+        if st and st[-1] == sid:
             st.pop()
 
 

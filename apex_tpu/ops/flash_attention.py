@@ -894,9 +894,14 @@ def _resolve_interpret(interpret):
 def _fa_fwd(q, k, v, sid_q, sid_kv, bias, seed, causal, scale, dropout_rate,
             block_q, block_k, block_q_bwd, block_k_bwd, interpret):
     scale_v = q.shape[-1] ** -0.5 if scale is None else scale
-    out, lse = _flash_fwd_impl(q, k, v, sid_q, sid_kv, bias, seed, scale_v,
-                               causal, dropout_rate, block_q, block_k,
-                               _resolve_interpret(interpret))
+    # a Pallas custom call is named by the innermost scope around it:
+    # the compiled instruction is ``apx_flash_attention_fwd`` (``_bwd``
+    # below), so a device trace tells the two directions apart
+    from apex_tpu.monitor import profile as _prof
+    with _prof.scope("flash_attention_fwd"):
+        out, lse = _flash_fwd_impl(q, k, v, sid_q, sid_kv, bias, seed,
+                                   scale_v, causal, dropout_rate, block_q,
+                                   block_k, _resolve_interpret(interpret))
     return out, (q, k, v, out, lse, sid_q, sid_kv, bias, seed)
 
 
@@ -905,10 +910,12 @@ def _fa_bwd(causal, scale, dropout_rate, block_q, block_k,
     q = res[0]
     bias = res[7]
     scale_v = q.shape[-1] ** -0.5 if scale is None else scale
-    dq, dk, dv = _flash_bwd_impl(
-        res, do, scale=scale_v, causal=causal, dropout_rate=dropout_rate,
-        block_q=block_q_bwd, block_k=block_k_bwd,
-        interpret=_resolve_interpret(interpret))
+    from apex_tpu.monitor import profile as _prof
+    with _prof.scope("flash_attention_bwd"):
+        dq, dk, dv = _flash_bwd_impl(
+            res, do, scale=scale_v, causal=causal,
+            dropout_rate=dropout_rate, block_q=block_q_bwd,
+            block_k=block_k_bwd, interpret=_resolve_interpret(interpret))
     # bias is an additive attention mask — non-differentiable by contract
     # (matches apex, where masks are inputs, never parameters); a real dbias
     # would require materializing [sq, sk] and is deliberately not offered.

@@ -280,9 +280,13 @@ def _fused_ce(x, e, tgt, label_smoothing, axis_name, block_t, block_v,
 def _fused_ce_fwd(x, e, tgt, label_smoothing, axis_name, block_t, block_v,
                   v_local, interpret):
     ec = e.astype(x.dtype)
-    m_loc, l_loc, pred_loc, ssum_loc = _fwd_partials(
-        x, ec, tgt, block_t, block_v, v_local, interpret,
-        with_ssum=label_smoothing > 0.0)
+    # the kernel is named by the innermost scope around its call:
+    # ``apx_lm_head_ce_fwd`` here, ``_bwd`` in the backward rule
+    from apex_tpu.monitor import profile as _prof
+    with _prof.scope("lm_head_ce_fwd"):
+        m_loc, l_loc, pred_loc, ssum_loc = _fwd_partials(
+            x, ec, tgt, block_t, block_v, v_local, interpret,
+            with_ssum=label_smoothing > 0.0)
     if axis_name is None:
         m_g, l_g, pred_g = m_loc, l_loc, pred_loc
     else:
@@ -311,7 +315,7 @@ def _fused_ce_bwd(label_smoothing, axis_name, block_t, block_v, v_local,
     kern = functools.partial(
         _bwd_kernel, block_v=block_v, v_local=v_local, v_total=v_total,
         label_smoothing=label_smoothing, upcast=interpret)
-    de, dxp = pl.pallas_call(
+    bwd = pl.pallas_call(
         kern,
         grid=(n_vb, n_tb),
         in_specs=[
@@ -332,8 +336,11 @@ def _fused_ce_bwd(label_smoothing, axis_name, block_t, block_v, v_local,
         ],
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(x, ec, tgt, m_g[None], l_g[None],
-      dloss.astype(jnp.float32)[None])
+    )
+    from apex_tpu.monitor import profile as _prof
+    with _prof.scope("lm_head_ce_bwd"):
+        de, dxp = bwd(x, ec, tgt, m_g[None], l_g[None],
+                      dloss.astype(jnp.float32)[None])
     # e arrives padded to a block multiple (see wrapper); the pad's own
     # transpose slices the padded rows (all-zero gradients) back off
     de = de[:e.shape[0]].astype(e.dtype)

@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from apex_tpu.amp import fp8 as fp8_mod
+from apex_tpu.monitor import profile as _prof
 
 #: heuristic default page size: big enough that a 1k-token context is
 #: 8 pages (program-count bound, like the flash forward), small enough
@@ -165,6 +166,7 @@ def _page_scales(cfg: CacheConfig, x) -> jax.Array:
                                  margin=cfg.fp8_margin)
 
 
+@_prof.scoped("kv_write")
 def write_token(cfg: CacheConfig, state: CacheState, layer: int,
                 page_ids, slots, k_new, v_new) -> CacheState:
     """Scatter one decode token per batch slot into layer ``layer``.
@@ -199,6 +201,7 @@ def write_token(cfg: CacheConfig, state: CacheState, layer: int,
     return CacheState(k_pool, v_pool, k_scale, v_scale)
 
 
+@_prof.scoped("kv_write")
 def write_prompt(cfg: CacheConfig, state: CacheState, layer: int,
                  block_table, length, k_seq, v_seq) -> CacheState:
     """Scatter a whole (padded) prompt's K/V for one sequence.

@@ -510,13 +510,21 @@ class ServeEngine:
         S = self.max_prompt_len
         ids = np.zeros((S,), np.int32)
         ids[:len(seq.prompt)] = seq.prompt
-        with _mspans.span("serve/prefill", parent=seq.span,
-                          seq_id=seq.seq_id, resumed=resumed,
+        # dispatch to the first token on the host: the fetch closes the
+        # span, so its duration is the prefill as the request feels it
+        # (closed at dispatch it read 2 ms of a 55 ms prefill). A child
+        # of the round by nesting; ``seq_id`` links it to the request.
+        # A resumed prefill samples nothing and fetches nothing: its
+        # span closes at dispatch and says ``resumed``.
+        with _mspans.span("serve/prefill", seq_id=seq.seq_id,
+                          resumed=resumed,
                           prompt_tokens=len(seq.prompt)):
             logits, next_tok, self.state = self._prefill(
                 self.params, self.state, jnp.asarray(self._bt_row(seq)),
                 jnp.int32(len(seq.prompt)), jnp.asarray(ids))
             seq.num_cached = len(seq.prompt)
+            if not resumed:
+                next_tok = int(next_tok)
         _mhooks.counter("serve/prefills")
         self._record(seq, len(seq.prompt), logits)
         if not resumed:
@@ -541,21 +549,39 @@ class ServeEngine:
         return self._step_inner()
 
     def _step_inner(self) -> bool:
-        plan = self.sched.schedule()
-        for seq in plan.preempted:
-            self._free_slot(seq)
-        for seq in plan.prefill:
-            self._do_prefill(seq)
-        decodes = [s for s in plan.decode
-                   if not s.done and s.state == RUNNING]
-        if decodes and self.spec_k:
-            # speculative mode: one draft+verify round per sequence
-            # (the verify window owns the batch rows)
-            for seq in decodes:
-                if seq.done or seq.state != RUNNING:
-                    continue
-                self._spec_round(seq)
-        elif decodes:
+        """One round, spanned by phase (docs/observability.md has the
+        table): ``serve/round`` holds ``serve/schedule``, one
+        ``serve/prefill`` per admitted sequence, ``serve/decode_inputs``,
+        ``serve/decode_step``, ``serve/sample`` and ``serve/gauges``.
+        The round's duration minus its ``serve/prefill`` and
+        ``serve/decode_step`` children is the host's time outside the
+        dispatching spans (inside them the device still waits for the
+        dispatch to arrive). Detached, each span is one global read."""
+        with _mspans.span("serve/round"):
+            with _mspans.span("serve/schedule"):
+                plan = self.sched.schedule()
+                for seq in plan.preempted:
+                    self._free_slot(seq)
+            for seq in plan.prefill:
+                self._do_prefill(seq)
+            decodes = [s for s in plan.decode
+                       if not s.done and s.state == RUNNING]
+            if decodes and self.spec_k:
+                # speculative mode: one draft+verify round per sequence
+                # (the verify window owns the batch rows)
+                for seq in decodes:
+                    if seq.done or seq.state != RUNNING:
+                        continue
+                    self._spec_round(seq)
+            elif decodes:
+                self._decode_round(decodes)
+            with _mspans.span("serve/gauges"):
+                self._record_step_gauges()
+        return self.sched.has_work
+
+    def _decode_round(self, decodes: List[Sequence]) -> None:
+        """One batched decode for the running sequences."""
+        with _mspans.span("serve/decode_inputs"):
             tok = np.zeros((self.max_batch,), np.int32)
             pos = np.zeros((self.max_batch,), np.int32)
             act = np.zeros((self.max_batch,), bool)
@@ -566,16 +592,18 @@ class ServeEngine:
                 pos[slot] = seq.num_tokens - 1
                 act[slot] = True
                 bts[slot] = self._bt_row(seq)
+            # decode_step_times has always counted the four uploads
             t0 = time.perf_counter()
-            with _mspans.span("serve/decode_step",
-                              n_active=len(decodes)):
-                logits, next_toks, self.state = self._decode(
-                    self.params, self.state, jnp.asarray(bts),
-                    jnp.asarray(pos), jnp.asarray(tok), jnp.asarray(act))
-                next_np = np.asarray(next_toks)
-            logits_np = np.asarray(logits) if self.record_logits else None
-            dt = time.perf_counter() - t0
-            self.decode_step_times.append(dt)
+            batch = (jnp.asarray(bts), jnp.asarray(pos), jnp.asarray(tok),
+                     jnp.asarray(act))
+        with _mspans.span("serve/decode_step", n_active=len(decodes)):
+            logits, next_toks, self.state = self._decode(
+                self.params, self.state, *batch)
+            next_np = np.asarray(next_toks)
+        logits_np = np.asarray(logits) if self.record_logits else None
+        dt = time.perf_counter() - t0
+        self.decode_step_times.append(dt)
+        with _mspans.span("serve/sample"):
             if _mhooks.enabled():
                 # per-TOKEN latency: each active slot produced one
                 # token this step — the streaming-percentile source of
@@ -590,8 +618,6 @@ class ServeEngine:
                 if logits_np is not None:
                     self._record(seq, seq.num_tokens, logits_np[slot])
                 self._sample(seq, next_np[slot])
-        self._record_step_gauges()
-        return self.sched.has_work
 
     def _record_step_gauges(self) -> None:
         """Pool-occupancy + queue-state gauges, once per scheduler

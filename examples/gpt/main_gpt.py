@@ -34,6 +34,7 @@ from apex_tpu._compat import shard_map
 
 from apex_tpu.amp import scaler as scaler_mod
 from apex_tpu.models import GPT, GPTConfig
+from apex_tpu.monitor import profile as _prof
 from apex_tpu.optimizers import FusedAdam
 from apex_tpu.parallel import allreduce_gradients
 from apex_tpu.transformer import parallel_state as ps
@@ -66,20 +67,26 @@ def make_step_fns(mesh, model, opt):
             loss = jnp.mean(vocab_parallel_cross_entropy(logits, labels))
             return scaler_mod.scale_value(loss, sstate)
 
-        scaled, grads = jax.value_and_grad(loss_fn)(variables)
+        # the four phase scopes of amp.make_train_step (metadata only):
+        # a device trace splits this step as it splits the amp one
+        with _prof.scope("amp_grad"):
+            scaled, grads = jax.value_and_grad(loss_fn)(variables)
         grads = allreduce_gradients(grads, ps.DATA_AXIS)
         # Megatron-SP contract: LN and post-reduce-scatter bias grads are
         # per-tp-rank partials
         grads = tp_mappings.allreduce_sequence_parallel_gradients(
             grads, GPT.sequence_parallel_grad_filter)
-        grads, found_inf = scaler_mod.unscale(grads, sstate)
+        with _prof.scope("amp_unscale"):
+            grads, found_inf = scaler_mod.unscale(grads, sstate)
         # tp ranks see different grad shards and must agree on skip-vs-
         # apply, or replicated state diverges (Megatron's model-parallel
         # found_inf all-reduce)
         found_inf = scaler_mod.sync_found_inf(found_inf, ps.TENSOR_AXIS)
-        new_vars, new_opt = opt.apply(opt_state, variables, grads,
-                                      skip=found_inf)
-        new_sstate = scaler_mod.update(sstate, found_inf, dynamic=True)
+        with _prof.scope("amp_optimizer"):
+            new_vars, new_opt = opt.apply(opt_state, variables, grads,
+                                          skip=found_inf)
+        with _prof.scope("amp_scaler"):
+            new_sstate = scaler_mod.update(sstate, found_inf, dynamic=True)
         loss = scaled / sstate.loss_scale
         return (new_vars, new_opt, new_sstate,
                 jax.lax.pmean(loss, ps.DATA_AXIS))

@@ -237,6 +237,51 @@ def test_tiny_gpt_step_jaxpr_unchanged_by_recorder_attach():
     assert detached == attached
 
 
+def _example_gpt_step():
+    """``examples/gpt/main_gpt.py:make_step_fns`` on a dp=2 x tp=2 mesh of
+    virtual devices, tiny."""
+    import importlib.util
+    import os
+    from apex_tpu.models import GPT, GPTConfig
+    from apex_tpu.optimizers import FusedAdam
+    from apex_tpu.transformer import parallel_state as ps
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "examples", "gpt", "main_gpt.py")
+    spec = importlib.util.spec_from_file_location("main_gpt", path)
+    main_gpt = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(main_gpt)
+    ps.destroy_model_parallel()
+    mesh = ps.initialize_model_parallel(tensor_model_parallel_size_=2,
+                                        devices=jax.devices()[:4])
+    model = GPT(GPTConfig(vocab_size=128, max_seq_len=16, hidden_size=32,
+                          num_layers=1, num_heads=2, dtype=jnp.bfloat16,
+                          sequence_parallel=True))
+    init_f, step_f = main_gpt.make_step_fns(
+        mesh, model, FusedAdam(lr=1e-3, master_weights=True))
+    ids = jnp.zeros((4, 16), jnp.int32)
+    return step_f, (*jax.eval_shape(init_f, ids), ids, ids)
+
+
+@pytest.mark.parametrize("build", [_tiny_gpt_step, _example_gpt_step],
+                         ids=["amp_step", "example_step"])
+def test_step_jaxpr_byte_identical_with_and_without_the_scopes(
+        build, monkeypatch):
+    """The phase scopes of both train steps (``amp_grad`` ..
+    ``amp_scaler``) and every scope below them are metadata: the step
+    traced with ``scope`` turned into a no-op is the same program."""
+    import contextlib
+    from apex_tpu.transformer import parallel_state as ps
+    try:
+        step, args = build()
+        scoped = str(jax.make_jaxpr(step)(*args))
+        monkeypatch.setattr(prof, "scope",
+                            lambda name: contextlib.nullcontext())
+        step, args = build()
+        assert str(jax.make_jaxpr(step)(*args)) == scoped
+    finally:
+        ps.destroy_model_parallel()
+
+
 # ---------------------------------------------------------------------------
 # recorder / report integration
 # ---------------------------------------------------------------------------

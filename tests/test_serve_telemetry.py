@@ -162,10 +162,99 @@ def test_preempt_readmit_trace_continuity(params):
 
 
 # ---------------------------------------------------------------------------
+# the round, by phase
+# ---------------------------------------------------------------------------
+
+def _closed(rec):
+    """``{span id: (name, parent, t0, t1, start event)}`` of the closed
+    spans, on the recorder's clock."""
+    starts = {e["value"]: e for e in rec.records("span_start")}
+    return {e["span"]: (e["name"], e["parent"], e["t"] - e["value"],
+                        e["t"], starts[e["span"]])
+            for e in rec.records("span_end")}
+
+
+def test_round_span_tree_every_child_inside_its_parent(params):
+    """One engine round that admits a prompt and decodes the sequence
+    already running: the phase spans of docs/observability.md, each a
+    child of the round by nesting and inside it in time, in the order the
+    round runs them."""
+    rec = monitor.Recorder(traced_hooks=False)
+    eng = _engine(params)
+    with monitor.attached(rec):
+        eng.add_request(PROMPTS[0], N_NEW)
+        eng.step()                       # a round that only prefills
+        sid = eng.add_request(PROMPTS[1], N_NEW)
+        eng.step()
+    spans_ = _closed(rec)
+    first, rid = [i for i, s in spans_.items() if s[0] == "serve/round"]
+    assert [s[0] for s in spans_.values() if s[1] == first] == [
+        "serve/schedule", "serve/prefill", "serve/gauges"]
+    _, parent, r0, r1, _ = spans_[rid]
+    assert parent is None
+    kids = sorted((s for s in spans_.values() if s[1] == rid),
+                  key=lambda s: s[2])
+    assert [k[0] for k in kids] == [
+        "serve/schedule", "serve/prefill", "serve/decode_inputs",
+        "serve/decode_step", "serve/sample", "serve/gauges"]
+    eps = 1e-4        # the recorder rounds t and durations to the us
+    for a, b in zip(kids, kids[1:]):
+        assert a[3] <= b[2] + eps               # one after the other
+    assert r0 - eps <= kids[0][2] and kids[-1][3] <= r1 + eps
+    step = next(k for k in kids if k[0] == "serve/decode_step")
+    assert step[4]["n_active"] == 1
+    # what the round admitted and decoded is read off the tree itself
+    assert [k[4]["seq_id"] for k in kids if k[0] == "serve/prefill"] == [sid]
+    # the host's time outside the dispatching spans: what the round does
+    # not spend inside a prefill or the decode step
+    covered = sum(k[3] - k[2] for k in kids
+                  if k[0] in ("serve/prefill", "serve/decode_step"))
+    assert 0 < (r1 - r0) - covered < r1 - r0
+
+
+def test_prefill_span_closes_after_the_first_token_is_on_the_host(params):
+    """``serve/prefill`` runs from the dispatch to the fetch of the first
+    token: at least as long as ``block_until_ready`` on the same call, and
+    it holds the fetch itself (closed at dispatch it would hold
+    neither)."""
+    import time
+    FETCH_S = 0.05
+    seen = {}
+
+    class SlowToken:
+        def __init__(self, tok):
+            self.tok = tok
+
+        def __int__(self):
+            time.sleep(FETCH_S)
+            return int(self.tok)
+
+    rec = monitor.Recorder(traced_hooks=False)
+    eng = _engine(params)
+    prefill = eng._prefill
+
+    def timed_prefill(*args):
+        t0 = time.perf_counter()
+        logits, tok, state = jax.block_until_ready(prefill(*args))
+        seen["block_s"] = time.perf_counter() - t0
+        return logits, SlowToken(tok), state
+
+    eng._prefill = timed_prefill
+    with monitor.attached(rec):
+        sid = eng.add_request(PROMPTS[0], 2)
+        eng.step()
+    (pre,) = [e for e in rec.records("span_end")
+              if e["name"] == "serve/prefill"]
+    assert pre["value"] >= seen["block_s"] + FETCH_S - 1e-3
+    assert eng.seqs[sid].num_generated >= 1
+
+
+# ---------------------------------------------------------------------------
 # purity + detached mode
 # ---------------------------------------------------------------------------
 
-def test_decode_prefill_jaxprs_byte_identical_spans_on_vs_off(params):
+def test_decode_prefill_jaxprs_byte_identical_spans_on_vs_off(params,
+                                                              monkeypatch):
     """The PR 2/10 purity contract, serve edition: tracing the
     engine's compiled decode/prefill steps with a (traced-hooks)
     recorder attached — spans live, histograms observing — yields
@@ -196,6 +285,13 @@ def test_decode_prefill_jaxprs_byte_identical_spans_on_vs_off(params):
     assert attached[0] == detached[0], "decode jaxpr drifted with spans"
     assert attached[1] == detached[1], "prefill jaxpr drifted with spans"
     assert "callback" not in detached[0] and "callback" not in detached[1]
+    # and the profile scopes (``kv_write``, ``paged_attn``, the kernels'
+    # per-direction ones) are metadata: the programs without any of them
+    # are the same programs
+    import contextlib
+    monkeypatch.setattr(profile_mod, "scope",
+                        lambda name: contextlib.nullcontext())
+    assert trace_both() == detached
 
 
 def test_detached_engine_records_nothing(params):

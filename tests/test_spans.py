@@ -159,6 +159,87 @@ def test_spans_detached_are_free():
     assert spans.open_spans() == before
 
 
+# -- the second sink: the profiler's host plane ----------------------------
+
+def _host_events(logdir):
+    """``[(name, span id or None, seconds)]`` of the ``/host:CPU`` plane
+    of the profiler session under ``logdir``."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(str(logdir), "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                sid = next((v for k, v in ev.stats if k == "span"), None)
+                out.append((ev.name, sid, ev.duration_ns * 1e-9))
+    return out
+
+
+def _profiled(logdir, fn):
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(logdir), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_events(logdir)
+
+
+def test_span_is_on_the_profilers_host_plane_with_its_id(tmp_path):
+    """Attached, a block span is in the recorder AND on the host plane of
+    the profiler's trace, under the same name with its id as the ``span``
+    stat; both sinks agree on how long it took."""
+    import time
+    rec = monitor.Recorder()
+    ids = {}
+
+    def work():
+        with monitor.attached(rec):
+            with spans.span("t/outer", k=1) as ids["outer"]:
+                with spans.span("t/inner") as ids["inner"]:
+                    time.sleep(0.02)
+    events = _profiled(tmp_path, work)
+    ends = {e["span"]: e for e in rec.records("span_end")}
+    for name in ("outer", "inner"):
+        (hit,) = [e for e in events if e[0] == "t/" + name]
+        assert hit[1] == ids[name]
+        assert hit[2] == pytest.approx(ends[ids[name]]["value"], abs=2e-3)
+        assert hit[2] >= 0.02
+
+
+def test_detached_span_makes_no_annotation_and_no_id(tmp_path):
+    assert monitor.get_recorder() is None
+    next_id = spans._next_id
+
+    def work():
+        with spans.span("t/detached") as sid:
+            assert sid is None
+    events = _profiled(tmp_path, work)
+    assert not [e for e in events if e[0] == "t/detached"]
+    assert spans._next_id == next_id
+
+
+def test_start_end_spans_stay_recorder_only(tmp_path):
+    """A span that outlives a block (``serve/request``) has no extent on
+    one thread's timeline: the recorder alone holds it."""
+    rec = monitor.Recorder()
+
+    def work():
+        with monitor.attached(rec):
+            spans.end(spans.start("t/request", seq_id=3))
+    events = _profiled(tmp_path, work)
+    assert not [e for e in events if e[0] == "t/request"]
+    assert [e["name"] for e in rec.records("span_end")] == ["t/request"]
+
+
 def test_span_detach_mid_flight_drops_cleanly():
     """A span whose recorder detaches before end(): the close is
     dropped (no event, no crash) and the open-table entry is freed."""

@@ -19,6 +19,7 @@ and steer that rule by monkeypatch, here in the test.
 import functools
 import importlib
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -167,6 +168,48 @@ def test_kernel_compiles_for_v5e(chip, case):
     fn, args = CASES[case](chip)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def _kv_write(chip):
+    """One layer's decode write and prompt write into a small pool."""
+    ccfg = cache_mod.CacheConfig(num_layers=2, kv_heads=H, head_dim=D,
+                                 num_pages=64, page_size=128, dtype=BF16)
+    state = jax.eval_shape(lambda: cache_mod.init_cache(ccfg))
+
+    def fn(state, page_ids, slots, k_new, table, length, k_seq):
+        state = cache_mod.write_token(ccfg, state, 0, page_ids, slots,
+                                      k_new, k_new)
+        return cache_mod.write_prompt(ccfg, state, 1, table, length, k_seq,
+                                      k_seq)
+    return fn, (_place(chip, state), _sds(chip, (B,), I32),
+                _sds(chip, (B,), I32), _sds(chip, (B, H, D), BF16),
+                _sds(chip, (4,), I32), _sds(chip, (), I32),
+                _sds(chip, (512, H, D), BF16))
+
+
+#: what a device trace of the chip is joined on (benchmarks/harness/
+#: span_reduce.py): a Pallas kernel's instruction is named by the innermost
+#: scope around its call, one name per direction; XLA's own instructions
+#: keep the scope path in their ``op_name``
+NAMED = {
+    "flash_fwd_bwd_b8_s1024": (
+        _flash(1024, 8), (r"%apx_flash_attention_fwd[.\d]* = ",
+                          r"%apx_flash_attention_bwd[.\d]* = ")),
+    "lm_head_ce_fwd_bwd_n8192_v32768": (
+        _lm_head_ce, (r"%apx_lm_head_ce_fwd[.\d]* = ",
+                      r"%apx_lm_head_ce_bwd[.\d]* = ")),
+    "kv_write_token_and_prompt": (
+        _kv_write, (r'op_name="[^"]*apx:kv_write/',)),
+}
+
+
+@pytest.mark.parametrize("case", list(NAMED))
+def test_compiled_text_names_direction_and_scope(chip, case):
+    build, patterns = NAMED[case]
+    fn, args = build(chip)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    for pattern in patterns:
+        assert re.search(pattern, text), pattern
 
 
 # ---------------------------------------------------------------------------
