@@ -927,14 +927,17 @@ def _fa_bwd(causal, scale, dropout_rate, block_q, block_k,
 _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 # ---------------------------------------------------------------------------
-# Paged decode attention (the serve path): one query token per sequence
-# reading K/V through a block table over a preallocated page pool.
+# Paged KV pool (the serve path): one query token per sequence reading K/V
+# through a block table over a preallocated page pool, and the in-place
+# writes that fill the pool.
 # ---------------------------------------------------------------------------
 #
 # Layout contract (shared with apex_tpu.serve.cache):
 #   q            [b, kv_heads, group, d]   (group = q_heads // kv_heads; GQA.
 #                                           MHA is group == 1)
-#   k/v pages    [kv_heads, num_pages, page_size, d]
+#   kv pages     [kv_heads, num_pages, page_size, 2*d]
+#                                          (ONE layer's pool: a token's K in
+#                                           lanes 0:d, its V in d:2d)
 #   block_tables [b, pages_per_seq] int32  (pool page ids; page 0 is the
 #                                           null page — entries past the
 #                                           sequence length point there and
@@ -945,33 +948,55 @@ _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 #                                           amp.fp8 — dequant divides it
 #                                           back out in-kernel)
 #
-# The kernel grid is (b, kv_heads, pages_per_seq): each program loads ONE
-# page of one head for one sequence (page id resolved from the
-# scalar-prefetched block table, the Pallas TPU paged-attention pattern)
-# and accumulates online-softmax state exactly like the training forward
-# kernel above. There is no backward: decode is inference-only.
+# Why K and V share a row: at d = 64 a [.., page_size, 64] array gets a
+# device layout with the page slots in the lanes, which no Pallas call
+# accepts, so every program that touched the pool copied it in and out
+# (PERF.md, PR 24). With 2*d >= 128 in the lanes the layout XLA picks IS
+# the row-major one the kernels below are held to, and a donated pool is
+# updated where it lies.
+#
+# The decode kernel grid is (b, kv_heads, pages_per_seq): each program
+# loads ONE page of one head for one sequence (page id resolved from the
+# scalar-prefetched block table, the Pallas TPU paged-attention pattern),
+# splits it into K and V in VMEM and accumulates online-softmax state
+# exactly like the training forward kernel above. There is no backward:
+# decode is inference-only.
 #
 # The page size IS this kernel's block size; it is fixed when the pool is
 # allocated, so resolution (explicit > tuned cache > heuristic, the
 # fwd/bwd policy) happens in ``serve.cache.resolve_page_size`` at pool
 # construction rather than per call.
+#
+# The two writes (``paged_kv_write_rows`` for a decode step,
+# ``paged_kv_write_pages`` for a prompt) alias the pool to their output
+# and move only the rows they touch: a tile of the pool is copied to
+# VMEM, the new rows are merged in, and the tile is copied back, one
+# tile after another so that two rows of one tile (a speculative verify
+# window, the masked rows on the null page) never race. An XLA scatter
+# or dynamic_update_slice computes the same pool but makes XLA lay the
+# pool out for the update ([kv, 1, 1, 2d]: heads next to the lanes) and
+# copy it there and back.
 
 
-def paged_attention_reference(q, k_pages, v_pages, block_tables, seq_lens,
+def _split_pages(kv_pages):
+    d = kv_pages.shape[-1] // 2
+    return kv_pages[..., :d], kv_pages[..., d:]
+
+
+def paged_attention_reference(q, kv_pages, block_tables, seq_lens,
                               *, scale=None, k_scales=None, v_scales=None):
     """Pure-XLA paged decode attention — the parity baseline and the
     off-TPU serving path (gathers pages through the block table; O(b *
     pages_per_seq * page_size) memory, fine at decode's one-query
     shapes)."""
-    kv_heads, _, page_size, d = k_pages.shape
-    b, _, _, _ = q.shape
+    kv_heads, _, page_size, _ = kv_pages.shape
+    b, _, _, d = q.shape
     m = block_tables.shape[1]
     scale = d ** -0.5 if scale is None else scale
-    # [kv, b, m, bs, d] -> [b, kv, m*bs, d]
-    k = jnp.take(k_pages, block_tables, axis=1).transpose(1, 0, 2, 3, 4)
-    v = jnp.take(v_pages, block_tables, axis=1).transpose(1, 0, 2, 3, 4)
-    k = k.astype(jnp.float32).reshape(b, kv_heads, m * page_size, d)
-    v = v.astype(jnp.float32).reshape(b, kv_heads, m * page_size, d)
+    # [kv, b, m, bs, 2d] -> [b, kv, m*bs, 2d]
+    kv = jnp.take(kv_pages, block_tables, axis=1).transpose(1, 0, 2, 3, 4)
+    kv = kv.astype(jnp.float32).reshape(b, kv_heads, m * page_size, 2 * d)
+    k, v = _split_pages(kv)
     if k_scales is not None:
         ks = jnp.take(k_scales, block_tables, axis=1).transpose(1, 0, 2)
         k = k / jnp.repeat(ks, page_size, axis=2)[..., None]
@@ -996,7 +1021,7 @@ def _paged_decode_kernel(*refs, scale, page_size, group, fp8, pages_per_seq):
     sl_ref = next(it)                       # scalar prefetch: [b] int32
     ks_ref = next(it) if fp8 else None      # SMEM [kv, num_pages] f32
     vs_ref = next(it) if fp8 else None
-    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr = it
+    q_ref, kv_ref, o_ref, m_scr, l_scr, acc_scr = it
 
     bi, kh, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -1007,7 +1032,7 @@ def _paged_decode_kernel(*refs, scale, page_size, group, fp8, pages_per_seq):
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     def _compute():
-        k = k_ref[0, 0]                                   # [bs, d]
+        k, v = _split_pages(kv_ref[0, 0])                 # [bs, d] each
         if fp8:
             idx = bt_ref[bi * pages_per_seq + j]
             q = q_ref[0, 0].astype(jnp.float32)
@@ -1035,7 +1060,6 @@ def _paged_decode_kernel(*refs, scale, page_size, group, fp8, pages_per_seq):
         p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, 0]
         if fp8:
             pv = jax.lax.dot_general(
                 p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
@@ -1060,7 +1084,7 @@ def _paged_decode_kernel(*refs, scale, page_size, group, fp8, pages_per_seq):
                        ).astype(o_ref.dtype)
 
 
-def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
+def paged_decode_attention(q, kv_pages, block_tables, seq_lens, *,
                            scale: Optional[float] = None,
                            k_scales=None, v_scales=None,
                            interpret: Optional[bool] = None):
@@ -1078,11 +1102,11 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
     under XLA CPU.
     """
     b, kv_heads, group, d = q.shape
-    kvp, num_pages, page_size, dp = k_pages.shape
-    if (kvp, dp) != (kv_heads, d):
+    kvp, num_pages, page_size, width = kv_pages.shape
+    if (kvp, width) != (kv_heads, 2 * d):
         raise ValueError(
-            f"k_pages {k_pages.shape} does not match q {q.shape}: want "
-            f"[kv_heads={kv_heads}, num_pages, page_size, d={d}]")
+            f"kv_pages {kv_pages.shape} does not match q {q.shape}: want "
+            f"[kv_heads={kv_heads}, num_pages, page_size, 2*d={2 * d}]")
     if (k_scales is None) != (v_scales is None):
         raise ValueError("fp8-KV mode needs BOTH k_scales and v_scales")
     if page_size % 8:
@@ -1118,10 +1142,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
         operands += [k_scales, v_scales]
     in_specs += [
         pl.BlockSpec((1, 1, g8, d), lambda bi, kh, j, bt, sl: (bi, kh, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, d), page_map),
-        pl.BlockSpec((1, 1, page_size, d), page_map),
+        pl.BlockSpec((1, 1, page_size, 2 * d), page_map),
     ]
-    operands += [q, k_pages, v_pages]
+    operands += [q, kv_pages]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -1143,6 +1166,112 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, seq_lens, *,
         )(block_tables.reshape(-1).astype(jnp.int32),
           seq_lens.astype(jnp.int32), *operands)
     return out[:, :, :group]
+
+
+def _merge_rows(tile_ref, buf, sem, src, lo, hi):
+    """Rows ``lo <= r < hi`` of the pool tile ``tile_ref`` (HBM,
+    [kv, rows, 2d]) become ``src`` ([kv, rows or 1, 2d]), the others
+    stay: read the tile, merge, write it back, and wait — the next
+    tile may be this one."""
+    read = pltpu.make_async_copy(tile_ref, buf, sem)
+    read.start()
+    read.wait()
+    row = jax.lax.broadcasted_iota(jnp.int32, buf.shape, 1)
+    buf[...] = jnp.where((row >= lo) & (row < hi), src, buf[...])
+    write = pltpu.make_async_copy(buf, tile_ref, sem)
+    write.start()
+    write.wait()
+
+
+def _write_rows_kernel(page_ref, slot_ref, rows_ref, _, pool_ref, buf, sem,
+                       *, tile):
+    bi = pl.program_id(0)
+    slot = slot_ref[bi]
+    base = pl.multiple_of(slot // tile * tile, tile)
+    _merge_rows(pool_ref.at[:, page_ref[bi], pl.ds(base, tile), :], buf, sem,
+                rows_ref[0], slot - base, slot - base + 1)
+
+
+def _write_pages_kernel(table_ref, len_ref, rows_ref, _, pool_ref, buf, sem,
+                        *, page_size, n_rows):
+    # phase 0: a page's live rows to the sequence's page; phase 1: the
+    # rows past the prompt's end to the null page, as the scatter does
+    j, phase = pl.program_id(0), pl.program_id(1)
+    here = jnp.minimum(page_size, n_rows - j * page_size)
+    live = jnp.clip(len_ref[0] - j * page_size, 0, here)
+    lo = jnp.where(phase == 0, 0, live)
+    hi = jnp.where(phase == 0, live, here)
+    page = jnp.where(phase == 0, table_ref[j], 0)
+
+    @pl.when(lo < hi)
+    def _():
+        _merge_rows(pool_ref.at[:, page], buf, sem, rows_ref[...], lo, hi)
+
+
+def _kv_write_call(kernel, grid, prefetch, rows, rows_spec, kv_pages,
+                   tile_rows, interpret):
+    kv_heads, _, _, width = kv_pages.shape
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=grid,
+        in_specs=[rows_spec, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.VMEM((kv_heads, tile_rows, width),
+                                   kv_pages.dtype),
+                        pltpu.SemaphoreType.DMA(())],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype),
+        # operands: two prefetched scalars, the rows, the pool
+        input_output_aliases={3: 0},
+        interpret=_resolve_interpret(interpret),
+    )(*prefetch, rows, kv_pages)
+
+
+def paged_kv_write_rows(kv_pages, page_ids, slots, rows, *,
+                        interpret: Optional[bool] = None):
+    """One token a batch row into a layer's pool, in place:
+    ``kv_pages[:, page_ids[i], slots[i]] = rows[i]`` for every ``i`` in
+    order. ``rows``: [b, kv_heads, 2*d] in the pool's dtype; masked
+    rows carry page 0. Returns the pool (aliased to the operand)."""
+    kv_heads, _, page_size, width = kv_pages.shape
+    # the smallest row group the DMA engine addresses in the pool's
+    # dtype: 8 sublanes of 32-bit words, each holding 4/itemsize rows
+    tile = 32 // jnp.dtype(kv_pages.dtype).itemsize
+    if page_size % tile:
+        tile = page_size
+    b = rows.shape[0]
+    kernel = functools.partial(_write_rows_kernel, tile=tile)
+    spec = pl.BlockSpec((1, kv_heads, 1, width),
+                        lambda bi, pg, sl: (bi, 0, 0, 0))
+    return _kv_write_call(
+        kernel, (b,), (page_ids.astype(jnp.int32), slots.astype(jnp.int32)),
+        rows[:, :, None, :], spec, kv_pages, tile, interpret)
+
+
+def paged_kv_write_pages(kv_pages, block_table, length, rows, *,
+                         interpret: Optional[bool] = None):
+    """A (padded) prompt's rows into a layer's pool, in place, page by
+    page: position ``p < length`` goes to page ``block_table[p //
+    page_size]``, slot ``p % page_size``; the positions past ``length``
+    go to the null page at their slot. ``rows``: [S, kv_heads, 2*d] in
+    the pool's dtype."""
+    kv_heads, _, page_size, width = kv_pages.shape
+    n_rows = rows.shape[0]
+    n_pages = -(-n_rows // page_size)
+    rows = jnp.pad(rows.transpose(1, 0, 2),
+                   ((0, 0), (0, n_pages * page_size - n_rows), (0, 0)))
+    kernel = functools.partial(_write_pages_kernel, page_size=page_size,
+                               n_rows=n_rows)
+    spec = pl.BlockSpec((kv_heads, page_size, width),
+                        lambda j, phase, bt, ln: (0, j, 0))
+    return _kv_write_call(
+        kernel, (n_pages, 2),
+        (block_table.astype(jnp.int32),
+         jnp.reshape(length, (1,)).astype(jnp.int32)),
+        rows, spec, kv_pages, page_size, interpret)
 
 
 from apex_tpu.amp.policy import half_function  # noqa: E402  (amp has no ops imports; placed here to keep kernel code import-light)

@@ -1,18 +1,31 @@
 """Paged KV cache: a preallocated page pool + per-sequence block tables.
 
 The pool is allocated ONCE (``init_cache``) and never reshaped: every
-cache mutation is a scatter into the fixed arrays, so the decode step
-can donate the pool and update it in place. Layout (the
-``ops.flash_attention.paged_decode_attention`` contract):
+cache mutation writes rows into the fixed arrays, so the decode and
+prefill steps donate the pool and update it in place. Layout (the
+contract in ``ops.flash_attention``, above its paged kernels):
 
-    k_pool / v_pool   [num_layers, kv_heads, num_pages, page_size, d]
+    pools[layer]      [kv_heads, num_pages, page_size, 2*d]
+                      one leaf a layer; a token's K in lanes 0:d, its V
+                      in d:2d
     k_scale / v_scale [num_layers, kv_heads, num_pages]  f32 (fp8 mode)
+
+One leaf a layer, because a program that updates and reads 2 x L slices
+of one stacked array makes XLA copy the whole stack; K beside V,
+because 2*d >= 128 in the lanes is what gives the leaf the row-major
+device layout the Pallas kernels take as it is (PERF.md, PR 24).
 
 Page 0 is the **null page**: the host allocator never hands it out, and
 every masked write (inactive batch slots, prompt padding) is routed to
-it — so a scatter never needs a branch, and nothing ever reads the null
-page's contents (block-table entries past a sequence's length point at
-it but are masked by ``seq_lens``).
+it — so a write never needs a branch on the row, and nothing ever reads
+the null page's contents (block-table entries past a sequence's length
+point at it but are masked by ``seq_lens``).
+
+A write is ``impl="kernel"`` (the aliased Pallas writes of
+``ops.flash_attention``: on the TPU an XLA scatter lays the pool out
+for the update and copies it there and back) or ``impl="reference"``
+(the XLA scatter: the off-TPU path and the parity baseline); both
+store the same bits.
 
 fp8-KV mode stores e4m3 pages through the :mod:`apex_tpu.amp.fp8` codec
 with ONE scale per (layer, head, page), fixed when the page's slot-0
@@ -33,13 +46,15 @@ fwd/bwd blocks.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, NamedTuple, Optional
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from apex_tpu.amp import fp8 as fp8_mod
 from apex_tpu.monitor import profile as _prof
+from apex_tpu.ops.flash_attention import (paged_kv_write_pages,
+                                          paged_kv_write_rows)
 
 #: heuristic default page size: big enough that a 1k-token context is
 #: 8 pages (program-count bound, like the flash forward), small enough
@@ -136,25 +151,23 @@ class CacheConfig:
 class CacheState(NamedTuple):
     """The device pytree the jitted steps thread and donate."""
 
-    k_pool: jax.Array
-    v_pool: jax.Array
+    pools: Tuple[jax.Array, ...]   # one [kv, pages, page_size, 2d] a layer
     k_scale: Optional[jax.Array]   # None outside fp8 mode
     v_scale: Optional[jax.Array]
 
 
 def init_cache(cfg: CacheConfig) -> CacheState:
-    shape = (cfg.num_layers, cfg.kv_heads, cfg.num_pages, cfg.page_size,
-             cfg.head_dim)
-    k = jnp.zeros(shape, cfg.pool_dtype)
-    v = jnp.zeros(shape, cfg.pool_dtype)
+    shape = (cfg.kv_heads, cfg.num_pages, cfg.page_size, 2 * cfg.head_dim)
+    # DISTINCT arrays, here and for the scales — aliased leaves break
+    # the donated step (donate-same-buffer-twice)
+    pools = tuple(jnp.zeros(shape, cfg.pool_dtype)
+                  for _ in range(cfg.num_layers))
     if not cfg.fp8:
-        return CacheState(k, v, None, None)
+        return CacheState(pools, None, None)
     # scales init to 1.0: finite and positive everywhere, so the
-    # kernel's dequant divides are safe even for never-written pages.
-    # Two DISTINCT arrays — aliased leaves break the donated step
-    # (donate-same-buffer-twice)
+    # kernel's dequant divides are safe even for never-written pages
     sshape = (cfg.num_layers, cfg.kv_heads, cfg.num_pages)
-    return CacheState(k, v, jnp.ones(sshape, jnp.float32),
+    return CacheState(pools, jnp.ones(sshape, jnp.float32),
                       jnp.ones(sshape, jnp.float32))
 
 
@@ -166,18 +179,34 @@ def _page_scales(cfg: CacheConfig, x) -> jax.Array:
                                  margin=cfg.fp8_margin)
 
 
+WRITE_IMPLS = ("reference", "kernel")
+
+
+def _check_impl(impl: str):
+    if impl not in WRITE_IMPLS:
+        raise ValueError(f"impl must be one of {WRITE_IMPLS}, got {impl!r}")
+
+
+def _with_layer(state: CacheState, layer: int, pool, k_scale,
+                v_scale) -> CacheState:
+    pools = state.pools[:layer] + (pool,) + state.pools[layer + 1:]
+    return CacheState(pools, k_scale, v_scale)
+
+
 @_prof.scoped("kv_write")
 def write_token(cfg: CacheConfig, state: CacheState, layer: int,
-                page_ids, slots, k_new, v_new) -> CacheState:
-    """Scatter one decode token per batch slot into layer ``layer``.
+                page_ids, slots, k_new, v_new, *, impl: str = "reference",
+                interpret: Optional[bool] = None) -> CacheState:
+    """Write one decode token per batch slot into layer ``layer``.
 
     ``page_ids``/``slots``: int32 [b] (masked slots carry page 0);
     ``k_new``/``v_new``: [b, kv_heads, d]. Pure — runs inside the
     donated decode step.
     """
-    # NB indexing below mixes the scalar ``layer`` with index arrays:
-    # both are "advanced" indices separated by the heads slice, so the
-    # broadcast dims land FIRST — gathers/scatters see [b, kv, ...]
+    _check_impl(impl)
+    # NB the scale indexing below mixes the scalar ``layer`` with index
+    # arrays: both are "advanced" indices separated by the heads slice,
+    # so the broadcast dims land FIRST — gathers/scatters see [b, kv]
     k_t, v_t = k_new, v_new                        # [b, kv, d]
     k_scale = state.k_scale
     v_scale = state.v_scale
@@ -196,27 +225,34 @@ def write_token(cfg: CacheConfig, state: CacheState, layer: int,
     else:
         k_t = k_t.astype(cfg.pool_dtype)
         v_t = v_t.astype(cfg.pool_dtype)
-    k_pool = state.k_pool.at[layer, :, page_ids, slots].set(k_t)
-    v_pool = state.v_pool.at[layer, :, page_ids, slots].set(v_t)
-    return CacheState(k_pool, v_pool, k_scale, v_scale)
+    rows = jnp.concatenate([k_t, v_t], axis=-1)    # [b, kv, 2d]
+    if impl == "kernel":
+        pool = paged_kv_write_rows(state.pools[layer], page_ids, slots,
+                                   rows, interpret=interpret)
+    else:
+        # adjacent index arrays stay in place: the update is [kv, b, 2d]
+        pool = state.pools[layer].at[:, page_ids, slots].set(
+            rows.transpose(1, 0, 2))
+    return _with_layer(state, layer, pool, k_scale, v_scale)
 
 
 @_prof.scoped("kv_write")
 def write_prompt(cfg: CacheConfig, state: CacheState, layer: int,
-                 block_table, length, k_seq, v_seq) -> CacheState:
-    """Scatter a whole (padded) prompt's K/V for one sequence.
+                 block_table, length, k_seq, v_seq, *,
+                 impl: str = "reference",
+                 interpret: Optional[bool] = None) -> CacheState:
+    """Write a whole (padded) prompt's K/V for one sequence.
 
     ``block_table``: int32 [m] (the sequence's pages); ``length``:
     traced scalar (real prompt length — positions past it route to the
     null page); ``k_seq``/``v_seq``: [S, kv_heads, d] with S static and
     a multiple-free shape (S <= m * page_size).
     """
+    _check_impl(impl)
     S = k_seq.shape[0]
     pos = jnp.arange(S, dtype=jnp.int32)
     live = pos < length
     pages = jnp.where(live, block_table[pos // cfg.page_size], 0)
-    slots = pos % cfg.page_size
-    # advanced-indexing note as in write_token: [S, kv, ...] layouts
     k_t, v_t = k_seq, v_seq                        # [S, kv, d]
     k_scale = state.k_scale
     v_scale = state.v_scale
@@ -238,6 +274,11 @@ def write_prompt(cfg: CacheConfig, state: CacheState, layer: int,
     else:
         k_t = k_t.astype(cfg.pool_dtype)
         v_t = v_t.astype(cfg.pool_dtype)
-    k_pool = state.k_pool.at[layer, :, pages, slots].set(k_t)
-    v_pool = state.v_pool.at[layer, :, pages, slots].set(v_t)
-    return CacheState(k_pool, v_pool, k_scale, v_scale)
+    rows = jnp.concatenate([k_t, v_t], axis=-1)    # [S, kv, 2d]
+    if impl == "kernel":
+        pool = paged_kv_write_pages(state.pools[layer], block_table, length,
+                                    rows, interpret=interpret)
+    else:
+        pool = state.pools[layer].at[:, pages, pos % cfg.page_size].set(
+            rows.transpose(1, 0, 2))
+    return _with_layer(state, layer, pool, k_scale, v_scale)

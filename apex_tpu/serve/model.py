@@ -10,11 +10,15 @@ serve path pays exactly the training collectives (row-parallel psum,
 logits gather). The only new math is the cache interaction:
 
 - :func:`prefill_forward` runs one (padded) prompt through full causal
-  attention and scatters every position's K/V into the sequence's
+  attention and writes every position's K/V into the sequence's
   pages;
-- :func:`decode_forward` runs ONE token per batch slot, scatters its
+- :func:`decode_forward` runs ONE token per batch slot, writes its
   K/V, and attends over the cache through the block table (the
   paged-attention path of ``ops.flash_attention``).
+
+The kernel paths (``paged_impl="kernel"``, ``attention_impl="flash"``)
+also write the pool through the aliased Pallas writes, the reference
+paths through the XLA scatter (``serve.cache``).
 
 Both are jit-pure: the engine compiles them once per static shape with
 the cache donated. ``monitor.profile`` scopes (``serve_prefill`` /
@@ -232,7 +236,8 @@ def decode_forward(cfg: GPTConfig, ccfg: cache_mod.CacheConfig, params,
         for i in range(cfg.num_layers):
             def attend(q, k, v, *, _i=i):
                 state_box[0] = cache_mod.write_token(
-                    ccfg, state_box[0], _i, page_ids, slots, k, v)
+                    ccfg, state_box[0], _i, page_ids, slots, k, v,
+                    impl=paged_impl, interpret=interpret)
                 st = state_box[0]
                 with _prof.scope("paged_attn"):
                     q4 = q[:, :, None, :]            # [B, hp, group=1, d]
@@ -242,13 +247,12 @@ def decode_forward(cfg: GPTConfig, ccfg: cache_mod.CacheConfig, params,
                                       v_scales=st.v_scale[_i])
                     if paged_impl == "kernel":
                         ctx = paged_decode_attention(
-                            q4, st.k_pool[_i], st.v_pool[_i],
-                            block_tables, seq_lens, interpret=interpret,
-                            **scales)
+                            q4, st.pools[_i], block_tables, seq_lens,
+                            interpret=interpret, **scales)
                     else:
                         ctx = paged_attention_reference(
-                            q4, st.k_pool[_i], st.v_pool[_i],
-                            block_tables, seq_lens, **scales)
+                            q4, st.pools[_i], block_tables, seq_lens,
+                            **scales)
                 return ctx[:, :, 0, :].reshape(B, -1)
 
             with _prof.scope(f"block_{i}"):
@@ -277,6 +281,7 @@ def prefill_forward(cfg: GPTConfig, ccfg: cache_mod.CacheConfig, params,
     S = ids.shape[0]
     d = cfg.hidden_size // cfg.num_heads
     lin_kw = dict(autotune=autotune, interpret=interpret)
+    write_impl = "kernel" if attention_impl == "flash" else "reference"
     with _prof.scope("serve_prefill"):
         x = _apply(mods["wte"], params["wte"], ids[None])
         x = (x + params["wpe"][None, :S]).astype(cfg.dtype)
@@ -286,7 +291,7 @@ def prefill_forward(cfg: GPTConfig, ccfg: cache_mod.CacheConfig, params,
             def attend(q, k, v, *, _i=i):
                 state_box[0] = cache_mod.write_prompt(
                     ccfg, state_box[0], _i, block_table, length, k[0],
-                    v[0])
+                    v[0], impl=write_impl, interpret=interpret)
                 ctx = _causal_attend(q, k, v, d, sid, attention_impl,
                                      interpret, "prefill_attn")
                 return ctx.reshape(1, S, -1)

@@ -10,10 +10,12 @@ path, first match wins, no-match is an error. Decisions:
 - ``"shard:<axis>"`` — put the tensor-parallel mesh axis at tensor
   dimension ``<axis>`` (``"shard:1"`` on a ``[in, out]`` kernel is the
   Megatron column shard);
-- ``"heads"`` — shorthand for ``"shard:1"``, the KV-cache convention:
-  every cache leaf (``[L, kv_heads, ...]`` pools and scales) shards its
-  heads dimension over the tensor axis, so each rank's pool holds its
-  local heads' pages and the paged-attention reads stay rank-local.
+- ``"heads"`` — shorthand for ``"shard:1"``, the heads dimension of the
+  KV-cache's stacked ``[L, kv_heads, num_pages]`` scale arrays. Every
+  cache leaf shards its heads dimension over the tensor axis (a layer's
+  pool leaf, ``[kv_heads, ...]``, is ``"shard:0"``), so each rank's
+  pool holds its local heads' pages and the paged-attention reads stay
+  rank-local.
 
 Two default tables ship: :data:`CACHE_RULES` for the paged KV-cache
 state and :data:`GPT_PARAM_RULES` for the GPT parameter tree the serve
@@ -38,11 +40,11 @@ from apex_tpu.zero.rules import first_match, leaf_path_names
 REPLICATE = "replicate"
 HEADS = "heads"
 
-#: KV-cache layout: pools are [L, kv_heads, num_pages, page_size, d],
-#: per-page fp8 scales are [L, kv_heads, num_pages] — heads dim 1 for
-#: all of them, sharded over the tensor axis.
+#: KV-cache layout: one pool leaf a layer, [kv_heads, num_pages,
+#: page_size, 2*d] (heads dim 0); per-page fp8 scales are [L, kv_heads,
+#: num_pages] (heads dim 1) — the heads dim sharded over the tensor axis.
 CACHE_RULES: tuple = (
-    (r"(k|v)_pool", HEADS),
+    (r"pools/\d+", "shard:0"),
     (r"(k|v)_scale", HEADS),
     (r".*", REPLICATE),
 )

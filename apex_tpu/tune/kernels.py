@@ -307,24 +307,22 @@ def build_decode_attention(shape: dict, dtype: str, flags: dict, *,
         bs = config["block_kv"]
         m = -(-s // bs)
         n_pages = b * m + 1                      # page 0 stays null
-        kp = rng.randn(kv, n_pages, bs, d) * 0.1
-        vp = rng.randn(kv, n_pages, bs, d) * 0.1
+        # one layer's pool leaf: K in lanes 0:d, V in d:2d
+        pool = rng.randn(kv, n_pages, bs, 2 * d) * 0.1
         scales = {}
         if fp8:
             from apex_tpu.amp import fp8 as f8
-            kp = jnp.clip(jnp.asarray(kp, jnp.float32), -f8.E4M3_MAX,
-                          f8.E4M3_MAX).astype(f8.E4M3)
-            vp = jnp.clip(jnp.asarray(vp, jnp.float32), -f8.E4M3_MAX,
-                          f8.E4M3_MAX).astype(f8.E4M3)
+            pool = jnp.clip(jnp.asarray(pool, jnp.float32), -f8.E4M3_MAX,
+                            f8.E4M3_MAX).astype(f8.E4M3)
             scales = dict(k_scales=jnp.ones((kv, n_pages), jnp.float32),
                           v_scales=jnp.ones((kv, n_pages), jnp.float32))
         else:
-            kp, vp = jnp.asarray(kp, dt), jnp.asarray(vp, dt)
+            pool = jnp.asarray(pool, dt)
         bt = jnp.asarray(1 + np.arange(b * m).reshape(b, m), jnp.int32)
         sl = jnp.full((b,), s, jnp.int32)
-        fn = jax.jit(lambda q, kp, vp, bt, sl: paged_decode_attention(
-            q, kp, vp, bt, sl, interpret=interpret, **scales))
-        return lambda: jax.block_until_ready(fn(q, kp, vp, bt, sl))
+        fn = jax.jit(lambda q, pool, bt, sl: paged_decode_attention(
+            q, pool, bt, sl, interpret=interpret, **scales))
+        return lambda: jax.block_until_ready(fn(q, pool, bt, sl))
     return build
 
 

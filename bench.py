@@ -2249,7 +2249,7 @@ def _bench_serve_spec():
             t0 = time.perf_counter()
             res = call(params_, state, bts, pos, tok, act)
             state = unpack(res)
-            _jax.block_until_ready(state.k_pool)
+            _jax.block_until_ready(state.pools)
             ts.append(time.perf_counter() - t0)
         return state, float(np.median(ts[2:]))
 

@@ -59,6 +59,18 @@ def _engine(params, *, fp8=False, num_pages=32, max_batch=2, **kw):
                              fp8_kv=fp8, record_logits=True, **kw)
 
 
+def _pool(k, v, **ccfg_kw):
+    """K and V pages ``[kv, pages, page_size, d]`` as one layer's pool
+    leaf, checked against the shape and dtype ``init_cache`` gives it."""
+    kv, n_pages, bs, d = k.shape
+    leaf, = cache_mod.init_cache(cache_mod.CacheConfig(
+        num_layers=1, kv_heads=kv, head_dim=d, num_pages=n_pages,
+        page_size=bs, **ccfg_kw)).pools
+    pool = jnp.concatenate([k, v], axis=-1)
+    assert (pool.shape, pool.dtype) == (leaf.shape, leaf.dtype)
+    return pool
+
+
 def _run(params, *, fp8=False, preempt_at=None, **kw):
     eng = _engine(params, fp8=fp8, **kw)
     ids = [eng.add_request(p, N_NEW) for p in PROMPTS]
@@ -89,8 +101,9 @@ def test_paged_decode_kernel_matches_reference_gqa():
     vp = jnp.asarray(rng.randn(kv, n_pages, bs, d) * 0.3, jnp.float32)
     bt = jnp.asarray(rng.randint(1, n_pages, (b, m)), jnp.int32)
     sl = jnp.asarray([13, 0, 32], jnp.int32)
-    ref = paged_attention_reference(q, kp, vp, bt, sl)
-    out = paged_decode_attention(q, kp, vp, bt, sl)
+    pool = _pool(kp, vp, dtype=jnp.float32)
+    ref = paged_attention_reference(q, pool, bt, sl)
+    out = paged_decode_attention(q, pool, bt, sl)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=1e-5)
     # inactive slot (seq_len 0) contributes exact zeros
     assert float(jnp.max(jnp.abs(out[1]))) == 0.0
@@ -109,40 +122,141 @@ def test_paged_decode_kernel_fp8_dequant():
     vp = f8.quantize(v32, 4.0, f8.E4M3)
     bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     sl = jnp.asarray([11, 16], jnp.int32)
-    ref = paged_attention_reference(q, kp, vp, bt, sl, k_scales=ks,
+    pool = _pool(kp, vp, fp8=True)
+    ref = paged_attention_reference(q, pool, bt, sl, k_scales=ks,
                                     v_scales=vs)
-    out = paged_decode_attention(q, kp, vp, bt, sl, k_scales=ks,
+    out = paged_decode_attention(q, pool, bt, sl, k_scales=ks,
                                  v_scales=vs)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=1e-5)
-    exact = paged_attention_reference(q, k32, v32, bt, sl)
+    exact = paged_attention_reference(q, _pool(k32, v32, dtype=jnp.float32),
+                                      bt, sl)
     assert float(jnp.max(jnp.abs(ref - exact))) < 0.1
 
 
 def test_decode_forward_kernel_impl_matches_reference(params):
-    """The model-level decode step through the Pallas kernel (interpret)
-    == through the XLA reference gather."""
+    """The model-level decode step through the Pallas kernels (interpret:
+    the aliased write, then the paged read) == through the XLA scatter
+    and the reference gather."""
     from apex_tpu.serve import model as serve_model
     ccfg = cache_mod.CacheConfig(num_layers=CFG.num_layers, kv_heads=2,
-                                 head_dim=16, num_pages=8, page_size=8)
+                                 head_dim=16, num_pages=8, page_size=8,
+                                 dtype=jnp.float32)
     bt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     pos = jnp.asarray([3, 5], jnp.int32)
     tok = jnp.asarray([7, 9], jnp.int32)
     act = jnp.ones((2,), bool)
     rng = np.random.RandomState(2)
-    state = cache_mod.CacheState(
-        jnp.asarray(rng.randn(CFG.num_layers, 2, 8, 8, 16) * 0.3,
-                    jnp.float32),
-        jnp.asarray(rng.randn(CFG.num_layers, 2, 8, 8, 16) * 0.3,
-                    jnp.float32), None, None)
-    l_ref, _ = serve_model.decode_forward(CFG, ccfg, params, state, bt,
-                                          pos, tok, act,
-                                          paged_impl="reference")
-    l_ker, _ = serve_model.decode_forward(CFG, ccfg, params, state, bt,
-                                          pos, tok, act,
-                                          paged_impl="kernel",
-                                          interpret=True)
+    state = cache_mod.init_cache(ccfg)
+    state = state._replace(pools=tuple(
+        jnp.asarray(rng.randn(*p.shape) * 0.3, p.dtype)
+        for p in state.pools))
+    l_ref, s_ref = serve_model.decode_forward(CFG, ccfg, params, state, bt,
+                                              pos, tok, act,
+                                              paged_impl="reference")
+    l_ker, s_ker = serve_model.decode_forward(CFG, ccfg, params, state, bt,
+                                              pos, tok, act,
+                                              paged_impl="kernel",
+                                              interpret=True)
     np.testing.assert_allclose(np.asarray(l_ref), np.asarray(l_ker),
                                atol=2e-5)
+    # the first layer's write sees the same inputs on both paths
+    np.testing.assert_array_equal(np.asarray(s_ref.pools[0]),
+                                  np.asarray(s_ker.pools[0]))
+    for a, b in zip(s_ref.pools[1:], s_ker.pools[1:]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the pool: what is written is what is stored, whichever write stores it
+# ---------------------------------------------------------------------------
+
+def _write_case(fp8, dtype=jnp.bfloat16):
+    """A prompt (13 of 24 padded positions live: one full page, one partly
+    live, one dead) into layer 1, then a decode step of 4 rows into layer
+    0: two live rows, one of them opening a page (slot 0), and two masked
+    rows that carry the same K/V (as inactive slots do) to the null page."""
+    ccfg = cache_mod.CacheConfig(num_layers=2, kv_heads=2, head_dim=16,
+                                 num_pages=8, page_size=8, dtype=dtype,
+                                 fp8=fp8)
+    rng = np.random.RandomState(3)
+
+    def rand(*shape):
+        return jnp.asarray(rng.randn(*shape) * 0.5, jnp.float32)
+
+    k_seq, v_seq = rand(24, 2, 16), rand(24, 2, 16)
+    k_new, v_new = rand(4, 2, 16), rand(4, 2, 16)
+    k_new = k_new.at[3].set(k_new[2])
+    v_new = v_new.at[3].set(v_new[2])
+    table = jnp.asarray([5, 2, 7], jnp.int32)
+    page_ids = jnp.asarray([3, 6, 0, 0], jnp.int32)
+    slots = jnp.asarray([5, 0, 0, 0], jnp.int32)
+
+    def write(impl):
+        state = cache_mod.init_cache(ccfg)
+        state = cache_mod.write_prompt(ccfg, state, 1, table, jnp.int32(13),
+                                       k_seq, v_seq, impl=impl,
+                                       interpret=True)
+        return cache_mod.write_token(ccfg, state, 0, page_ids, slots, k_new,
+                                     v_new, impl=impl, interpret=True)
+    return ccfg, write, (k_seq, v_seq, table), (k_new, v_new, page_ids, slots)
+
+
+@pytest.mark.parametrize("fp8", [False, True], ids=["bf16", "e4m3"])
+def test_pool_read_back_equals_written_kv(fp8):
+    """K in lanes 0:d, V in d:2d of the token's row: the merged pool read
+    back is the K and V that were written, in the stored type (bf16 rows,
+    or e4m3 under the page's slot-0 scale)."""
+    from apex_tpu.amp import fp8 as f8
+    ccfg, write, (k_seq, v_seq, table), (k_new, v_new, page_ids, slots) = \
+        _write_case(fp8)
+    state = write("reference")
+    d = ccfg.head_dim
+
+    def stored(x, scale):
+        if not fp8:
+            return np.asarray(x.astype(jnp.bfloat16).astype(jnp.float32))
+        return np.asarray(f8.quantize(x, scale[..., None], f8.E4M3)
+                          .astype(jnp.float32))
+
+    def scale_of(first):             # the page's first token, [kv, d]
+        return cache_mod._page_scales(ccfg, first) if fp8 else None
+
+    for pos in range(13):            # the prompt's live positions, layer 1
+        row = np.asarray(state.pools[1][:, int(table[pos // 8]), pos % 8]
+                         .astype(jnp.float32))
+        first = pos // 8 * 8
+        np.testing.assert_array_equal(
+            row[:, :d], stored(k_seq[pos], scale_of(k_seq[first])))
+        np.testing.assert_array_equal(
+            row[:, d:], stored(v_seq[pos], scale_of(v_seq[first])))
+    # the decode row that opens page 6 (slot 0 sets its scale), layer 0
+    row = np.asarray(state.pools[0][:, 6, 0].astype(jnp.float32))
+    np.testing.assert_array_equal(row[:, :d],
+                                  stored(k_new[1], scale_of(k_new[1])))
+    np.testing.assert_array_equal(row[:, d:],
+                                  stored(v_new[1], scale_of(v_new[1])))
+    if fp8:
+        np.testing.assert_array_equal(np.asarray(state.k_scale[0, :, 6]),
+                                      np.asarray(scale_of(k_new[1])))
+    # untouched pages of a written layer stay zero
+    assert not np.asarray(state.pools[1][:, 1].astype(jnp.float32)).any()
+
+
+@pytest.mark.parametrize("pool", ["f32", "bf16", "e4m3"])
+def test_pallas_write_equals_xla_scatter_bitwise(pool):
+    """The aliased Pallas writes (interpret mode) leave the whole state
+    — every page of every layer, the null page with its masked rows, the
+    fp8 scales — bit for bit as the XLA scatter leaves it."""
+    _, write, _, _ = _write_case(
+        pool == "e4m3", jnp.float32 if pool == "f32" else jnp.bfloat16)
+    ref, ker = write("reference"), write("kernel")
+    # the masked writes did land on the null page
+    assert np.asarray(ref.pools[0][:, 0, 0].astype(jnp.float32)).any()
+    assert np.asarray(ref.pools[1][:, 0, 5:].astype(jnp.float32)).any()
+    for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(ker)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
+                                      np.asarray(b.astype(jnp.float32)))
 
 
 # ---------------------------------------------------------------------------
@@ -155,7 +269,7 @@ def test_serve_rules_cache_and_param_specs(params):
         num_layers=1, kv_heads=2, head_dim=8, num_pages=4, page_size=8,
         fp8=True))
     spec = serve.match_serve_rules(serve.CACHE_RULES, state, world=2)
-    assert spec.k_pool == P(None, "tensor", None, None, None)
+    assert spec.pools == (P("tensor", None, None, None),)
     assert spec.k_scale == P(None, "tensor", None)
     pspec = serve.match_serve_rules(serve.GPT_PARAM_RULES, params, world=2)
     assert pspec["block_0"]["attn"]["qkv"]["kernel"] == P(None, "tensor")

@@ -16,6 +16,7 @@ the train step ``chip_smoke.py`` builds and the ``ServeEngine`` programs —
 and steer that rule by monkeypatch, here in the test.
 """
 
+import dataclasses
 import functools
 import importlib
 import os
@@ -98,17 +99,40 @@ def _paged_decode(fp8):
         page = cache_mod.resolve_page_size(
             kv_heads=H, head_dim=D, context_len=1024, dtype=BF16, fp8=fp8,
             batch=B)
-        pool = _sds(chip, (H, 64, page, D),
+        pool = _sds(chip, (H, 64, page, 2 * D),
                     fp8_mod.E4M3 if fp8 else BF16)
-        args = [_sds(chip, (B, H, 1, D), BF16), pool, pool,
+        args = [_sds(chip, (B, H, 1, D), BF16), pool,
                 _sds(chip, (B, 1024 // page), I32), _sds(chip, (B,), I32)]
         if fp8:
             scales = _sds(chip, (H, 64), F32)
-            return (lambda q, k, v, bt, sl, ks, vs: paged_decode_attention(
-                q, k, v, bt, sl, k_scales=ks, v_scales=vs,
+            return (lambda q, kv, bt, sl, ks, vs: paged_decode_attention(
+                q, kv, bt, sl, k_scales=ks, v_scales=vs,
                 interpret=False)), args + [scales, scales]
         return functools.partial(paged_decode_attention,
                                  interpret=False), args
+    return build
+
+
+def _kv_write(fp8):
+    """One layer's decode write and prompt write into a small pool,
+    through the aliased Pallas writes the engine uses on the chip."""
+    def build(chip):
+        ccfg = cache_mod.CacheConfig(num_layers=2, kv_heads=H, head_dim=D,
+                                     num_pages=64, page_size=128,
+                                     dtype=BF16, fp8=fp8)
+        state = jax.eval_shape(lambda: cache_mod.init_cache(ccfg))
+
+        def fn(state, page_ids, slots, k_new, table, length, k_seq):
+            state = cache_mod.write_token(
+                ccfg, state, 0, page_ids, slots, k_new, k_new,
+                impl="kernel", interpret=False)
+            return cache_mod.write_prompt(
+                ccfg, state, 1, table, length, k_seq, k_seq, impl="kernel",
+                interpret=False)
+        return fn, (_place(chip, state), _sds(chip, (B,), I32),
+                    _sds(chip, (B,), I32), _sds(chip, (B, H, D), BF16),
+                    _sds(chip, (4,), I32), _sds(chip, (), I32),
+                    _sds(chip, (512, H, D), BF16))
     return build
 
 
@@ -156,6 +180,8 @@ CASES = {
     "lm_head_ce_fwd_bwd_n8192_v32768": _lm_head_ce,
     "paged_decode_bf16": _paged_decode(False),
     "paged_decode_fp8_kv": _paged_decode(True),
+    "kv_write_bf16": _kv_write(False),
+    "kv_write_fp8_kv": _kv_write(True),
     "layer_norm_fwd_bwd_8192x1024": _layer_norm,
     "fused_ce_fwd_bwd_8192x32768": _fused_ce,
     "fp8_dequant_matmul_h1024_linears": _fp8_matmul,
@@ -170,23 +196,6 @@ def test_kernel_compiles_for_v5e(chip, case):
     assert "tpu_custom_call" in text
 
 
-def _kv_write(chip):
-    """One layer's decode write and prompt write into a small pool."""
-    ccfg = cache_mod.CacheConfig(num_layers=2, kv_heads=H, head_dim=D,
-                                 num_pages=64, page_size=128, dtype=BF16)
-    state = jax.eval_shape(lambda: cache_mod.init_cache(ccfg))
-
-    def fn(state, page_ids, slots, k_new, table, length, k_seq):
-        state = cache_mod.write_token(ccfg, state, 0, page_ids, slots,
-                                      k_new, k_new)
-        return cache_mod.write_prompt(ccfg, state, 1, table, length, k_seq,
-                                      k_seq)
-    return fn, (_place(chip, state), _sds(chip, (B,), I32),
-                _sds(chip, (B,), I32), _sds(chip, (B, H, D), BF16),
-                _sds(chip, (4,), I32), _sds(chip, (), I32),
-                _sds(chip, (512, H, D), BF16))
-
-
 #: what a device trace of the chip is joined on (benchmarks/harness/
 #: span_reduce.py): a Pallas kernel's instruction is named by the innermost
 #: scope around its call, one name per direction; XLA's own instructions
@@ -199,7 +208,8 @@ NAMED = {
         _lm_head_ce, (r"%apx_lm_head_ce_fwd[.\d]* = ",
                       r"%apx_lm_head_ce_bwd[.\d]* = ")),
     "kv_write_token_and_prompt": (
-        _kv_write, (r'op_name="[^"]*apx:kv_write/',)),
+        _kv_write(False), (r'op_name="[^"]*apx:kv_write/',
+                           r"%apx_kv_write[.\d]* = ")),
 }
 
 
@@ -248,16 +258,13 @@ def test_train_step_compiles_for_v5e(chip, no_interpret, chip_smoke):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15e9
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("fp8_kv", [False, True])
-def test_serve_programs_compile_for_v5e(chip, chip_smoke, fp8_kv):
-    """The ``ServeEngine``'s own decode and prefill programs at the shapes
-    chip_smoke serves, with the kernel paths the engine picks on a TPU
-    (named here: on this host its default is the XLA reference)."""
+def _compile_serve(chip, smoke, sz, fp8_kv=False):
+    """The ``ServeEngine``'s own decode and prefill programs, compiled for
+    the described chip with the kernel paths the engine picks on a TPU
+    (named here: on this host its default is the XLA reference), pool
+    donated. Returns the engine and the two executables."""
     from apex_tpu import serve
     from apex_tpu.models import GPT
-    smoke = chip_smoke
-    sz = smoke.FULL
     cfg = smoke._gpt_config(sz)
     params = jax.eval_shape(
         lambda: GPT(cfg).init(jax.random.PRNGKey(0),
@@ -270,8 +277,68 @@ def test_serve_programs_compile_for_v5e(chip, chip_smoke, fp8_kv):
         interpret=False)
     eng.state = _place(chip, eng.state)
     decode, prefill = smoke._serve_programs(eng, sharding=chip)
-    smoke._require_kernels(
-        smoke._kernel_calls(decode.compile().as_text()), paged_attn=1)
-    smoke._require_kernels(
-        smoke._kernel_calls(prefill.compile().as_text()),
-        flash_attention=1)
+    return eng, decode.compile(), prefill.compile()
+
+
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\((.*)$",
+    re.M)
+
+
+def _pool_traffic(text, leaf_elems):
+    """Instructions of a compiled program that XLA added to move the pool:
+    rematerialisation clones (``fusion.7.remat_compressed``), and every
+    copy, slice, update or fusion whose result has as many elements as one
+    layer's pool leaf. The in-place program has none: its only
+    instructions of that size are the aliased Pallas writes."""
+    found = []
+    for name, dims, op, _ in _INSTRUCTION.findall(text):
+        elems = 1
+        for n in filter(None, dims.split(",")):
+            elems *= int(n)
+        if ".remat" in name or (elems == leaf_elems and op in (
+                "copy", "slice", "dynamic-slice", "dynamic-update-slice",
+                "scatter", "fusion")):
+            found.append(f"{name} = [{dims}] {op}")
+    return found
+
+
+#: cell 3 of the benchmark (gpt2-medium, 64 clients): the widths, pool and
+#: batch at which XLA cloned and copied the pool before PR 24
+CELL3 = dict(vocab=50304, hidden=1024, heads=16, max_seq_len=1024,
+             max_prompt_len=512, max_batch=64, num_pages=385)
+
+
+def test_serve_programs_update_the_pool_in_place(chip, chip_smoke):
+    """Two layers of cell 3 (the census does not depend on depth: at the
+    parent the same two layers hold 8 copies and 4 slices of a layer's
+    pool in decode, 4 copies in prefill)."""
+    sz = dataclasses.replace(chip_smoke.FULL, layers=2, **CELL3)
+    eng, decode, prefill = _compile_serve(chip, chip_smoke, sz)
+    leaf = eng.state.pools[0].size
+    for program in (decode, prefill):
+        assert _pool_traffic(program.as_text(), leaf) == []
+        assert program.memory_analysis().alias_size_in_bytes \
+            >= eng.ccfg.pool_bytes()
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("shapes,fp8_kv", [("smoke", False), ("smoke", True),
+                                           ("cell3", False)])
+def test_serve_programs_compile_for_v5e(chip, chip_smoke, shapes, fp8_kv):
+    """Decode and prefill at full depth, at the shapes chip_smoke serves
+    and at cell 3's: the kernels are there, the pool is updated in place,
+    and the programs need next to nothing beside their arguments (cell 3's
+    decode took 7.39 GB of temporaries for its pool copies before PR 24)."""
+    smoke = chip_smoke
+    sz = smoke.FULL if shapes == "smoke" else dataclasses.replace(
+        smoke.FULL, layers=24, **CELL3)
+    eng, decode, prefill = _compile_serve(chip, smoke, sz, fp8_kv)
+    smoke._require_kernels(smoke._kernel_calls(decode.as_text()),
+                           paged_attn=1)
+    smoke._require_kernels(smoke._kernel_calls(prefill.as_text()),
+                           flash_attention=1)
+    leaf = eng.state.pools[0].size
+    for program in (decode, prefill):
+        assert _pool_traffic(program.as_text(), leaf) == []
+        assert program.memory_analysis().temp_size_in_bytes < 0.5e9
