@@ -22,7 +22,11 @@ existing subsystems on the decode hot path:
   regex tables producing real PartitionSpecs for the cache (heads over
   the tensor axis) and the GPT param tree;
 - ``monitor.profile`` scopes thread prefill/decode attribution through
-  the existing analytic walk.
+  the existing analytic walk;
+- the **model interface** (:class:`~apex_tpu.serve.model.GPTServed`):
+  what the engine asks of a model. GPT answers it here; the
+  latent-attention model answers it in :mod:`apex_tpu.serve.deepseek`
+  (imported on demand, not by this package).
 
 Quick start (see ``examples/serve_gpt.py`` / ``docs/serve.md``)::
 

@@ -8,6 +8,10 @@ contract in ``ops.flash_attention``, above its paged kernels):
     pools[layer]      [kv_heads, num_pages, page_size, 2*d]
                       one leaf a layer; a token's K in lanes 0:d, its V
                       in d:2d
+                      (a model that caches something else a token gives
+                      the lanes of its row as ``row_width``: a latent
+                      cache is ``kv_heads=1`` and one padded latent row,
+                      ``serve/deepseek.py``)
     k_scale / v_scale [num_layers, kv_heads, num_pages]  f32 (fp8 mode)
 
 One leaf a layer, because a program that updates and reads 2 x L slices
@@ -98,6 +102,8 @@ class CacheConfig:
     dtype: Any = jnp.bfloat16      # pool dtype (ignored when fp8)
     fp8: bool = False
     fp8_margin: float = 2.0        # 2**margin headroom over the slot-0 amax
+    #: lanes of one token's row in a head's page; None = K beside V
+    row_width: Optional[int] = None
 
     def __post_init__(self):
         if self.num_pages < 2:
@@ -111,6 +117,11 @@ class CacheConfig:
         return fp8_mod.E4M3 if self.fp8 else jnp.dtype(self.dtype)
 
     @property
+    def width(self) -> int:
+        return 2 * self.head_dim if self.row_width is None \
+            else self.row_width
+
+    @property
     def usable_pages(self) -> int:
         return self.num_pages - 1
 
@@ -121,9 +132,10 @@ class CacheConfig:
     #    about fp8 capacity come from HERE, not from hand-waving) ------
 
     def bytes_per_page(self) -> int:
-        """HBM bytes one pool page costs across k+v (+ fp8 scales)."""
-        elems = self.kv_heads * self.page_size * self.head_dim
-        per = 2 * elems * jnp.dtype(self.pool_dtype).itemsize
+        """HBM bytes one pool page costs across its rows as they are held,
+        padding included (+ fp8 scales)."""
+        elems = self.kv_heads * self.page_size * self.width
+        per = elems * jnp.dtype(self.pool_dtype).itemsize
         if self.fp8:
             per += 2 * self.kv_heads * 4          # k_scale + v_scale rows
         return per * self.num_layers
@@ -151,13 +163,13 @@ class CacheConfig:
 class CacheState(NamedTuple):
     """The device pytree the jitted steps thread and donate."""
 
-    pools: Tuple[jax.Array, ...]   # one [kv, pages, page_size, 2d] a layer
+    pools: Tuple[jax.Array, ...]   # one [kv, pages, page_size, width] a layer
     k_scale: Optional[jax.Array]   # None outside fp8 mode
     v_scale: Optional[jax.Array]
 
 
 def init_cache(cfg: CacheConfig) -> CacheState:
-    shape = (cfg.kv_heads, cfg.num_pages, cfg.page_size, 2 * cfg.head_dim)
+    shape = (cfg.kv_heads, cfg.num_pages, cfg.page_size, cfg.width)
     # DISTINCT arrays, here and for the scales — aliased leaves break
     # the donated step (donate-same-buffer-twice)
     pools = tuple(jnp.zeros(shape, cfg.pool_dtype)
@@ -226,14 +238,37 @@ def write_token(cfg: CacheConfig, state: CacheState, layer: int,
         k_t = k_t.astype(cfg.pool_dtype)
         v_t = v_t.astype(cfg.pool_dtype)
     rows = jnp.concatenate([k_t, v_t], axis=-1)    # [b, kv, 2d]
-    if impl == "kernel":
-        pool = paged_kv_write_rows(state.pools[layer], page_ids, slots,
-                                   rows, interpret=interpret)
-    else:
-        # adjacent index arrays stay in place: the update is [kv, b, 2d]
-        pool = state.pools[layer].at[:, page_ids, slots].set(
-            rows.transpose(1, 0, 2))
+    pool = _store_token_rows(state.pools[layer], page_ids, slots, rows,
+                             impl, interpret)
     return _with_layer(state, layer, pool, k_scale, v_scale)
+
+
+def _store_token_rows(pool, page_ids, slots, rows, impl, interpret):
+    if impl == "kernel":
+        return paged_kv_write_rows(pool, page_ids, slots, rows,
+                                   interpret=interpret)
+    # adjacent index arrays stay in place: the update is [kv, b, width]
+    return pool.at[:, page_ids, slots].set(rows.transpose(1, 0, 2))
+
+
+@_prof.scoped("kv_write")
+def write_token_rows(cfg: CacheConfig, state: CacheState, layer: int,
+                     page_ids, slots, rows, *, impl: str = "reference",
+                     interpret: Optional[bool] = None) -> CacheState:
+    """:func:`write_token` for a model that builds its own rows:
+    ``rows`` ``[b, kv_heads, cfg.width]``, one a batch slot."""
+    _check_impl(impl)
+    _no_fp8_rows(cfg)
+    pool = _store_token_rows(state.pools[layer], page_ids, slots,
+                             rows.astype(cfg.pool_dtype), impl, interpret)
+    return _with_layer(state, layer, pool, state.k_scale, state.v_scale)
+
+
+def _no_fp8_rows(cfg: CacheConfig):
+    if cfg.fp8:
+        raise NotImplementedError(
+            "fp8 pages hold a K and a V scale a page: rows of another "
+            "kind are not quantized (write_token / write_prompt are)")
 
 
 @_prof.scoped("kv_write")
@@ -275,10 +310,31 @@ def write_prompt(cfg: CacheConfig, state: CacheState, layer: int,
         k_t = k_t.astype(cfg.pool_dtype)
         v_t = v_t.astype(cfg.pool_dtype)
     rows = jnp.concatenate([k_t, v_t], axis=-1)    # [S, kv, 2d]
-    if impl == "kernel":
-        pool = paged_kv_write_pages(state.pools[layer], block_table, length,
-                                    rows, interpret=interpret)
-    else:
-        pool = state.pools[layer].at[:, pages, pos % cfg.page_size].set(
-            rows.transpose(1, 0, 2))
+    pool = _store_prompt_rows(cfg, state.pools[layer], block_table, length,
+                              pages, pos, rows, impl, interpret)
     return _with_layer(state, layer, pool, k_scale, v_scale)
+
+
+def _store_prompt_rows(cfg, pool, block_table, length, pages, pos, rows,
+                       impl, interpret):
+    if impl == "kernel":
+        return paged_kv_write_pages(pool, block_table, length, rows,
+                                    interpret=interpret)
+    return pool.at[:, pages, pos % cfg.page_size].set(
+        rows.transpose(1, 0, 2))
+
+
+@_prof.scoped("kv_write")
+def write_prompt_rows(cfg: CacheConfig, state: CacheState, layer: int,
+                      block_table, length, rows, *, impl: str = "reference",
+                      interpret: Optional[bool] = None) -> CacheState:
+    """:func:`write_prompt` for a model that builds its own rows:
+    ``rows`` ``[S, kv_heads, cfg.width]``, one a (padded) position."""
+    _check_impl(impl)
+    _no_fp8_rows(cfg)
+    pos = jnp.arange(rows.shape[0], dtype=jnp.int32)
+    pages = jnp.where(pos < length, block_table[pos // cfg.page_size], 0)
+    pool = _store_prompt_rows(cfg, state.pools[layer], block_table, length,
+                              pages, pos, rows.astype(cfg.pool_dtype), impl,
+                              interpret)
+    return _with_layer(state, layer, pool, state.k_scale, state.v_scale)

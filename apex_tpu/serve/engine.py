@@ -93,15 +93,19 @@ _REPLICA_SEQ_LOCK = threading.Lock()
 
 
 class ServeEngine:
-    """Paged-KV-cache GPT serving on one host (optionally TP-sharded).
+    """Paged-cache serving on one host (optionally TP-sharded).
 
-    ``params`` is the full (tp=1 layout) ``models.gpt.GPT`` parameter
-    tree (``variables["params"]``). Sampling is greedy argmax —
+    ``cfg`` is a ``models.gpt.GPTConfig`` with ``params`` the full (tp=1
+    layout) ``models.gpt.GPT`` parameter tree (``variables["params"]``),
+    or any object that answers the model interface
+    (:class:`apex_tpu.serve.model.GPTServed` spells it out) with that
+    model's tree: the engine, the scheduler and the page allocator know
+    nothing of the model but that interface. Sampling is greedy argmax —
     deterministic by design, which the preempt/resume bit-exactness
     contract relies on.
     """
 
-    def __init__(self, cfg: GPTConfig, params, *, num_pages: int,
+    def __init__(self, cfg, params, *, num_pages: int,
                  max_seq_len: int, max_prompt_len: int,
                  page_size: Optional[int] = None, max_batch: int = 4,
                  fp8_kv: bool = False, fp8_margin: float = 2.0,
@@ -118,14 +122,17 @@ class ServeEngine:
                  fp8_weights: bool = False,
                  fp8_weight_margin: float = 0.0):
         d_impl, p_impl = _default_impls()
-        self.cfg = cfg
+        self.model = model = model_mod.as_served(cfg)
+        self.cfg = cfg = model.cfg
+        self.tp = ps.get_tensor_model_parallel_world_size()
+        model.check(tp=self.tp, fp8_kv=fp8_kv, fp8_weights=fp8_weights,
+                    spec_k=spec_k)
         self.fp8_weights = bool(fp8_weights)
         if fp8_weights:
             # one-time e4m3 encode of the block linear kernels: same
             # tree shape (+ scalar scale leaves), so the TP rules and
             # shard_map specs below apply unchanged
-            params = model_mod.quantize_gpt_weights(
-                cfg, params, margin=fp8_weight_margin)
+            params = model.quantize_weights(params, margin=fp8_weight_margin)
         self.params = params
         # stable replica identity for fleet telemetry: labels every
         # exported sample (monitor.export) and keys this engine in a
@@ -142,31 +149,20 @@ class ServeEngine:
         self.attention_impl = attention_impl or p_impl
         self.interpret = interpret
         self.autotune = autotune
-        self.tp = ps.get_tensor_model_parallel_world_size()
-        if cfg.num_heads % self.tp:
-            raise ValueError(f"num_heads {cfg.num_heads} not divisible "
-                             f"by tp {self.tp}")
-        head_dim = cfg.hidden_size // cfg.num_heads
-        # the pool is allocated at GLOBAL head count — under tp the
-        # shard_map in_specs split the heads dim, each rank holding its
-        # local heads' pages; page-size resolution sees the PER-RANK
-        # kernel geometry
         psize = cache_mod.resolve_page_size(
-            kv_heads=cfg.num_heads // self.tp, head_dim=head_dim,
-            context_len=max_seq_len, dtype=cfg.dtype, fp8=fp8_kv,
-            batch=max_batch, page_size=page_size, autotune=autotune)
-        if max_seq_len > cfg.max_seq_len:
+            **model.page_geometry(self.tp), context_len=max_seq_len,
+            fp8=fp8_kv, batch=max_batch, page_size=page_size,
+            autotune=autotune)
+        if max_seq_len > model.max_seq_len:
             raise ValueError(f"max_seq_len {max_seq_len} exceeds the "
-                             f"model's {cfg.max_seq_len}")
+                             f"model's {model.max_seq_len}")
         if max_prompt_len > max_seq_len:
             raise ValueError("max_prompt_len exceeds max_seq_len")
         self.max_seq_len = max_seq_len
         self.max_prompt_len = max_prompt_len
         self.pages_per_seq = -(-max_seq_len // psize)
-        self.ccfg = cache_mod.CacheConfig(
-            num_layers=cfg.num_layers, kv_heads=cfg.num_heads,
-            head_dim=head_dim, num_pages=num_pages, page_size=psize,
-            dtype=cfg.dtype, fp8=fp8_kv, fp8_margin=fp8_margin)
+        self.ccfg = model.cache_config(num_pages=num_pages, page_size=psize,
+                                       fp8=fp8_kv, fp8_margin=fp8_margin)
         self.state = cache_mod.init_cache(self.ccfg)
         self.spec_k = int(spec_k)
         if self.spec_k < 0:
@@ -189,30 +185,26 @@ class ServeEngine:
                                  "sequential writes)")
             if draft_params is None:
                 layers = draft_num_layers or max(1, cfg.num_layers // 2)
-                self.draft_cfg, self.draft_params = spec_mod.derive_draft(
-                    cfg, self.params, num_layers=layers)
+                self.draft_model, self.draft_params = model.derive_draft(
+                    self.params, num_layers=layers)
             else:
                 if draft_cfg is None:
                     raise ValueError("draft_params requires draft_cfg")
-                self.draft_cfg = draft_cfg
+                self.draft_model = model_mod.as_served(draft_cfg)
                 self.draft_params = (
-                    model_mod.quantize_gpt_weights(
-                        draft_cfg, draft_params, margin=fp8_weight_margin)
+                    self.draft_model.quantize_weights(
+                        draft_params, margin=fp8_weight_margin)
                     if fp8_weights else draft_params)
-            if self.draft_cfg.num_heads % self.tp:
-                raise ValueError(f"draft num_heads "
-                                 f"{self.draft_cfg.num_heads} not "
-                                 f"divisible by tp {self.tp}")
+            self.draft_cfg = self.draft_model.cfg
+            try:
+                self.draft_model.check(tp=self.tp)
+            except ValueError as e:
+                raise ValueError(f"draft {e}") from None
             # the draft pool mirrors the target pool's geometry
             # (num_pages, page_size) so the draft REUSES each
             # sequence's block table — zero new allocator state
-            self.draft_ccfg = cache_mod.CacheConfig(
-                num_layers=self.draft_cfg.num_layers,
-                kv_heads=self.draft_cfg.num_heads,
-                head_dim=(self.draft_cfg.hidden_size
-                          // self.draft_cfg.num_heads),
-                num_pages=num_pages, page_size=psize,
-                dtype=self.draft_cfg.dtype)
+            self.draft_ccfg = self.draft_model.cache_config(
+                num_pages=num_pages, page_size=psize)
             self.draft_state = cache_mod.init_cache(self.draft_ccfg)
         self.sched = Scheduler(num_pages=num_pages, page_size=psize,
                                max_batch=max_batch,
@@ -221,6 +213,7 @@ class ServeEngine:
         self.slots: List[Optional[Sequence]] = [None] * max_batch
         self.record_logits = record_logits
         self.logits_log: Dict[int, Dict[int, np.ndarray]] = {}
+        self.aux_log: Dict[int, Dict[int, dict]] = {}
         self.decode_step_times: List[float] = []
         self.tokens_generated = 0
         self._next_id = 0
@@ -230,32 +223,34 @@ class ServeEngine:
     # -- jitted steps ------------------------------------------------
 
     def _build_steps(self):
-        cfg, ccfg = self.cfg, self.ccfg
+        model, ccfg = self.model, self.ccfg
 
+        # ``aux`` (the model's own small outputs, {} for GPT) leaves the
+        # program beside the logits: no leaf, no output, same program
         def decode(params, state, bt, pos, tok, act):
-            logits, state = model_mod.decode_forward(
-                cfg, ccfg, params, state, bt, pos, tok, act,
+            logits, state, aux = model.decode(
+                ccfg, params, state, bt, pos, tok, act,
                 paged_impl=self.paged_impl, interpret=self.interpret,
                 autotune=self.autotune)
             return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-                state
+                state, aux
 
         def prefill(params, state, bt, length, ids):
-            logits, state = model_mod.prefill_forward(
-                cfg, ccfg, params, state, bt, length, ids,
+            logits, state, aux = model.prefill(
+                ccfg, params, state, bt, length, ids,
                 attention_impl=self.attention_impl,
                 interpret=self.interpret, autotune=self.autotune)
             return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-                state
+                state, aux
 
         draft = None
         if self.spec_k:
-            dcfg, dccfg = self.draft_cfg, self.draft_ccfg
+            dmodel, dccfg = self.draft_model, self.draft_ccfg
 
             def draft(params, state, bt, pos, tok, act):
                 # greedy draft: only the argmaxes leave the program
-                logits, state = model_mod.decode_forward(
-                    dcfg, dccfg, params, state, bt, pos, tok, act,
+                logits, state, _ = dmodel.decode(
+                    dccfg, params, state, bt, pos, tok, act,
                     paged_impl=self.paged_impl, interpret=self.interpret,
                     autotune=self.autotune)
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32), state
@@ -264,9 +259,9 @@ class ServeEngine:
             mesh = ps.get_mesh()
             from jax.sharding import NamedSharding, PartitionSpec as P
             pspec = rules_mod.match_serve_rules(
-                rules_mod.GPT_PARAM_RULES, self.params, world=self.tp)
+                model.param_rules, self.params, world=self.tp)
             cspec = rules_mod.match_serve_rules(
-                rules_mod.CACHE_RULES, self.state, world=self.tp)
+                model.cache_rules, self.state, world=self.tp)
 
             def place(tree, spec):
                 # once, in the layout the step programs' in_specs name:
@@ -282,18 +277,16 @@ class ServeEngine:
             decode = shard_map(
                 decode, mesh=mesh,
                 in_specs=(pspec, cspec, P(), P(), P(), P()),
-                out_specs=(P(), P(), cspec), check_vma=False)
+                out_specs=(P(), P(), cspec, P()), check_vma=False)
             prefill = shard_map(
                 prefill, mesh=mesh,
                 in_specs=(pspec, cspec, P(), P(), P()),
-                out_specs=(P(), P(), cspec), check_vma=False)
+                out_specs=(P(), P(), cspec, P()), check_vma=False)
             if draft is not None:
                 dpspec = rules_mod.match_serve_rules(
-                    rules_mod.GPT_PARAM_RULES, self.draft_params,
-                    world=self.tp)
+                    dmodel.param_rules, self.draft_params, world=self.tp)
                 dcspec = rules_mod.match_serve_rules(
-                    rules_mod.CACHE_RULES, self.draft_state,
-                    world=self.tp)
+                    dmodel.cache_rules, self.draft_state, world=self.tp)
                 self.draft_params = place(self.draft_params, dpspec)
                 self.draft_state = place(self.draft_state, dcspec)
                 draft = shard_map(
@@ -338,10 +331,26 @@ class ServeEngine:
         row[:len(seq.pages)] = seq.pages
         return row
 
-    def _record(self, seq: Sequence, pos: int, logits_row) -> None:
-        if self.record_logits:
-            self.logits_log.setdefault(seq.seq_id, {})[pos] = \
-                np.asarray(logits_row)
+    def _record(self, seq: Sequence, pos: int, logits_row, aux=None,
+                row=None) -> None:
+        """Under ``record_logits``: the logits that predict position
+        ``pos`` and, where the model gives per-row ``aux``, its values
+        for that row (``row`` = the batch row; None = a prefill's one)."""
+        if not self.record_logits:
+            return
+        self.logits_log.setdefault(seq.seq_id, {})[pos] = \
+            np.asarray(logits_row)
+        if aux and aux.get("rows"):
+            self.aux_log.setdefault(seq.seq_id, {})[pos] = {
+                k: np.asarray(v if row is None else v[row])
+                for k, v in aux["rows"].items()}
+
+    def _record_round(self, aux) -> None:
+        """The model's own counters of a decode round (``aux["round"]``):
+        fetched once the tokens are on the host, so the fetch waits for
+        nothing; skipped without a recorder."""
+        if aux and aux.get("round") and _mhooks.enabled():
+            self.model.record_round(jax.device_get(aux["round"]))
 
     def _free_slot(self, seq: Sequence) -> None:
         for i, s in enumerate(self.slots):
@@ -387,10 +396,10 @@ class ServeEngine:
             pos[slot] = j
             act[slot] = True
             bts[slot] = self._bt_row(seq)
-            logits, _, self.state = self._decode(
+            logits, _, self.state, aux = self._decode(
                 self.params, self.state, jnp.asarray(bts),
                 jnp.asarray(pos), jnp.asarray(tok), jnp.asarray(act))
-            self._record(seq, j + 1, logits[slot])
+            self._record(seq, j + 1, logits[slot], aux, slot)
             seq.num_cached = j + 1
 
     # -- speculative decoding ----------------------------------------
@@ -471,7 +480,7 @@ class ServeEngine:
         t0 = time.perf_counter()
         with _mspans.span("serve/verify", parent=seq.span,
                           seq_id=seq.seq_id, rows=k + 1):
-            logits, next_toks, self.state = self._decode(
+            logits, next_toks, self.state, aux = self._decode(
                 self.params, self.state, jnp.asarray(bts),
                 jnp.asarray(pos), jnp.asarray(tok), jnp.asarray(act))
             next_np = np.asarray(next_toks)
@@ -493,7 +502,7 @@ class ServeEngine:
             _mhooks.observe("serve/spec_accept_rate", m / k)
         for i, t in enumerate(committed):
             if logits_np is not None:
-                self._record(seq, n + i, logits_np[i])
+                self._record(seq, n + i, logits_np[i], aux, i)
             self._sample(seq, t)
         if _mhooks.enabled():
             per_tok = 1e3 * dt / len(committed)
@@ -519,14 +528,14 @@ class ServeEngine:
         with _mspans.span("serve/prefill", seq_id=seq.seq_id,
                           resumed=resumed,
                           prompt_tokens=len(seq.prompt)):
-            logits, next_tok, self.state = self._prefill(
+            logits, next_tok, self.state, aux = self._prefill(
                 self.params, self.state, jnp.asarray(self._bt_row(seq)),
                 jnp.int32(len(seq.prompt)), jnp.asarray(ids))
             seq.num_cached = len(seq.prompt)
             if not resumed:
                 next_tok = int(next_tok)
         _mhooks.counter("serve/prefills")
-        self._record(seq, len(seq.prompt), logits)
+        self._record(seq, len(seq.prompt), logits, aux)
         if not resumed:
             self._sample(seq, next_tok)
         else:
@@ -597,13 +606,16 @@ class ServeEngine:
             batch = (jnp.asarray(bts), jnp.asarray(pos), jnp.asarray(tok),
                      jnp.asarray(act))
         with _mspans.span("serve/decode_step", n_active=len(decodes)):
-            logits, next_toks, self.state = self._decode(
+            logits, next_toks, self.state, aux = self._decode(
                 self.params, self.state, *batch)
             next_np = np.asarray(next_toks)
         logits_np = np.asarray(logits) if self.record_logits else None
         dt = time.perf_counter() - t0
         self.decode_step_times.append(dt)
         with _mspans.span("serve/sample"):
+            self._record_round(aux)
+            if logits_np is not None:
+                aux = jax.device_get(aux)
             if _mhooks.enabled():
                 # per-TOKEN latency: each active slot produced one
                 # token this step — the streaming-percentile source of
@@ -616,7 +628,8 @@ class ServeEngine:
                 slot = seq.slot
                 seq.num_cached = seq.num_tokens
                 if logits_np is not None:
-                    self._record(seq, seq.num_tokens, logits_np[slot])
+                    self._record(seq, seq.num_tokens, logits_np[slot], aux,
+                                 slot)
                 self._sample(seq, next_np[slot])
 
     def _record_step_gauges(self) -> None:

@@ -40,6 +40,7 @@ from apex_tpu.ops.flash_attention import (
     flash_attention, mha_reference, paged_attention_reference,
     paged_decode_attention)
 from apex_tpu.serve import cache as cache_mod
+from apex_tpu.serve import rules as rules_mod
 from apex_tpu.transformer import parallel_state as ps
 from apex_tpu.transformer.tensor_parallel import (
     ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding,
@@ -47,6 +48,105 @@ from apex_tpu.transformer.tensor_parallel import (
 
 PAGED_IMPLS = ("reference", "kernel")
 PREFILL_IMPLS = ("reference", "flash")
+
+
+class GPTServed:
+    """GPT behind the engine's model interface.
+
+    **The interface** (``docs/serve.md``): what ``ServeEngine`` asks of a
+    model, so that it, the scheduler and the page allocator serve any
+    model that answers. An object with
+
+    - ``cfg`` (the model's static sizes), ``max_seq_len``;
+    - ``check(tp=, fp8_kv=, fp8_weights=, spec_k=)``: raises for what the
+      model cannot be served with, at engine construction;
+    - ``page_geometry(tp)``: the per-rank kernel geometry
+      ``serve.cache.resolve_page_size`` takes (``kv_heads``, ``head_dim``,
+      ``dtype``);
+    - ``cache_config(num_pages=, page_size=, fp8=, fp8_margin=)``: the
+      ``CacheConfig`` of its pool: layers, the geometry of a layer's leaf
+      and of one token's row in it;
+    - ``prefill(ccfg, params, state, block_table, length, ids, **impls)``
+      and ``decode(ccfg, params, state, block_tables, positions, tokens,
+      active, **impls)``, jit-pure, returning ``(logits f32, new state,
+      aux)``. ``aux`` is ``{}`` or ``{"rows": {...}, "round": {...}}``:
+      small arrays that leave the program beside the logits: ``rows``,
+      kept with the logits under ``record_logits`` (a decode step's with
+      one entry a batch row, a prefill's whole), and ``round`` for
+      ``record_round``;
+    - ``record_round(aux_round)``: the host's side of ``aux["round"]`` (the
+      model's own counters), called with numpy values while a recorder is
+      attached;
+    - ``param_rules`` / ``cache_rules``: sharding rules for ``tp > 1``;
+    - ``quantize_weights(params, margin=)`` and ``derive_draft(params,
+      num_layers=)`` for ``fp8_weights`` and ``spec_k``.
+    """
+
+    param_rules = rules_mod.GPT_PARAM_RULES
+    cache_rules = rules_mod.CACHE_RULES
+
+    def __init__(self, cfg: GPTConfig):
+        self.cfg = cfg
+
+    @property
+    def max_seq_len(self) -> int:
+        return self.cfg.max_seq_len
+
+    @property
+    def head_dim(self) -> int:
+        return self.cfg.hidden_size // self.cfg.num_heads
+
+    def check(self, *, tp: int, **_):
+        if self.cfg.num_heads % tp:
+            raise ValueError(f"num_heads {self.cfg.num_heads} not "
+                             f"divisible by tp {tp}")
+
+    def page_geometry(self, tp: int) -> dict:
+        # the pool is allocated at GLOBAL head count: under tp the
+        # shard_map in_specs split the heads dim, each rank holding its
+        # local heads' pages; page-size resolution sees the PER-RANK
+        # kernel geometry
+        return dict(kv_heads=self.cfg.num_heads // tp,
+                    head_dim=self.head_dim, dtype=self.cfg.dtype)
+
+    def cache_config(self, *, num_pages: int, page_size: int,
+                     fp8: bool = False, fp8_margin: float = 2.0):
+        return cache_mod.CacheConfig(
+            num_layers=self.cfg.num_layers, kv_heads=self.cfg.num_heads,
+            head_dim=self.head_dim, num_pages=num_pages,
+            page_size=page_size, dtype=self.cfg.dtype, fp8=fp8,
+            fp8_margin=fp8_margin)
+
+    def prefill(self, ccfg, params, state, block_table, length, ids, **kw):
+        logits, state = prefill_forward(self.cfg, ccfg, params, state,
+                                        block_table, length, ids, **kw)
+        return logits, state, {}
+
+    def decode(self, ccfg, params, state, block_tables, positions, tokens,
+               active, **kw):
+        logits, state = decode_forward(self.cfg, ccfg, params, state,
+                                       block_tables, positions, tokens,
+                                       active, **kw)
+        return logits, state, {}
+
+    def record_round(self, aux_round) -> None:
+        pass
+
+    def quantize_weights(self, params, *, margin: float = 0.0):
+        return quantize_gpt_weights(self.cfg, params, margin=margin)
+
+    def derive_draft(self, params, *, num_layers: int):
+        from apex_tpu.serve import spec as spec_mod
+        cfg, draft = spec_mod.derive_draft(self.cfg, params,
+                                           num_layers=num_layers)
+        return GPTServed(cfg), draft
+
+
+def as_served(model):
+    """The engine's first argument as a served model: a ``GPTConfig``
+    (what the engine has always taken) is wrapped, anything else is taken
+    to answer the interface itself."""
+    return GPTServed(model) if isinstance(model, GPTConfig) else model
 
 
 def _mods(cfg: GPTConfig):

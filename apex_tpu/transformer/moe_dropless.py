@@ -1,0 +1,129 @@
+"""A dropless expert layer that is told which experts it holds.
+
+The capacity-based layer of :mod:`apex_tpu.transformer.moe` (top-1/top-2,
+one-hot ``[t, E, C]`` dispatch, dropped tokens) cannot express top-8 of
+256. This one routes every token over ALL ``n_routed_experts`` with the
+DeepSeek-V3 rule, keeps the assignments whose expert lies in
+``[first_expert, first_expert + n_local)``, lays them out by expert
+(``ops.grouped_matmul.tile_layout``), runs gate/up and down as two grouped
+matmuls, gathers each token's rows back weighted, and adds the shared
+expert. No token is dropped, whatever the load.
+
+With ``n_local == n_routed_experts`` that is the whole layer. With fewer
+it is one chip's part of an expert-parallel layer: what the absent experts
+would add is NOT here (no exchange, and nothing that stands in for the
+other chips); summed over the shares, with the shared expert (which every
+chip computes alike) counted once, the parts are the whole layer
+(``tests/test_deepseek.py``).
+
+Routing (float32, as published): ``sc = sigmoid(x W_g)``; the correction
+bias moves the CHOICE only (``sc + b``); a group's score is the sum of its
+two best corrected scores, the best ``topk_group`` groups stay, the others'
+scores become 0; the top ``k`` of what is left are chosen; the weights are
+the UNcorrected scores of the chosen, normalised to sum to
+``routed_scaling_factor``.
+
+No operation mixes tokens: a token's output row depends on its own input
+alone (its position among an expert's rows changes which tile row computes
+it, not what is computed), which the serve engine's bit-exact replay rests
+on.
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from apex_tpu.models.deepseek import DeepseekConfig, gated_mlp
+from apex_tpu.monitor import profile as _prof
+from apex_tpu.ops import grouped_matmul as gmm
+
+#: rows of a tile of the grouped matmul: a decode step brings a handful of
+#: rows an expert (weight-streaming-bound whatever the tile: 16 / 32 / 64
+#: read 1.39 / 1.35 / 1.34 ms on the chip), a prompt some dozens
+BLOCK_M_DECODE, BLOCK_M_PREFILL = 32, 128
+
+
+def route(cfg: DeepseekConfig, router, bias, x):
+    """``(idx [t, k] int32, w [t, k] f32)`` over all routed experts."""
+    t = x.shape[0]
+    E, G = cfg.n_routed_experts, cfg.n_group
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    sc = jax.nn.sigmoid(logits)
+    cor = sc + bias.astype(jnp.float32)
+    best2 = jax.lax.top_k(cor.reshape(t, G, E // G), 2)[0].sum(-1)  # [t, G]
+    groups = jax.lax.top_k(best2, cfg.topk_group)[1]                # [t, kg]
+    keep = (groups[:, :, None] == jnp.arange(G)[None, None, :]).any(1)
+    masked = jnp.where(jnp.repeat(keep, E // G, axis=1), cor, 0.0)
+    idx = jax.lax.top_k(masked, cfg.num_experts_per_tok)[1]
+    w = jnp.take_along_axis(sc, idx, axis=1)
+    if cfg.norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * cfg.routed_scaling_factor
+
+
+def expert_layer(cfg: DeepseekConfig, p, x, *, active=None,
+                 impl: str = "kernel", interpret: Optional[bool] = None):
+    """This share's part of the expert layer for rows ``x`` ``[t, h]``.
+
+    ``p``: a layer's ``moe`` sub-tree (``models.deepseek``). ``active``
+    ``[t]`` bool masks rows that carry no token (a decode batch's empty
+    slots): they are routed nowhere and counted nowhere. Returns ``(y [t,
+    h], stats)`` with ``stats`` = ``{"idx": chosen experts [t, k],
+    "assignments_local": [], "expert_load_max": [], "experts_touched": []}``
+    (int32 scalars over the active rows: what this share was handed, its
+    fullest expert's rows, and how many of its experts got any).
+    ``impl``: the grouped matmul's (``ops.grouped_matmul.IMPLS``)."""
+    t, h = x.shape
+    k, nl = cfg.num_experts_per_tok, cfg.local_experts
+    bm = BLOCK_M_DECODE if t * k <= 4096 else BLOCK_M_PREFILL
+    max_rows = t * min(k, nl)
+    rows_padded = gmm.num_tiles(nl, bm, max_rows) * bm
+    with _prof.scope("moe"):
+        with _prof.scope("moe_route"):
+            idx, w = route(cfg, p["router"], p["bias"], x)
+            local = idx - cfg.first_expert
+            here = (local >= 0) & (local < nl)
+            if active is not None:
+                here = here & active[:, None]
+            group = jnp.where(here, local, nl).reshape(-1)       # [t*k]
+            onehot = (group[:, None] == jnp.arange(nl)[None, :]
+                      ).astype(jnp.int32)                        # [t*k, nl]
+            counts = onehot.sum(0)
+            rank = jnp.take_along_axis(
+                jnp.cumsum(onehot, axis=0), jnp.minimum(group, nl - 1)[:, None],
+                axis=1)[:, 0] - 1
+            starts, tile_group, used = gmm.tile_layout(counts, bm, max_rows)
+            dest = jnp.where(group < nl,
+                             starts[jnp.minimum(group, nl - 1)] + rank,
+                             rows_padded)                        # [t*k]
+            # the token each padded row holds (padding rows: token 0; their
+            # results are never read)
+            src = jnp.zeros((rows_padded,), jnp.int32).at[dest].set(
+                jnp.arange(t * k, dtype=jnp.int32) // k, mode="drop")
+            xs = jnp.take(x, src, axis=0)
+        with _prof.scope("moe_experts"):
+            ex = p["experts"]
+            kw = dict(block_m=bm, impl=impl, interpret=interpret)
+            gu = gmm.grouped_matmul(xs, ex["gate_up"], tile_group, used,
+                                    **kw).astype(jnp.float32)
+            im = gu.shape[1] // 2
+            act = (jax.nn.silu(gu[:, :im]) * gu[:, im:]).astype(x.dtype)
+            ys = gmm.grouped_matmul(act, ex["down"], tile_group, used, **kw)
+        with _prof.scope("moe_combine"):
+            rows = jnp.take(ys, jnp.minimum(dest, rows_padded - 1),
+                            axis=0).reshape(t, k, h)
+            # an absent expert's row index points at a row nobody wrote
+            rows = jnp.where(here[:, :, None], rows.astype(jnp.float32), 0.0)
+            y = jnp.einsum("tk,tkh->th", jnp.where(here, w, 0.0), rows)
+        with _prof.scope("moe_shared"):
+            sh = p["shared"]
+            y = y + gated_mlp(x, sh["gate"], sh["up"],
+                              sh["down"]).astype(jnp.float32)
+    stats = {"idx": idx, "assignments_local": counts.sum(),
+             "expert_load_max": counts.max(),
+             "experts_touched": (counts > 0).sum()}
+    return y.astype(x.dtype), stats

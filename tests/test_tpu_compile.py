@@ -174,6 +174,45 @@ def _shard_update(chip):
     return fn, (x, x, x, x, _sds(chip, (), I32))
 
 
+def _flash_fwd_d192(chip):
+    """Prefill's expanded latent attention: one padded 1,024-token prompt,
+    64 heads of 128 + 64 for q and k, 192 for v, unpadded."""
+    q = _sds(chip, (1, 64, 1024, 192), BF16)
+    return functools.partial(flash_attention, causal=True, scale=0.14468,
+                             interpret=False), (q, q, q)
+
+
+def _mla_decode(chip):
+    """The latent decode kernel at the benchmark cell's shape: 256 rows of
+    64 heads against pages of 128 rows of 640 lanes (512 + 64, padded)."""
+    from apex_tpu.ops.mla_attention import mla_decode_attention
+    return (functools.partial(mla_decode_attention, value_dim=512,
+                              scale=0.14468, interpret=False),
+            (_sds(chip, (256, 64, 640), BF16),
+             _sds(chip, (1, 5121, 128, 640), BF16),
+             _sds(chip, (256, 20), I32), _sds(chip, (256,), I32)))
+
+
+def _grouped_matmul(block_m, rows):
+    """An expert layer's two grouped matmuls over 16 held experts at the
+    published widths: decode's layout (tiles of 32 rows) and a prompt's
+    (128)."""
+    def build(chip):
+        from apex_tpu.ops import grouped_matmul as gmm
+        tiles = gmm.num_tiles(16, block_m, rows)
+
+        def fn(x, gate_up, down, tile_group, used):
+            h = gmm.grouped_matmul(x, gate_up, tile_group, used,
+                                   block_m=block_m, interpret=False)
+            return gmm.grouped_matmul(h[:, :2048], down, tile_group, used,
+                                      block_m=block_m, interpret=False)
+        return fn, (_sds(chip, (tiles * block_m, 7168), BF16),
+                    _sds(chip, (16, 7168, 4096), BF16),
+                    _sds(chip, (16, 2048, 7168), BF16),
+                    _sds(chip, (tiles,), I32), _sds(chip, (), I32))
+    return build
+
+
 CASES = {
     "flash_fwd_bwd_b8_s1024": _flash(1024, 8),
     "flash_fwd_bwd_b2_s4096": _flash(4096, 2),
@@ -186,6 +225,10 @@ CASES = {
     "fused_ce_fwd_bwd_8192x32768": _fused_ce,
     "fp8_dequant_matmul_h1024_linears": _fp8_matmul,
     "fused_shard_update_adam": _shard_update,
+    "flash_fwd_latent_prefill_d192": _flash_fwd_d192,
+    "mla_decode_b256_h64_w640": _mla_decode,
+    "moe_grouped_matmul_decode_16x7168x4096": _grouped_matmul(32, 2048),
+    "moe_grouped_matmul_prefill_16x7168x4096": _grouped_matmul(128, 8192),
 }
 
 
@@ -210,6 +253,9 @@ NAMED = {
     "kv_write_token_and_prompt": (
         _kv_write(False), (r'op_name="[^"]*apx:kv_write/',
                            r"%apx_kv_write[.\d]* = ")),
+    "mla_decode": (_mla_decode, (r"%apx_mla_decode_attention[.\d]* = ",)),
+    "moe_grouped_matmul": (_grouped_matmul(32, 2048),
+                           (r"%apx_moe_grouped_matmul[.\d]* = ",)),
 }
 
 
@@ -320,6 +366,55 @@ def test_serve_programs_update_the_pool_in_place(chip, chip_smoke):
         assert _pool_traffic(program.as_text(), leaf) == []
         assert program.memory_analysis().alias_size_in_bytes \
             >= eng.ccfg.pool_bytes()
+
+
+#: the latent-attention cell of the benchmark (GigaChat3.1-702B-A36B, one
+#: chip's share of EP16) at two of its five layers: one dense, one expert
+LATENT = dict(vocab_size=16032, hidden_size=7168, num_layers=2, num_heads=64,
+              q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+              qk_rope_head_dim=64, v_head_dim=192, intermediate_size=18432,
+              moe_intermediate_size=2048, n_routed_experts=256,
+              num_experts_per_tok=8, n_group=8, topk_group=4,
+              first_k_dense_replace=1, routed_scaling_factor=2.5,
+              n_local_experts=16, rope_theta=1e5, max_seq_len=2560,
+              rope_scaling=(("beta_fast", 32), ("beta_slow", 1),
+                            ("factor", 64), ("mscale", 1),
+                            ("mscale_all_dim", 1),
+                            ("original_max_position_embeddings", 4096)))
+
+
+def test_latent_serve_programs_update_the_pool_in_place(chip, chip_smoke):
+    """The same engine over the latent-attention model: its decode
+    (absorbed attention, grouped expert matmuls) and prefill (flash at d =
+    192) compile with their kernels, update the latent pool where it lies
+    and need next to nothing beside their arguments."""
+    from apex_tpu import serve
+    from apex_tpu.models import deepseek as ds
+    from apex_tpu.serve.deepseek import DeepseekServed
+    cfg = ds.DeepseekConfig(**LATENT)
+    params = jax.eval_shape(
+        lambda: ds.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = serve.ServeEngine(
+        DeepseekServed(cfg), _place(chip, params), num_pages=5121,
+        max_seq_len=2560, max_prompt_len=1024, page_size=128, max_batch=256,
+        paged_impl="kernel", attention_impl="flash", interpret=False)
+    eng.state = _place(chip, eng.state)
+    assert eng.state.pools[0].shape == (1, 5121, 128, 640)
+    decode, prefill = (p.compile() for p in chip_smoke._serve_programs(
+        eng, sharding=chip))
+    leaf = eng.state.pools[0].size
+    for program, kernels in (
+            (decode, ("apx_mla_decode_attention", "apx_moe_grouped_matmul",
+                      "apx_kv_write")),
+            (prefill, ("apx_flash_attention_fwd", "apx_moe_grouped_matmul",
+                       "apx_kv_write"))):
+        text = program.as_text()
+        for kernel in kernels:
+            assert re.search(rf"%{kernel}[.\d]* = ", text), kernel
+        assert _pool_traffic(text, leaf) == []
+        mem = program.memory_analysis()
+        assert mem.alias_size_in_bytes >= eng.ccfg.pool_bytes()
+        assert mem.temp_size_in_bytes < 0.5e9
 
 
 @pytest.mark.slow
