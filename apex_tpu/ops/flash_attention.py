@@ -955,17 +955,25 @@ _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # the row-major one the kernels below are held to, and a donated pool is
 # updated where it lies.
 #
-# The decode kernel grid is (b, kv_heads, pages_per_seq): each program
-# loads ONE page of one head for one sequence (page id resolved from the
-# scalar-prefetched block table, the Pallas TPU paged-attention pattern),
-# splits it into K and V in VMEM and accumulates online-softmax state
-# exactly like the training forward kernel above. There is no backward:
-# decode is inference-only.
+# The decode kernel is one program a SEQUENCE (grid ``(b, head_blocks)``,
+# head_blocks > 1 only where one page of every kv head, twice, would not
+# fit ``_DECODE_BUFFER_BYTES`` of VMEM). The pool stays in HBM
+# (``pl.ANY``); the program reads its row's length, walks that row's
+# ``ceil(seq_len / page_size)`` live pages in a ``fori_loop`` and copies
+# each, ``pool[:, table[j]]`` = one page of ALL kv heads, into one of two
+# VMEM buffers, page j+1 (or the next row's first page) in flight while
+# page j is computed. A slot past the sequence's end is never a step and
+# never a DMA. Per page the heads' scores lie side by side in the lanes
+# of one [page_size, heads] block, so the online softmax runs once a page
+# for all heads, and both products stream the PAGE through the MXU
+# against a small held operand (the queries; the probabilities). There is
+# no backward: decode is inference-only.
 #
-# The page size IS this kernel's block size; it is fixed when the pool is
-# allocated, so resolution (explicit > tuned cache > heuristic, the
-# fwd/bwd policy) happens in ``serve.cache.resolve_page_size`` at pool
-# construction rather than per call.
+# The page size is fixed when the pool is allocated, so resolution
+# (explicit > tuned cache > heuristic, the fwd/bwd policy) happens in
+# ``serve.cache.resolve_page_size`` at pool construction rather than per
+# call. It is the unit of allocation, of one DMA and of one step of the
+# walk.
 #
 # The two writes (``paged_kv_write_rows`` for a decode step,
 # ``paged_kv_write_pages`` for a prompt) alias the pool to their output
@@ -1015,73 +1023,197 @@ def paged_attention_reference(q, kv_pages, block_tables, seq_lens,
     return out.astype(q.dtype)
 
 
+#: VMEM a decode program's two page buffers may take together. A program
+#: takes every kv head of a sequence where one page of all of them, twice,
+#: fits (16 heads x 128 rows x 128 lanes of bf16, twice: 1 MB), and
+#: otherwise the largest divisor of ``kv_heads`` that does.
+_DECODE_BUFFER_BYTES = 8 * 1024 * 1024
+
+
 def _paged_decode_kernel(*refs, scale, page_size, group, fp8, pages_per_seq):
     it = iter(refs)
     bt_ref = next(it)                       # scalar prefetch: [b*m] int32
     sl_ref = next(it)                       # scalar prefetch: [b] int32
     ks_ref = next(it) if fp8 else None      # SMEM [kv, num_pages] f32
     vs_ref = next(it) if fp8 else None
-    q_ref, kv_ref, o_ref, m_scr, l_scr, acc_scr = it
+    q_ref, pool_ref, o_ref, buf, sem, acc_scr, slot_ref = it
 
-    bi, kh, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    hb, width = buf.shape[1], buf.shape[3]  # kv heads a program, 2*d
+    d, rows = acc_scr.shape                 # rows = hb * group query heads
+    n_hb = pool_ref.shape[0] // hb          # head blocks: 1 where VMEM allows
+    bi, hj = pl.program_id(0), pl.program_id(1)
+    here = bi * n_hb + hj                   # programs run in this order
+    last = pl.num_programs(0) * n_hb - 1
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def live_pages(b):
+        return pl.cdiv(sl_ref[b], page_size)
 
-    def _compute():
-        k, v = _split_pages(kv_ref[0, 0])                 # [bs, d] each
-        if fp8:
-            idx = bt_ref[bi * pages_per_seq + j]
-            q = q_ref[0, 0].astype(jnp.float32)
-            k = k.astype(jnp.float32)
-        else:
-            q = q_ref[0, 0]                               # [g8, d]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+    def fetch(p, j, slot):
+        # page j of program p: one page of ``hb`` kv heads into a buffer
+        b, h = p // n_hb, p % n_hb
+        return pltpu.make_async_copy(
+            pool_ref.at[pl.ds(h * hb, hb), bt_ref[b * pages_per_seq + j]],
+            buf.at[slot], sem.at[slot])
+
+    seq_len = sl_ref[bi]
+    n_live = live_pages(bi)
+    following = jnp.minimum(here + 1, last)
+
+    # the buffer of this program's first page is carried from program to
+    # program: the one before, if it walked any page, started that copy
+    # during its own last page
+    @pl.when(here == 0)
+    def _():
+        slot_ref[0] = 0
+    first = slot_ref[0]
+    fetched = (here > 0) & (live_pages(jnp.maximum(here - 1, 0) // n_hb) > 0)
+
+    @pl.when((n_live > 0) & jnp.logical_not(fetched))
+    def _():
+        fetch(here, 0, first).start()
+
+    # a cached row is K | V. The pool's own dtype feeds the MXU; 8-bit
+    # pages are widened to the query's (e4m3 is exact in bf16)
+    cdt = q_ref.dtype if fp8 else buf.dtype
+    q = q_ref[0, 0].astype(cdt)             # [rows, 2d] = [q | 0], or [rows, d]
+    head = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1) // group
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def page_step(j, carry):
+        m_prev, l_prev = carry              # [1, rows]: heads in the lanes
+        slot = (first + j) % 2
+
+        @pl.when(j + 1 < n_live)
+        def _():
+            fetch(here, j + 1, 1 - slot).start()
+
+        @pl.when((j + 1 == n_live) & (here < last)
+                 & (live_pages(following // n_hb) > 0))
+        def _():
+            fetch(following, 0, 1 - slot).start()
+
+        fetch(here, j, slot).wait()
+        page = bt_ref[bi * pages_per_seq + j]
+
+        # scores with the QUERIES held in the MXU and the page streamed
+        # through it: [page_size, 2d] x [2d, rows], of which a head keeps
+        # its own columns. (Holding the page instead loads a 128 x 128
+        # tile into the MXU for ``group`` streamed rows: 2.2 us a page of
+        # 16 heads against 0.9, PERF.md PR 28.)
+        s = jnp.zeros((page_size, rows), jnp.float32)
+        for h in range(hb):
+            k = buf[slot, h][:, :q.shape[1]].astype(cdt)
+            s = jnp.where(head == h, jax.lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32), s)
+        factor = scale
         if fp8:
             # dequant: stored pages are clip(x * page_scale); the scale
             # guards in amp.fp8.compute_scale keep every stored scale
-            # finite and positive, so the divides are safe even for the
-            # null page
-            s = s / ks_ref[kh, idx]
-        s = s * scale
+            # finite and positive, so the divides are safe
+            factor = jnp.full((1, rows), scale, jnp.float32)
+            for h in range(hb):
+                factor = jnp.where(
+                    head == h, scale / ks_ref[hj * hb + h, page], factor)
         pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (group, page_size), 1)
-        mask = pos < sl_ref[bi]
-        s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            jnp.int32, (page_size, 1), 0)
+        # a walked page holds at least one live row, so every column's
+        # maximum is a real score and the dead rows' exp is an exact 0
+        s = jnp.where(pos < seq_len, s * factor, _NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         p = jnp.exp(s - m_new)
-        # rows whose max is the masked fill (partially-dead pages, the
-        # padded group rows): exp(-1e30 - (-1e30)) = 1, not 0
-        p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
-        if fp8:
-            pv = jax.lax.dot_general(
-                p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) / vs_ref[kh, idx]
-        else:
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        l_new = alpha * l_prev + jnp.sum(p, axis=0, keepdims=True)
+
+        # values the same way round: V^T [d, page_size] streamed against
+        # the probabilities [page_size, rows] held, so the accumulator is
+        # [d, rows] and ``alpha`` scales it as it lies. (The whole row is
+        # transposed and V^T taken as its last sublanes: slicing the V
+        # lanes off first read 4% slower on the chip.)
+        p = p.astype(cdt)
+        pv = jnp.zeros_like(acc_scr)
+        for h in range(hb):
+            v_t = buf[slot, h].astype(cdt).T[width - d:]
+            out = jax.lax.dot_general(
+                v_t, p, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = m_new
+            if fp8:
+                out = out / vs_ref[hj * hb + h, page]
+            pv = jnp.where(head == h, out, pv)
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        return m_new, l_new
 
-    # dead pages (fully past the sequence end — including every page of
-    # an inactive slot, whose table points at the null page) skip the
-    # compute entirely; init/finalize still run, so the output block is
-    # always written (zeros for a fully-dead sequence)
-    pl.when(j * page_size < sl_ref[bi])(_compute)
+    # a dead slot is never a step and never a DMA; an inactive row
+    # (seq_len 0) walks nothing and writes zeros
+    _, l = jax.lax.fori_loop(
+        0, n_live, page_step,
+        (jnp.full((1, rows), _NEG_INF, jnp.float32),
+         jnp.zeros((1, rows), jnp.float32)))
+    slot_ref[0] = (first + n_live) % 2
+    o_ref[0, 0] = (acc_scr[...] / jnp.where(l > 0, l, 1.0)
+                   ).astype(o_ref.dtype)
 
-    @pl.when(j == pages_per_seq - 1)
-    def _finish():
-        l = l_scr[:]
-        o_ref[0, 0] = (acc_scr[:] / jnp.where(l > 0, l, 1.0)
-                       ).astype(o_ref.dtype)
+
+@functools.partial(jax.jit, static_argnames=("scale", "hb", "interpret"))
+def _paged_decode_call(q, kv_pages, block_tables, seq_lens, k_scales,
+                       v_scales, *, scale, hb, interpret):
+    """The kernel call with ``hb`` kv heads a program. Jitted on its own:
+    a decode program makes this call once a layer on the same shapes, and
+    so traces and lowers the kernel (unrolled over the heads: ~0.2 s a
+    call on a host core) once, not once a layer."""
+    b, kv_heads, group, d = q.shape
+    _, _, page_size, width = kv_pages.shape
+    fp8 = k_scales is not None
+    n_hb, rows = kv_heads // hb, hb * group
+    # the score product runs over a whole cached row where K is not a
+    # lane tile of its own: the query becomes [q | 0]
+    q_lanes = d if d % 128 == 0 else width
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, q_lanes - d)))
+    q = q.reshape(b, n_hb, rows, q_lanes)
+
+    kernel = functools.partial(
+        _paged_decode_kernel, scale=scale, page_size=page_size,
+        group=group, fp8=fp8, pages_per_seq=block_tables.shape[1])
+
+    in_specs = []
+    operands = []
+    if fp8:
+        in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM),
+                     pl.BlockSpec(memory_space=pltpu.SMEM)]
+        operands += [k_scales, v_scales]
+    in_specs += [
+        pl.BlockSpec((1, 1, rows, q_lanes),
+                     lambda bi, hj, bt, sl: (bi, hj, 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    operands += [q, kv_pages]
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, n_hb),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, d, rows),
+                               lambda bi, hj, bt, sl: (bi, hj, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, hb, page_size, width),
+                                   kv_pages.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.VMEM((d, rows), jnp.float32),
+                        pltpu.SMEM((1,), jnp.int32)],
+    )
+    from apex_tpu.monitor import profile as _prof
+    with _prof.scope("paged_decode_attention"):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, n_hb, d, rows), q.dtype),
+            # in order: a program waits for a copy the one before started
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=interpret,
+        )(block_tables.reshape(-1).astype(jnp.int32),
+          seq_lens.astype(jnp.int32), *operands)
+    # [b, n_hb, d, hb * group] -> [b, kv_heads, group, d]
+    return out.transpose(0, 1, 3, 2).reshape(b, kv_heads, group, d)
 
 
 def paged_decode_attention(q, kv_pages, block_tables, seq_lens, *,
@@ -1101,8 +1233,8 @@ def paged_decode_attention(q, kv_pages, block_tables, seq_lens, *,
     :func:`paged_attention_reference` there instead, which is faster
     under XLA CPU.
     """
-    b, kv_heads, group, d = q.shape
-    kvp, num_pages, page_size, width = kv_pages.shape
+    _, kv_heads, _, d = q.shape
+    kvp, _, page_size, width = kv_pages.shape
     if (kvp, width) != (kv_heads, 2 * d):
         raise ValueError(
             f"kv_pages {kv_pages.shape} does not match q {q.shape}: want "
@@ -1110,62 +1242,21 @@ def paged_decode_attention(q, kv_pages, block_tables, seq_lens, *,
     if (k_scales is None) != (v_scales is None):
         raise ValueError("fp8-KV mode needs BOTH k_scales and v_scales")
     if page_size % 8:
-        # the page is the kernel's sublane block extent; the tune menu
-        # and serve.cache's heuristic are both 8-aligned, but an
-        # explicit page_size can reach here unrounded — fail with the
-        # contract rather than a Mosaic tiling error
+        # a page is a VMEM buffer's sublane extent; the tune menu and
+        # serve.cache's heuristic are both 8-aligned, but an explicit
+        # page_size can reach here unrounded — fail with the contract
+        # rather than a Mosaic tiling error
         raise ValueError(
             f"page_size {page_size} must be a multiple of 8 (the Pallas "
             f"sublane tile); use the reference path for odd pools")
-    fp8 = k_scales is not None
-    m = block_tables.shape[1]
-    scale_v = d ** -0.5 if scale is None else scale
-    # pad the group (query-heads-per-kv-head) dim up to the 8-sublane
-    # tile; padded rows cost dead VPU lanes, not correctness (masked
-    # rows normalize to zeros and are sliced away)
-    g8 = max(8, -(-group // 8) * 8)
-    if g8 != group:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, g8 - group), (0, 0)))
-
-    kernel = functools.partial(
-        _paged_decode_kernel, scale=scale_v, page_size=page_size,
-        group=g8, fp8=fp8, pages_per_seq=m)
-
-    def page_map(bi, kh, j, bt, sl):
-        return (kh, bt[bi * m + j], 0, 0)
-
-    in_specs = []
-    operands = []
-    if fp8:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM),
-                     pl.BlockSpec(memory_space=pltpu.SMEM)]
-        operands += [k_scales, v_scales]
-    in_specs += [
-        pl.BlockSpec((1, 1, g8, d), lambda bi, kh, j, bt, sl: (bi, kh, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, 2 * d), page_map),
-    ]
-    operands += [q, kv_pages]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, kv_heads, m),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g8, d),
-                               lambda bi, kh, j, bt, sl: (bi, kh, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((g8, 1), jnp.float32),
-                        pltpu.VMEM((g8, 1), jnp.float32),
-                        pltpu.VMEM((g8, d), jnp.float32)],
-    )
-    from apex_tpu.monitor import profile as _prof
-    with _prof.scope("paged_decode_attention"):
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((b, kv_heads, g8, d), q.dtype),
-            interpret=_resolve_interpret(interpret),
-        )(block_tables.reshape(-1).astype(jnp.int32),
-          seq_lens.astype(jnp.int32), *operands)
-    return out[:, :, :group]
+    # kv heads a program: all of them where their page, twice, fits
+    page_bytes = page_size * width * kv_pages.dtype.itemsize
+    hb = max(h for h in range(1, kv_heads + 1) if kv_heads % h == 0
+             and (h == 1 or 2 * h * page_bytes <= _DECODE_BUFFER_BYTES))
+    return _paged_decode_call(
+        q, kv_pages, block_tables, seq_lens, k_scales, v_scales,
+        scale=float(d ** -0.5 if scale is None else scale), hb=hb,
+        interpret=_resolve_interpret(interpret))
 
 
 def _merge_rows(tile_ref, buf, sem, src, lo, hi):

@@ -60,10 +60,12 @@ from apex_tpu.monitor import profile as _prof
 from apex_tpu.ops.flash_attention import (paged_kv_write_pages,
                                           paged_kv_write_rows)
 
-#: heuristic default page size: big enough that a 1k-token context is
-#: 8 pages (program-count bound, like the flash forward), small enough
-#: that the per-sequence tail waste (page_size/2 tokens average) stays
-#: a few percent at chat lengths
+#: heuristic default page size: big enough that one DMA of the decode
+#: kernel's walk moves a page of every kv head in one piece (512 KB at 16
+#: heads of 64 in bf16) and a 1k-token context is 8 steps, small enough
+#: that the tail the kernel reads and the pool holds for nothing
+#: (page_size/2 tokens a sequence on average) stays a few percent at
+#: chat lengths
 DEFAULT_PAGE_SIZE = 128
 
 

@@ -133,6 +133,118 @@ def test_paged_decode_kernel_fp8_dequant():
     assert float(jnp.max(jnp.abs(ref - exact))) < 0.1
 
 
+def _pallas_grids(fn, *args):
+    """The grid of every ``pallas_call`` in ``fn``'s jaxpr, nested jits
+    included."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield tuple(eqn.params["grid_mapping"].grid)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def _walk_case(group, fp8, *, kv=2):
+    """Five rows in one batch: inactive, one token, exactly a page, a page
+    and a token, the whole table. Every row owns its pages (none shared,
+    none the null page) and holds them out of order; the pages a row's
+    length does not reach are in its table all the same, as stale entries
+    are."""
+    from apex_tpu.amp import fp8 as f8
+    d, bs, m = 16, 8, 4
+    rng = np.random.RandomState(4)
+    sl = np.asarray([0, 1, bs, bs + 1, m * bs], np.int32)
+    b = len(sl)
+    n_pages = 1 + b * m
+    bt = rng.permutation(np.arange(1, n_pages)).reshape(b, m).astype(np.int32)
+    dtype = jnp.float32 if fp8 else jnp.bfloat16
+    q = jnp.asarray(rng.randn(b, kv, group, d) * 0.5, dtype)
+    k32 = jnp.asarray(rng.randn(kv, n_pages, bs, d) * 0.5, jnp.float32)
+    v32 = jnp.asarray(rng.randn(kv, n_pages, bs, d) * 0.5, jnp.float32)
+    scales = {}
+    if fp8:
+        ks = jnp.asarray(rng.uniform(1.0, 4.0, (kv, n_pages)), jnp.float32)
+        vs = jnp.asarray(rng.uniform(1.0, 4.0, (kv, n_pages)), jnp.float32)
+        pool = _pool(f8.quantize(k32, ks[:, :, None, None], f8.E4M3),
+                     f8.quantize(v32, vs[:, :, None, None], f8.E4M3),
+                     fp8=True)
+        scales = dict(k_scales=ks, v_scales=vs)
+    else:
+        pool = _pool(k32.astype(dtype), v32.astype(dtype), dtype=dtype)
+    dead = np.ones(n_pages, bool)           # the null page among them
+    for row, n in zip(bt, -(-sl // bs)):
+        dead[row[:n]] = False
+    return q, pool, jnp.asarray(bt), jnp.asarray(sl), scales, dead
+
+
+@pytest.mark.parametrize("pool_dtype", ["bf16", "e4m3"])
+@pytest.mark.parametrize("group", [1, 4, 8])
+def test_paged_decode_kernel_walks_live_pages(group, pool_dtype):
+    """The kernel against the reference at MHA and two GQA groups (one
+    form for all: the heads' scores side by side), both pools, and
+    sequence lengths on every edge of a page."""
+    q, pool, bt, sl, scales, _ = _walk_case(group, pool_dtype == "e4m3")
+    ref = paged_attention_reference(q, pool, bt, sl, **scales)
+    out = paged_decode_attention(q, pool, bt, sl, **scales)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    # bf16: the result is rounded to 8 bits of mantissa, and so are the
+    # probabilities that enter the value product
+    np.testing.assert_allclose(
+        np.asarray(ref, np.float32), np.asarray(out, np.float32),
+        atol=1e-5 if pool_dtype == "e4m3" else 2e-2)
+    assert float(jnp.max(jnp.abs(out[0].astype(jnp.float32)))) == 0.0
+
+
+def test_paged_decode_kernel_splits_heads_that_do_not_fit(monkeypatch):
+    """Where one page of every kv head, twice, is over the kernel's VMEM
+    budget, a program takes a block of the heads and the grid grows a
+    second dimension; the walk, and the copy a program starts for the
+    next one, go on as before."""
+    import importlib
+    fa = importlib.import_module("apex_tpu.ops.flash_attention")
+    q, pool, bt, sl, _, _ = _walk_case(2, False, kv=4)
+    page_bytes = pool[0, 0].size * pool.dtype.itemsize
+    monkeypatch.setattr(fa, "_DECODE_BUFFER_BYTES", 2 * 2 * page_bytes)
+    assert _pallas_grids(paged_decode_attention, q, pool, bt, sl) == [
+        (q.shape[0], 2)]
+    np.testing.assert_allclose(
+        np.asarray(paged_attention_reference(q, pool, bt, sl), np.float32),
+        np.asarray(paged_decode_attention(q, pool, bt, sl), np.float32),
+        atol=2e-2)
+
+
+@pytest.mark.parametrize("group", [1, 8])
+def test_paged_decode_kernel_never_reads_a_dead_page(group):
+    """NaN in the null page and in every page past a row's live range
+    (both still named by the block tables) changes no bit of the
+    result: a dead slot is not fetched, let alone multiplied by zero."""
+    q, pool, bt, sl, _, dead = _walk_case(group, False)
+    clean = paged_decode_attention(q, pool, bt, sl)
+    poisoned = jnp.where(jnp.asarray(dead)[None, :, None, None], jnp.nan,
+                         pool)
+    assert bool(jnp.isnan(poisoned[:, 0]).all())
+    out = paged_decode_attention(q, poisoned, bt, sl)
+    assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
+    np.testing.assert_array_equal(np.asarray(clean, np.float32),
+                                  np.asarray(out, np.float32))
+
+
+def test_paged_decode_grid_is_one_program_a_sequence():
+    """At the benchmark's serve cell (64 rows, 16 heads of 64, 385 pages
+    of 128 tokens, 8 slots a row) the grid holds the batch and nothing of
+    the table or the heads: 64 programs a layer."""
+    b, kv, d, m = 64, 16, 64, 8
+    grid, = _pallas_grids(
+        paged_decode_attention,
+        jax.ShapeDtypeStruct((b, kv, 1, d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((kv, 385, 128, 2 * d), jnp.bfloat16),
+        jax.ShapeDtypeStruct((b, m), jnp.int32),
+        jax.ShapeDtypeStruct((b,), jnp.int32))
+    assert grid[0] == b and m not in grid and kv not in grid
+    assert int(np.prod(grid)) == b
+
+
 def test_decode_forward_kernel_impl_matches_reference(params):
     """The model-level decode step through the Pallas kernels (interpret:
     the aliased write, then the paged read) == through the XLA scatter

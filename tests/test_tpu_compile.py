@@ -113,6 +113,21 @@ def _paged_decode(fp8):
     return build
 
 
+def _paged_decode_at(b, kv, num_pages, page, slots):
+    """The bf16 decode kernel at MHA on ``b`` rows of ``kv`` heads of 64."""
+    def build(chip):
+        return (functools.partial(paged_decode_attention, interpret=False),
+                (_sds(chip, (b, kv, 1, D), BF16),
+                 _sds(chip, (kv, num_pages, page, 2 * D), BF16),
+                 _sds(chip, (b, slots), I32), _sds(chip, (b,), I32)))
+    return build
+
+
+#: the benchmark's serve cell: 64 rows, 16 heads, one layer's leaf of 385
+#: pages of 128 tokens, 8 slots a row
+_paged_decode_cell3 = _paged_decode_at(64, H, 385, 128, 8)
+
+
 def _kv_write(fp8):
     """One layer's decode write and prompt write into a small pool,
     through the aliased Pallas writes the engine uses on the chip."""
@@ -219,6 +234,10 @@ CASES = {
     "lm_head_ce_fwd_bwd_n8192_v32768": _lm_head_ce,
     "paged_decode_bf16": _paged_decode(False),
     "paged_decode_fp8_kv": _paged_decode(True),
+    "paged_decode_b64_h16_p385x128": _paged_decode_cell3,
+    # one page of 32 heads of 1,024 tokens, twice, is 16 MB: a program
+    # takes 16 heads and the grid is (b, 2)
+    "paged_decode_two_head_blocks": _paged_decode_at(B, 32, 17, 1024, 2),
     "kv_write_bf16": _kv_write(False),
     "kv_write_fp8_kv": _kv_write(True),
     "layer_norm_fwd_bwd_8192x1024": _layer_norm,
@@ -237,6 +256,22 @@ def test_kernel_compiles_for_v5e(chip, case):
     fn, args = CASES[case](chip)
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_paged_decode_reads_the_pool_where_it_lies(chip):
+    """The kernel takes the pool as it is (``pl.ANY``) and fetches pages by
+    table entry itself, so beside it the program holds only the query's
+    padding and the result's transpose: no gather the size of a row's
+    table of pages (64 x 8 pages = 134 MB), no copy of the leaf (202 MB),
+    and the instruction keeps the name the trace reader looks for."""
+    fn, args = _paged_decode_cell3(chip)
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert re.search(r"%apx_paged_decode_attention[\w.]* = ", text)
+    assert _pool_traffic(text, args[1].size) == []
+    assert "gather" not in text
+    q_bytes = 64 * H * 2 * D * 2            # [q | 0] in bf16
+    assert compiled.memory_analysis().temp_size_in_bytes <= 4 * q_bytes
 
 
 #: what a device trace of the chip is joined on (benchmarks/harness/
