@@ -47,11 +47,11 @@ from apex_tpu import fp16_utils  # noqa: F401
 from apex_tpu import reparameterization  # noqa: F401
 from apex_tpu import rnn  # noqa: F401
 from apex_tpu import monitor  # noqa: F401
-from apex_tpu import pyprof  # noqa: F401
 from apex_tpu import checkpoint  # noqa: F401
 from apex_tpu import zero  # noqa: F401
 from apex_tpu import tune  # noqa: F401
 
-# heavier subpackages (transformer, contrib, models) import on demand:
-#   import apex_tpu.transformer / apex_tpu.contrib / apex_tpu.models
+# heavier subpackages (transformer, contrib, models) and the profiler
+# shims over monitor's tool side (pyprof) import on demand:
+#   import apex_tpu.transformer / .contrib / .models / .pyprof
 RNN = rnn  # reference package name alias (apex.RNN)

@@ -14,9 +14,7 @@ storm/divergence/plateau/starvation/straggler detection as typed
 ``health_event`` records), per-module cost attribution
 (``monitor.profile``: :func:`scope` tags + the analytic jaxpr
 attributor + measured wall-time sampling,
-``python -m apex_tpu.monitor profile``), bench-trajectory regression
-detection (``monitor.regress``: versioned round loader + noise-aware
-verdicts, ``python -m apex_tpu.monitor regress``), request-level span
+``python -m apex_tpu.monitor profile``), request-level span
 tracing + O(1)-memory log-scale latency histograms (``monitor.spans``:
 the serve SLO evidence layer — per-request queue-wait/prefill/decode
 traces with preempt/re-admit annotations, rendered as the ``serve``
@@ -69,39 +67,38 @@ from __future__ import annotations
 import contextlib
 
 from apex_tpu.monitor import _state
-from apex_tpu.monitor import flight  # noqa: F401
-from apex_tpu.monitor import health  # noqa: F401
 from apex_tpu.monitor import hooks  # noqa: F401
-from apex_tpu.monitor import memory  # noqa: F401
-from apex_tpu.monitor import merge  # noqa: F401
 from apex_tpu.monitor import profile  # noqa: F401
-from apex_tpu.monitor import regress  # noqa: F401
 from apex_tpu.monitor import spans  # noqa: F401
-from apex_tpu.monitor import timeline  # noqa: F401
-from apex_tpu.monitor import trace  # noqa: F401
-from apex_tpu.monitor import xprof  # noqa: F401
-from apex_tpu.monitor.health import Watchdog  # noqa: F401
-from apex_tpu.monitor.memory import MemorySampler  # noqa: F401
+from apex_tpu.monitor.hooks import enabled, epoch  # noqa: F401
 from apex_tpu.monitor.profile import scope  # noqa: F401
 from apex_tpu.monitor.recorder import Recorder  # noqa: F401
-from apex_tpu.monitor.report import (  # noqa: F401
-    aggregate, load_jsonl, render_cross_host, render_fleet, render_memory,
-    render_report, render_serve, render_steps, selfcheck)
 from apex_tpu.monitor.spans import LogHistogram  # noqa: F401
-from apex_tpu.monitor.hooks import enabled, epoch  # noqa: F401
+
+# The emit side above is all the program packages import (held by
+# tests/test_layering.py). The tool side loads on first use: a process
+# that only trains or serves never pays for a module that reads dumps,
+# and never for http.server (tests/test_export.py).
+_LAZY_MODULES = ("export", "fleet", "flight", "health", "memory", "merge",
+                 "report", "slo", "timeline", "trace", "xprof")
+_LAZY_NAMES = {
+    "Watchdog": "health", "MemorySampler": "memory",
+    **{name: "report" for name in (
+        "aggregate", "load_jsonl", "render_cross_host", "render_fleet",
+        "render_memory", "render_report", "render_serve", "render_steps",
+        "selfcheck")},
+}
 
 
 def __getattr__(name: str):
-    # lazily-imported submodules: export pulls in http.server (and the
-    # disabled-mode contract for the exporter is "no thread, no import
-    # cost" — a process that never exports never pays for the module,
-    # asserted by tests/test_export.py); fleet/slo sit on top of export
-    # and inherit the same laziness so the guarantee survives
-    if name in ("export", "fleet", "slo"):
-        import importlib
-        mod = importlib.import_module(f"apex_tpu.monitor.{name}")
-        globals()[name] = mod
-        return mod
+    import importlib
+    if name in _LAZY_MODULES:
+        return importlib.import_module(f"apex_tpu.monitor.{name}")
+    if name in _LAZY_NAMES:
+        value = getattr(importlib.import_module(
+            f"apex_tpu.monitor.{_LAZY_NAMES[name]}"), name)
+        globals()[name] = value
+        return value
     raise AttributeError(f"module 'apex_tpu.monitor' has no attribute "
                          f"{name!r}")
 

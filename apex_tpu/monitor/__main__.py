@@ -7,7 +7,6 @@
     python -m apex_tpu.monitor profile [--model gpt|mlp] [--measured]
     python -m apex_tpu.monitor memory [--model gpt|mlp|zero|serve]
                                       [--live] [--json]
-    python -m apex_tpu.monitor regress RUNS... [--against BASELINE.json]
     python -m apex_tpu.monitor export run.jsonl [--once [--check]|--port N]
     python -m apex_tpu.monitor fleet ENDPOINT... [--watch|--once] [--json]
     python -m apex_tpu.monitor selfcheck [--steps N]
@@ -31,21 +30,17 @@ builds a model train step (GPT by default; shape knobs below) and
 prints the per-module cost attribution table — analytic FLOPs/bytes
 per profile scope, optionally merged with measured eager wall times
 (``--measured``) and an XProf per-op table (``--per-op``, subsuming
-the old ``scripts/profile_gpt.py``). ``regress`` loads bench evidence
-rounds (driver ``BENCH_r*.json`` wrappers, assembled bench JSON, or
-``bench_stream.jsonl`` streams), degrades per round, and renders
-noise-aware verdicts — exit status is non-zero only on a confirmed
-regression. ``export`` renders a recorder JSONL dump/stream as
-Prometheus text exposition — ``--once`` to stdout (``--check``
-additionally parses the output back and asserts scrape == aggregate;
-the ``scripts/ci.sh`` export stage), otherwise served over HTTP with
+the old ``scripts/profile_gpt.py``). ``export`` renders a recorder
+JSONL dump/stream as Prometheus text exposition — ``--once`` to stdout (``--check``
+additionally parses the output back and asserts scrape == aggregate),
+otherwise served over HTTP with
 the file re-read per scrape. ``fleet`` polls N replica exports — live
 ``/metrics`` URLs and/or exposition files — and renders the per-replica
 + fleet table (counters summed, gauges min/max/sum, histograms merged
 bucket-wise) with SLO burn-rate alerts and autoscale decisions;
-``--once`` exits non-zero when an alert fires (the CI fleet stage).
+``--once`` exits non-zero when an alert fires.
 ``selfcheck`` records a synthetic 3-step amp run on CPU and asserts
-the dump → report round trip (used by ``scripts/ci.sh``).
+the dump → report round trip.
 
 ``profile`` also reports **MFU** (model FLOPs utilization): the
 analytic step FLOPs divided by measured wall time and the
@@ -171,29 +166,11 @@ def main(argv=None) -> int:
     pmem.add_argument("--json", action="store_true")
     pmem.add_argument("--max-rows", type=int, default=30)
 
-    pg = sub.add_parser("regress",
-                        help="bench-trajectory verdicts over evidence "
-                             "rounds")
-    pg.add_argument("runs", nargs="+",
-                    help="evidence rounds in chronological order: "
-                         "BENCH_r*.json driver wrappers, assembled "
-                         "bench JSON, or bench_stream.jsonl streams")
-    pg.add_argument("--against", default=None, metavar="BASELINE.json",
-                    help="extra baseline round prepended to the history")
-    pg.add_argument("--json", action="store_true")
-    pg.add_argument("--nmad", type=float, default=3.0,
-                    help="MAD multiplier for the noise band")
-    pg.add_argument("--rel-tol", type=float, default=0.05,
-                    help="relative floor of the noise band")
-    pg.add_argument("--min-history", type=int, default=3,
-                    help="comparable prior rounds required before a "
-                         "regression verdict can gate")
-
     pe = sub.add_parser("export",
                         help="Prometheus text exposition from a "
                              "recorder JSONL dump/stream")
     pe.add_argument("path", help="Recorder.dump_jsonl file or "
-                                 "bench/serve evidence stream")
+                                 "recorder stream")
     pe.add_argument("--once", action="store_true",
                     help="render one snapshot to stdout and exit")
     pe.add_argument("--check", action="store_true",
@@ -297,20 +274,6 @@ def main(argv=None) -> int:
               f"chrome://tracing)")
         return 0
 
-    if args.cmd == "regress":
-        from apex_tpu.monitor import regress as regress_mod
-        rounds = regress_mod.load_rounds(args.runs)
-        against = (regress_mod.load_round(args.against)
-                   if args.against else None)
-        rep = regress_mod.compare(rounds, against=against, nmad=args.nmad,
-                                  rel_tol=args.rel_tol,
-                                  min_history=args.min_history)
-        if args.json:
-            print(json.dumps(json_safe(rep), indent=2))
-        else:
-            print(regress_mod.render_regress(rep))
-        return rep["exit_code"]
-
     if args.cmd == "export":
         from apex_tpu.monitor import export as export_mod
         return export_mod.main(args)
@@ -340,7 +303,6 @@ def _run_profile(args) -> int:
     from apex_tpu.monitor import profile as profile_mod
     from apex_tpu.monitor.recorder import json_safe
 
-    # the ONE step recipe shared with the bench `profile` section
     step, step_args = profile_mod.demo_train_step(
         args.model, batch=args.batch, seq=args.seq, hidden=args.hidden,
         layers=args.layers, heads=args.heads, vocab=args.vocab,

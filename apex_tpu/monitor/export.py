@@ -9,9 +9,9 @@ or ``--once`` to stdout for CI:
     python -m apex_tpu.monitor export run.jsonl --once [--check]
     python -m apex_tpu.monitor export run.jsonl --port 9464
 
-Live mode rides the serve engine: ``ServeEngine.serve(export_port=...)``
-starts an exporter bound to whichever recorder is attached, so SLO
-histograms (p50/p95/p99 token latency, TTFT), pool-occupancy gauges and
+Live mode rides the serve engine: :func:`serve_engine` drives
+``ServeEngine.run()`` under an exporter bound to whichever recorder is
+attached, so SLO histograms (p50/p95/p99 token latency, TTFT), pool-occupancy gauges and
 scheduler counters are scrapeable while requests are in flight.
 
 Disabled mode is free by construction: this module is imported lazily
@@ -350,6 +350,39 @@ class MetricsExporter:
 
     def __exit__(self, *exc):
         self.stop()
+
+
+def serve_engine(engine, *, export_port: int,
+                 export_addr: str = "127.0.0.1",
+                 max_steps: int = 100_000,
+                 export_recorder=None, on_export=None,
+                 export_hold: Optional[threading.Event] = None) -> dict:
+    """``engine.run()`` with a live metrics surface: a
+    :class:`MetricsExporter` serves ``GET /metrics`` (the attached
+    recorder's counters/gauges/SLO histograms) for the duration of the
+    drain and is stopped after it. ``export_port=0`` binds an ephemeral
+    port.
+
+    Fleet wiring (all host-side; compiled programs untouched): samples
+    carry ``replica="<engine.replica_id>"`` labels; ``export_recorder``
+    pins the exporter to a specific recorder (instead of resolving the
+    attached one per scrape — what the multi-replica harness uses, one
+    concrete recorder per engine thread); ``on_export(engine, port)``
+    fires once the port is bound, the registration hook a
+    :class:`~apex_tpu.monitor.fleet.ReplicaSet` hands in;
+    ``export_hold`` keeps the endpoint scrapeable after the drain until
+    the caller sets the event (bounded by a 60 s guard so a forgotten
+    event cannot hang the engine)."""
+    with MetricsExporter(recorder=export_recorder, port=export_port,
+                         addr=export_addr,
+                         replica=engine.replica_id) as exporter:
+        if on_export is not None:
+            on_export(engine, exporter.port)
+        try:
+            return engine.run(max_steps=max_steps)
+        finally:
+            if export_hold is not None:
+                export_hold.wait(timeout=60.0)
 
 
 def main(args) -> int:

@@ -6,8 +6,8 @@ traffic is many serve replicas behind a router; this module is the
 fleet-shaped counterpart of what ``merge`` does for training ranks:
 
 - :class:`ReplicaSet` — the registry of replica endpoints: live
-  ``MetricsExporter`` HTTP URLs (``ServeEngine.serve(export_port=...)``
-  registers itself via the ``on_export`` hook) and/or file-backed
+  ``MetricsExporter`` HTTP URLs (``export.serve_engine(engine, ...)``
+  registers the engine via the ``on_export`` hook) and/or file-backed
   exposition snapshots (``monitor export --once`` output).
 - :class:`FleetPoller` — scrapes every endpoint through the existing
   ``parse_prometheus``, tolerating dead/slow replicas: a per-scrape
@@ -34,8 +34,8 @@ fleet-shaped counterpart of what ``merge`` does for training ranks:
   ``report.aggregate()``.
 
 - :class:`ReplicaThreadRouter` + :class:`LocalFleet` — the CPU-testable
-  multi-replica harness: K ``ServeEngine``s on threads, each
-  ``serve(export_port=0)`` with its OWN concrete Recorder (the router
+  multi-replica harness: K ``ServeEngine``s on threads, each under
+  ``export.serve_engine(export_port=0)`` with its OWN concrete Recorder (the router
   is attached as the single global recorder and routes every write-path
   hook to the calling thread's recorder), registered into a
   ``ReplicaSet`` as their ports bind. Purity: all of this is host-side
@@ -64,6 +64,7 @@ import threading
 import time
 from typing import Dict, Optional
 
+from apex_tpu.monitor import export
 from apex_tpu.monitor import slo as slo_mod
 from apex_tpu.monitor.export import parse_prometheus, parse_prometheus_types
 from apex_tpu.monitor.recorder import Recorder, json_safe
@@ -213,8 +214,9 @@ class ReplicaSet:
 
     ``add(rid, endpoint)`` takes an HTTP(S) ``/metrics`` URL or an
     exposition file path; :meth:`register_engine` is the live-serve
-    hook — pass it as ``ServeEngine.serve(on_export=rs.register_engine)``
-    and the engine registers itself the moment its port binds."""
+    hook — pass it as ``export.serve_engine(engine,
+    on_export=rs.register_engine)`` and the engine is registered the
+    moment its port binds."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -224,12 +226,9 @@ class ReplicaSet:
         with self._lock:
             self._replicas[str(rid)] = _Replica(str(rid), str(endpoint))
 
-    def register_engine(self, engine, addr: str = "127.0.0.1") -> None:
-        if getattr(engine, "export_port", None) is None:
-            raise ValueError("engine has no bound export port; register "
-                             "from serve(on_export=...) or after start")
-        self.add(engine.replica_id,
-                 f"http://{addr}:{engine.export_port}/metrics")
+    def register_engine(self, engine, port: int,
+                        addr: str = "127.0.0.1") -> None:
+        self.add(engine.replica_id, f"http://{addr}:{port}/metrics")
 
     def remove(self, rid: str) -> None:
         with self._lock:
@@ -545,7 +544,7 @@ class LocalFleet:
     Each engine thread binds its own concrete Recorder into the shared
     :class:`ReplicaThreadRouter` (which the CALLER attaches globally:
     ``with monitor.attached(fleet.router): ...``), queues its requests,
-    and runs ``serve(export_port=0)`` — registering into
+    and runs under ``export.serve_engine(export_port=0)`` — registering into
     ``self.replica_set`` the moment its port binds, and holding its
     ``/metrics`` endpoint open after the drain until :meth:`release`
     (so a poller can take a final post-drain scrape: that is the
@@ -601,12 +600,12 @@ class LocalFleet:
                     for prompt, n_new in requests.get(rid, []):
                         eng.add_request(list(prompt), int(n_new))
 
-                    def register(e, rid=rid):
-                        self.replica_set.register_engine(e)
+                    def register(e, port, rid=rid):
+                        self.replica_set.register_engine(e, port)
                         self.ready[rid].set()
 
-                    self.outputs[rid] = eng.serve(
-                        export_port=0,
+                    self.outputs[rid] = export.serve_engine(
+                        eng, export_port=0,
                         export_recorder=self.recorders[rid],
                         on_export=register,
                         export_hold=self.holds[rid])

@@ -103,8 +103,8 @@ def tune_event(kernel: str, key: str, *, hit: bool, source: str,
                config=None):
     """One autotuner cache resolution (``apex_tpu.tune.runtime``):
     bumps the ``tune/cache_hit``/``tune/cache_miss`` counter, sets the
-    ``tune/cache_hit`` gauge (1.0 on a hit — last-resolution-wins, the
-    cheap thing a bench section asserts), and records a typed ``tune``
+    ``tune/cache_hit`` gauge (1.0 on a hit — last-resolution-wins),
+    and records a typed ``tune``
     event carrying the full cache key and the resolved config."""
     rec = _state.recorder
     if rec is None:

@@ -41,20 +41,19 @@ through one vocabulary, in the mold of ``profile.py``/``spans.py``:
   ``memory/hbm_bytes_in_use`` gauges and a streaming
   :class:`~apex_tpu.monitor.spans.LogHistogram`. Platforms whose
   backend returns ``None`` (CPU hosts) degrade to a nominal row — real
-  ``jax.live_arrays()`` resident bytes against the :data:`HBM_BYTES`
-  table limit (the ``profile.PEAK_FLOPS`` cpu-row convention: the
-  whole pipeline is exercisable on CI, and platform-bound unit markers
-  keep the nominal figure out of any cross-host verdict). The sampler
+  ``jax.live_arrays()`` resident bytes against the
+  ``profile.DEVICE_PEAKS`` table limit (its nominal cpu row: the whole
+  pipeline is exercisable on CI, and the row is stamped nominal). The
+  sampler
   installs the ``jax.monitoring`` compile listeners, so retrace storms
   land on the same recorder timeline as the byte samples.
 - :func:`resident_bytes` — device-local resident buffer bytes of a
   pytree (or of every live array): the measurement behind the ZeRO
-  residency ratios, shared by the bench and the CLI.
+  residency ratios.
 - :func:`zero_memory_report` / :func:`serve_pool_report` — the ZeRO
   dense/zero2/zero3 residency split and the serve KV-pool occupancy,
-  derived THROUGH this layer (the bench ``memory`` section and
-  ``python -m apex_tpu.monitor memory`` both call these — no
-  bench-local byte accounting).
+  derived THROUGH this layer (``python -m apex_tpu.monitor memory``
+  prints them).
 - :func:`vmem_calibration` — closes the tuner loop: compares
   ``tune.vmem.vmem_estimate`` envelope predictions against compiled
   temp bytes for resolved kernel configs, emitting
@@ -82,33 +81,12 @@ Rendered by ``python -m apex_tpu.monitor memory`` and embedded in
 
 from __future__ import annotations
 
-import sys
 import threading
 from typing import Callable, Optional
 
 from apex_tpu.monitor import _state
 from apex_tpu.monitor.profile import (UNSCOPED, _aval_bytes, _scope_of,
-                                      _sub_jaxprs)
-
-#: Per-chip HBM capacity by ``device_kind`` substring — the byte twin
-#: of ``profile.PEAK_FLOPS``. Sources: published TPU specs (v2-v6e).
-#: The ``cpu`` row is a NOMINAL table figure, not a hardware spec: it
-#: exists so the HBM-utilization pipeline (sampler -> gauges ->
-#: watchdog ``hbm_high_water``) is exercisable on CI hosts;
-#: cross-host comparison is blocked by the bench's platform-bound unit
-#: markers, so the arbitrariness never leaks into a verdict.
-HBM_BYTES = {
-    "tpu v2": 8 << 30,
-    "tpu v3": 16 << 30,
-    "tpu v4": 32 << 30,
-    "tpu v5 lite": 16 << 30,
-    "tpu v5e": 16 << 30,
-    "tpu v5p": 95 << 30,
-    "tpu v6 lite": 32 << 30,
-    "tpu v6e": 32 << 30,
-    "tpu7": 192 << 30,
-    "cpu": 4 << 30,
-}
+                                      _sub_jaxprs, device_peaks)
 
 #: The compiled-breakdown fields read off ``Compiled.memory_analysis()``
 #: (one place, shared with the trace shim).
@@ -118,24 +96,10 @@ _MA_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
 
 
 def hbm_limit_for(device_kind: Optional[str] = None) -> Optional[int]:
-    """Per-chip HBM bytes for a ``device_kind`` string (default: the
-    first jax device's), by normalized longest-substring match against
-    :data:`HBM_BYTES`. ``None`` for unknown kinds — callers must treat
-    that as "utilization not computable", never substitute a guess."""
-    if device_kind is None:
-        jax = sys.modules.get("jax")
-        if jax is None:
-            return None
-        try:
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            return None
-    kind = str(device_kind).strip().lower()
-    best = None
-    for key, val in HBM_BYTES.items():
-        if key in kind and (best is None or len(key) > len(best[0])):
-            best = (key, val)
-    return best[1] if best else None
+    """Per-chip HBM bytes (``profile.device_peaks``), ``None`` when the
+    kind is unknown: utilization is then not computable."""
+    row = device_peaks(device_kind)
+    return row[1] if row else None
 
 
 # ---------------------------------------------------------------------------
@@ -376,9 +340,9 @@ def device_memory_snapshot(devices=None, recorder=None) -> list[dict]:
     ``memory_stats()`` get the real row (``bytes_in_use``,
     ``peak_bytes_in_use``, ``bytes_limit`` when present); platforms
     that return ``None`` (CPU hosts) degrade to a NOMINAL row —
-    ``jax.live_arrays()`` resident bytes against the :data:`HBM_BYTES`
-    table limit, stamped ``"nominal": True`` (the ``PEAK_FLOPS``
-    cpu-row convention). Recorded as ``memory/...`` gauges on the
+    ``jax.live_arrays()`` resident bytes against the
+    ``profile.DEVICE_PEAKS`` table limit, stamped ``"nominal": True``.
+    Recorded as ``memory/...`` gauges on the
     attached (or passed) recorder; the headline
     ``memory/hbm_bytes_in_use`` gauge is the max across devices."""
     import jax

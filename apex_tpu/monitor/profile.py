@@ -427,9 +427,9 @@ def demo_train_step(model: str = "gpt", *, batch: int = 2, seq: int = 64,
                     vocab: int = 256, dtype: str = "float32",
                     attention: str = "fused_softmax",
                     fused_lm_head: bool = False):
-    """The canonical amp train step the profile CLI and the bench
-    ``profile`` section attribute — ONE recipe, so both always measure
-    the same program. Returns ``(step, args)`` with ``step(*args)``
+    """The canonical amp train step the profile and memory CLIs
+    attribute — ONE recipe, so both always measure the same program.
+    Returns ``(step, args)`` with ``step(*args)``
     runnable and traceable. ``model`` is ``"gpt"`` (tiny Megatron-style
     GPT; ``fused_softmax``/unfused LM head by default so every matmul
     is visible to the analytic FLOP model) or ``"mlp"``. All heavy
@@ -481,32 +481,33 @@ def demo_train_step(model: str = "gpt", *, batch: int = 2, seq: int = 64,
 # MFU / goodput accounting
 # ---------------------------------------------------------------------------
 
-#: Dense peak FLOP/s per chip by ``device_kind`` substring (bf16/matmul
-#: units — the MFU convention). Sources: published TPU specs (v2-v6e).
-#: The ``cpu`` row is a NOMINAL table figure, not a hardware spec: it
-#: exists so the whole MFU pipeline (analytic FLOPs ÷ wall ÷ peak) is
-#: exercisable and same-host trajectories are self-consistent on CI
-#: hosts; cross-host comparison is blocked by the bench's platform-
-#: bound unit markers, so the arbitrariness never leaks into a verdict.
-PEAK_FLOPS = {
-    "tpu v2": 45e12,
-    "tpu v3": 123e12,
-    "tpu v4": 275e12,
-    "tpu v5 lite": 197e12,
-    "tpu v5e": 197e12,
-    "tpu v5p": 459e12,
-    "tpu v6 lite": 918e12,
-    "tpu v6e": 918e12,
-    "tpu7": 2307e12,
-    "cpu": 5e10,
+#: Per-chip peaks by ``device_kind`` substring: dense bf16 matmul
+#: FLOP/s (the MFU convention) and HBM capacity in bytes. Sources:
+#: published TPU specs (v2-v6e). The ``cpu`` row is NOMINAL, not a
+#: hardware spec: it exists so the MFU pipeline (analytic FLOPs ÷ wall
+#: ÷ peak) and the HBM-utilization pipeline (``memory.MemorySampler``
+#: -> gauges -> watchdog ``hbm_high_water``) are exercisable on CI
+#: hosts; whatever reads it is stamped nominal.
+DEVICE_PEAKS = {
+    "tpu v2": (45e12, 8 << 30),
+    "tpu v3": (123e12, 16 << 30),
+    "tpu v4": (275e12, 32 << 30),
+    "tpu v5 lite": (197e12, 16 << 30),
+    "tpu v5e": (197e12, 16 << 30),
+    "tpu v5p": (459e12, 95 << 30),
+    "tpu v6 lite": (918e12, 32 << 30),
+    "tpu v6e": (918e12, 32 << 30),
+    "tpu7": (2307e12, 192 << 30),
+    "cpu": (5e10, 4 << 30),
 }
 
 
-def peak_flops_for(device_kind: Optional[str] = None) -> Optional[float]:
-    """Peak FLOP/s for a ``device_kind`` string (default: the first
-    jax device's), by normalized longest-substring match against
-    :data:`PEAK_FLOPS`. ``None`` for unknown kinds — callers must treat
-    that as "MFU not computable", never substitute a guess."""
+def device_peaks(device_kind: Optional[str] = None) -> Optional[tuple]:
+    """The :data:`DEVICE_PEAKS` row ``(FLOP/s, HBM bytes)`` for a
+    ``device_kind`` string (default: the first jax device's), by
+    normalized longest-substring match. ``None`` for unknown kinds —
+    callers must treat that as "not computable", never substitute a
+    guess."""
     if device_kind is None:
         jax = sys.modules.get("jax")
         if jax is None:
@@ -516,11 +517,15 @@ def peak_flops_for(device_kind: Optional[str] = None) -> Optional[float]:
         except Exception:
             return None
     kind = str(device_kind).strip().lower()
-    best = None
-    for key, val in PEAK_FLOPS.items():
-        if key in kind and (best is None or len(key) > len(best[0])):
-            best = (key, val)
-    return best[1] if best else None
+    keys = [key for key in DEVICE_PEAKS if key in kind]
+    return DEVICE_PEAKS[max(keys, key=len)] if keys else None
+
+
+def peak_flops_for(device_kind: Optional[str] = None) -> Optional[float]:
+    """Peak FLOP/s per chip (:func:`device_peaks`), ``None`` when the
+    kind is unknown: MFU is then not computable."""
+    row = device_peaks(device_kind)
+    return row[0] if row else None
 
 
 def mfu(flops_per_step: float, step_time_s: float, *,

@@ -31,9 +31,8 @@ Crash resilience: pass ``stream=<path or file>`` and every event is
 ALSO appended to that file as one JSON line the moment it is emitted
 (write + flush, so the line survives the process being killed). A run
 that times out or crashes mid-step leaves a parseable JSONL holding
-everything recorded up to the kill — this is what ``bench.py`` builds
-its streaming evidence on, and what ``dump_shard`` rank-tagged shards
-use on multi-host runs.
+everything recorded up to the kill — this is what ``dump_shard``
+rank-tagged shards use on multi-host runs.
 
 Observers: :meth:`add_observer` registers a host callback invoked with
 every closed ``step`` record — the hook :class:`~apex_tpu.monitor.
@@ -119,8 +118,8 @@ class Recorder:
         # traced_hooks=False makes this a host-only observer: the traced
         # hook family (traced_scalar/traced_tick/collective/schedule and
         # the optimizer norm gauges) stays dormant, so compiled programs
-        # are untouched while host timers and compile events still land.
-        # bench.py uses this to time UNperturbed programs.
+        # are untouched while host timers and compile events still land:
+        # what a benchmark attaches to time UNperturbed programs.
         self.traced_hooks = bool(traced_hooks)
         self._events: collections.deque = collections.deque(
             maxlen=self.capacity)
@@ -201,7 +200,8 @@ class Recorder:
     def emit(self, kind: str, name: str, value, **extra) -> dict:
         """Record a custom typed event (user-defined ``kind``). The
         event rides the ring, the JSONL dump, and — when streaming — is
-        flushed to disk immediately (bench sections, health events)."""
+        flushed to disk immediately (health events, a caller's own
+        kinds)."""
         return self._emit(kind, name, value, **extra)
 
     @property

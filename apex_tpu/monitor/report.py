@@ -761,8 +761,8 @@ def selfcheck(n_steps: int = 3, verbose: bool = True) -> dict:
     a recorder attached, dump + reload the JSONL, and assert the report
     round-trips with the per-step fields the acceptance contract names
     (loss scale, grad norm, step time, collective table). Returns the
-    aggregate. Raises AssertionError on any missing piece — wired into
-    ``scripts/ci.sh``."""
+    aggregate. Raises AssertionError on any missing piece — run by
+    ``tests/test_monitor.py``."""
     import io
     import jax.numpy as jnp
     from apex_tpu import monitor

@@ -165,7 +165,7 @@ def op_stats(logdir: str, host: bool = False,
 
 def top_ops(logdir: str, n: int = 5, host: bool = False) -> List[list]:
     """Compact ``[op name, self-time % of device total, bound_by]``
-    triples for the n heaviest ops — what ``bench.py`` embeds per model.
+    triples for the n heaviest ops.
     The share is computed from the self-time column (xprof's own
     percent column is unreliable across converter versions)."""
     rows = op_stats(logdir, host=host)

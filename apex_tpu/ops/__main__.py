@@ -1,6 +1,6 @@
 """``python -m apex_tpu.ops tune`` — the offline Pallas-kernel autotune
-sweep. Subsumes the three historical throwaway scripts
-(``scripts/fa_ablate.py``, ``fa_microbench.py``, ``lmhead_bench.py``):
+sweep. Subsumes the historical throwaway sweep scripts (two remain
+under ``scripts/`` as thin default-shape wrappers over this CLI):
 one sweep implementation (``apex_tpu.tune``), one persistent cache that
 the runtime lookup in ``flash_attention`` / ``fused_lm_head_cross_
 entropy`` then serves from.

@@ -55,8 +55,8 @@ def init_distributed(coordinator_address: str | None = None,
         process_id=process_id)
     # rank-tag any attached recorder so its JSONL shard self-identifies
     # (monitor.merge reads process_index/process_count from the header)
-    from apex_tpu import monitor
-    rec = monitor.get_recorder()
+    from apex_tpu.monitor import _state as _monitor_state
+    rec = _monitor_state.recorder
     if rec is not None:
         rec.meta.setdefault("process_index", jax.process_index())
         rec.meta.setdefault("process_count", jax.process_count())

@@ -72,6 +72,7 @@ from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.flatten_util import ravel_pytree
 
 from apex_tpu._compat import axis_size as _axis_size
 from apex_tpu.monitor import hooks as _mon
@@ -422,6 +423,26 @@ def bucket_partition(leaves: Sequence, message_size: int,
     return buckets
 
 
+def _psum_bucket(ops: Sequence, axis_name: str) -> list:
+    """ONE ``psum`` eqn for a bucket's leaves: apex's flatten ->
+    all-reduce -> unflatten. ``jax.lax.psum`` of a tuple binds one eqn
+    a leaf, so the leaves are raveled into one buffer first — one
+    buffer per wire dtype, so no leaf's reduction changes precision
+    (a gradient tree of one dtype gives one eqn a bucket)."""
+    by_dtype: dict = {}
+    for j, g in enumerate(ops):
+        by_dtype.setdefault(jnp.dtype(g.dtype), []).append(j)
+    if _mon.traced_enabled():
+        _mon.collective("psum", axis_name, nbytes=_mon.tree_bytes(ops),
+                        count=len(by_dtype))
+    out = list(ops)
+    for idx in by_dtype.values():
+        flat, unravel = ravel_pytree([ops[j] for j in idx])
+        for j, g in zip(idx, unravel(jax.lax.psum(flat, axis_name))):
+            out[j] = g
+    return out
+
+
 def bucketed_allreduce(
     grads: Any,
     axis_name: str = "data",
@@ -436,7 +457,8 @@ def bucketed_allreduce(
     one fused ``psum`` *per bucket* instead of one per leaf.
 
     Each bucket's psum is a single collective eqn over that bucket's
-    leaves, data-independent of every other bucket's — XLA pipelines the
+    leaves raveled into one buffer (one a wire dtype where a bucket
+    mixes them), data-independent of every other bucket's — XLA pipelines the
     bucket collectives against each other and against whatever consumes
     the already-reduced buckets (per-bucket optimizer math, the next
     microbatch's compute in :func:`accumulate_gradients`). Scaling
@@ -488,18 +510,12 @@ def bucketed_allreduce(
             if _mon.traced_enabled():
                 _mon.collective("pmax", axis_name, nbytes=4, count=1)
             scale = _fp8.compute_scale(bucket_amax * world, _fp8.E5M2_MAX)
-            wire = tuple(_fp8.quantize(g, scale, _fp8.E5M2) for g in ops)
-            if _mon.traced_enabled():
-                _mon.collective("psum", axis_name,
-                                nbytes=_mon.tree_bytes(wire), count=1)
-            summed = jax.lax.psum(wire, axis_name)   # fp8 on the wire
+            wire = [_fp8.quantize(g, scale, _fp8.E5M2) for g in ops]
+            summed = _psum_bucket(wire, axis_name)   # fp8 on the wire
             reduced = [_fp8.dequantize(q, scale, jnp.float32)
                        for q in summed]
         else:
-            if _mon.traced_enabled():
-                _mon.collective("psum", axis_name,
-                                nbytes=_mon.tree_bytes(ops), count=1)
-            reduced = jax.lax.psum(tuple(ops), axis_name)  # ONE eqn/bucket
+            reduced = _psum_bucket(ops, axis_name)
         for i, g in zip(bucket, reduced):
             out[i] = _postscale_leaf(g, leaves[i].dtype, world,
                                      gradient_average,

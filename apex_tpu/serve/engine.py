@@ -135,8 +135,8 @@ class ServeEngine:
             params = model.quantize_weights(params, margin=fp8_weight_margin)
         self.params = params
         # stable replica identity for fleet telemetry: labels every
-        # exported sample (monitor.export) and keys this engine in a
-        # monitor.fleet.ReplicaSet. Host-side only — never reaches a
+        # sample a metrics exporter renders for this engine and keys it
+        # in a fleet's replica set. Host-side only — never reaches a
         # compiled program.
         if replica_id is None:
             with _REPLICA_SEQ_LOCK:
@@ -144,7 +144,6 @@ class ServeEngine:
                 replica_id = f"replica{_REPLICA_SEQ}"
                 _REPLICA_SEQ += 1
         self.replica_id = str(replica_id)
-        self.export_port: Optional[int] = None
         self.paged_impl = paged_impl or d_impl
         self.attention_impl = attention_impl or p_impl
         self.interpret = interpret
@@ -683,6 +682,10 @@ class ServeEngine:
             # unless flight.install() armed dumps.
             _mflight.trigger("serve/abort")
             raise
+        finally:
+            # engine shutdown: snapshot the final SLO/occupancy state
+            # (no-op unless the flight recorder is armed)
+            _mflight.trigger("serve/shutdown")
         self._record_run_summary(t0, tok0)
         return {sid: s.tokens[len(s.prompt):]
                 for sid, s in self.seqs.items()}
@@ -705,53 +708,6 @@ class ServeEngine:
             # cumulative SLO histograms ride the ring/stream, so a
             # crash after drain still leaves the percentiles on disk
             rec.emit_histograms()
-
-    def serve(self, *, export_port: Optional[int] = None,
-              export_addr: str = "127.0.0.1",
-              max_steps: int = 100_000,
-              export_recorder=None, on_export=None,
-              export_hold: Optional[threading.Event] = None
-              ) -> Dict[int, List[int]]:
-        """:meth:`run` with a live metrics surface: when
-        ``export_port`` is given, a :class:`~apex_tpu.monitor.export.
-        MetricsExporter` serves ``GET /metrics`` (Prometheus text
-        exposition of the attached recorder's counters/gauges/SLO
-        histograms) for the duration of the drain — ``export_port=0``
-        binds an ephemeral port (``self.export_port`` holds the bound
-        port). Without ``export_port`` this IS ``run()`` — no thread,
-        no ``http.server`` import.
-
-        Fleet wiring (all host-side; compiled programs untouched):
-        samples carry ``replica="<self.replica_id>"`` labels;
-        ``export_recorder`` pins the exporter to a specific recorder
-        (instead of resolving the attached one per scrape — what the
-        multi-replica harness uses, one concrete recorder per engine
-        thread); ``on_export(self)`` fires once the port is bound, the
-        registration hook a :class:`~apex_tpu.monitor.fleet.ReplicaSet`
-        hands in; ``export_hold`` keeps the endpoint scrapeable after
-        the drain until the caller sets the event (bounded by a 60 s
-        guard so a forgotten event cannot hang the engine)."""
-        try:
-            if export_port is None:
-                return self.run(max_steps=max_steps)
-            from apex_tpu.monitor import export as export_mod
-            exporter = export_mod.MetricsExporter(recorder=export_recorder,
-                                                  port=export_port,
-                                                  addr=export_addr,
-                                                  replica=self.replica_id)
-            self.export_port = exporter.start()
-            if on_export is not None:
-                on_export(self)
-            try:
-                return self.run(max_steps=max_steps)
-            finally:
-                if export_hold is not None:
-                    export_hold.wait(timeout=60.0)
-                exporter.stop()
-        finally:
-            # engine shutdown: snapshot the final SLO/occupancy state
-            # (no-op unless the flight recorder is armed)
-            _mflight.trigger("serve/shutdown")
 
 
 def naive_generate(cfg: GPTConfig, params, requests, *, max_seq_len: int,

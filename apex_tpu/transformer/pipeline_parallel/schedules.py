@@ -751,8 +751,7 @@ def forward_backward_pipelining_zb_model(
     ``2·(nmb + 2(P-1)) + nmb``, an idle-slot fraction of
     ``4(P-1)/(3·nmb + 4(P-1))`` vs 1F1B's
     ``2(P-1)/(nmb + 2(P-1))`` — strictly lower for P > 1 (measured per
-    rank by the ``traced_tick_marks`` table, not just this formula;
-    ``bench.py``'s ``pp_zero_bubble`` section records both).
+    rank by the ``traced_tick_marks`` table, not just this formula).
 
     ``wgrad_stash`` (the memory knob, ``backward_split.
     normalize_wgrad_stash``): ``None`` = full deferral (stash holds all

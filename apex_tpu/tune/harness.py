@@ -51,8 +51,8 @@ def _call_with_timeout(fn: Callable[[], object],
     back to unguarded calls — the sweep still skips the config on any
     exception, it just cannot preempt a hang there.
 
-    ITIMER_REAL is process-global, so an enclosing alarm budget (e.g.
-    bench.py's per-section SIGALRM) is suspended for the duration and
+    ITIMER_REAL is process-global, so an enclosing alarm budget (an
+    outer SIGALRM the caller armed) is suspended for the duration and
     re-armed with its REMAINING time afterwards — if it would have
     expired while ours was live, it fires (almost) immediately under
     its restored handler instead of being silently cancelled."""
@@ -127,9 +127,9 @@ def sweep(candidates: list[dict], build: Callable[[dict], Callable[[], None]],
                             "timings_s": timings, "build_s": build_s,
                             "_idx": idx})
         except Exception as e:      # a failed config is data; BaseException
-            # control-flow (KeyboardInterrupt, SystemExit, bench.py's
-            # SectionTimeout — a BaseException precisely so broad
-            # excepts can't eat it) must propagate out of the sweep
+            # control-flow (KeyboardInterrupt, SystemExit, a caller's
+            # timeout raised from an outer SIGALRM handler) must
+            # propagate out of the sweep
             failed.append({"config": dict(config),
                            "error": f"{type(e).__name__}: {e}"})
             hooks.counter("tune/sweep_config_failed")

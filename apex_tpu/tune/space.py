@@ -15,7 +15,7 @@ from apex_tpu.tune import vmem
 
 # power-of-two block menu shared by both flash phases; Mosaic wants the
 # trailing dims (8, 128)-aligned and every real sweep to date has only
-# ever ranked powers of two (scripts/fa_microbench.py history)
+# ever ranked powers of two
 _FLASH_BLOCKS = (1024, 512, 256, 128)
 _CE_BLOCK_T = (1024, 512, 256, 128)
 _CE_BLOCK_V = (8192, 4096, 2048, 1024, 512, 256, 128)

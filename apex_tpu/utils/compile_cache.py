@@ -13,7 +13,7 @@ entry's key, so a cache that moves never hits: entry points call
   location (git-ignored) — never a temp dir, a pid or a timestamp.
 
 Library code never calls this; only ``__main__``-style entry points do
-(``chip_smoke.py``, ``bench.py``, the examples, ``python -m
+(``chip_smoke.py``, ``benchmarks/run.py``, the examples, ``python -m
 apex_tpu.ops``, ``python -m apex_tpu.monitor profile|memory``). The test
 suite turns the cache off (``tests/conftest.py``): a compile for a
 described-but-absent chip is written but cannot be read back.

@@ -75,7 +75,13 @@ def main():
             prompts[rid] = prompt
 
         t0 = time.perf_counter()
-        outputs = engine.serve(export_port=args.export_port)
+        if args.export_port is None:
+            outputs = engine.run()
+        else:
+            outputs = monitor.export.serve_engine(
+                engine, export_port=args.export_port,
+                on_export=lambda _eng, port: print(
+                    f"serving /metrics on port {port} during the drain"))
         dt = time.perf_counter() - t0
     for rid in sorted(outputs):
         print(f"request {rid}: prompt[{len(prompts[rid])}] -> "
@@ -121,9 +127,6 @@ def main():
         agg = rec.aggregate()
         rendered = monitor.render_serve(agg)
         print(rendered if rendered else "(no serve telemetry recorded)")
-        if args.export_port is not None:
-            print(f"(live /metrics was served on port "
-                  f"{engine.export_port} during the drain)")
         if args.monitor:
             n = rec.dump_jsonl(args.monitor)
             print(f"dumped {n} events to {args.monitor} "

@@ -10,8 +10,7 @@ implementation in ``apex_tpu.tune``:
 This wrapper runs exactly that (the GPT and BERT bench shapes) and
 writes the persistent per-device cache that
 ``fused_lm_head_cross_entropy(block_t=None, ...)`` resolves from. The
-fused-vs-unfused comparison lives in ``bench.py`` (sections ``gpt`` /
-``bert``); the historical sweep numbers are quoted in
+historical sweep numbers are quoted in
 ``ops/lm_head_ce.py:_pick_blocks``. Extra arguments pass through.
 """
 import sys

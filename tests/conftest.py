@@ -14,7 +14,7 @@ import os
 # real chip.
 os.environ["JAX_PLATFORMS"] = "cpu"
 # No persistent compile cache under test, here or in the subprocesses
-# tests start (bench.py, the examples and the monitor/ops mains enable
+# tests start (chip_smoke.py, the examples and the monitor/ops mains enable
 # it): a compile for a described-but-absent chip (test_tpu_compile.py)
 # is written to it but cannot be read back.
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"

@@ -1,0 +1,53 @@
+"""Tier-1 runs the instrument's program-facing tests.
+
+``benchmarks/tests`` rehearses on the CPU, at tiny sizes, the code path
+the driver runs on the chip in every cell: ``run_cell`` through the
+families, the references, the manifest and the reductions that read the
+program's ``apx:`` scopes and spans. A program PR that renames something
+a family imports must learn of it here, not from the chip run's
+``output_malformed``. Tier-1 collects ``tests/`` only and nothing under
+``benchmarks/`` may be edited outside a ``benchmark`` PR, so each file
+that imports ``apex_tpu`` or the ``_tiny`` rehearsal helpers runs as a
+child process in the benchmark's own environment (four virtual devices,
+no xdist). When a ``benchmark`` issue can move files, this bridge goes
+(ROADMAP D9).
+"""
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+FILES = ("test_rehearsal.py", "test_deepseek.py", "test_reference.py",
+         "test_manifest.py", "test_flops_bytes.py", "test_span_reduce.py")
+
+
+@pytest.fixture(scope="module")
+def children():
+    """All six start together: under ``--dist loadfile`` this file is
+    one worker's, and run one after another they are three minutes of
+    it, the last of them after every other worker has finished."""
+    # tier-1's XLA_FLAGS asks for eight devices; without it
+    # benchmarks/tests/conftest.py sets the four its cells are laid out on
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    procs = {name: subprocess.Popen(
+        [sys.executable, "-m", "pytest", f"benchmarks/tests/{name}", "-q",
+         "-p", "no:cacheprovider"],
+        cwd=ROOT, env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+        text=True) for name in FILES}
+    yield procs
+    for proc in procs.values():
+        if proc.poll() is None:
+            proc.kill()
+            proc.wait()
+
+
+@pytest.mark.parametrize("name", FILES)
+def test_benchmark_tests_pass(children, name):
+    out, _ = children[name].communicate(timeout=900)
+    assert children[name].returncode == 0, out[-4000:]

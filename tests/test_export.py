@@ -163,9 +163,23 @@ def test_http_exporter_resolves_attached_recorder_per_scrape():
         exporter.stop()
 
 
-def test_cli_export_once_check(tmp_path):
+def _memory_recorder():
+    """The ``memory/hbm_*`` gauges a ``MemorySampler`` tick records."""
+    from apex_tpu.monitor import memory
+    rec = monitor.Recorder(name="memory")
+    with monitor.attached(rec):
+        memory.device_memory_snapshot()
+    return rec
+
+
+@pytest.mark.parametrize("make_recorder,expected", [
+    (_mini_recorder, "apex_serve_preemptions_total 3"),
+    # the sampler's gauges are scrapeable (was a stage of scripts/ci.sh)
+    (_memory_recorder, "\napex_memory_hbm_bytes_in_use "),
+])
+def test_cli_export_once_check(tmp_path, make_recorder, expected):
     from apex_tpu.monitor.__main__ import main as cli_main
-    rec = _mini_recorder()
+    rec = make_recorder()
     path = tmp_path / "run.jsonl"
     rec.dump_jsonl(str(path))
     import contextlib
@@ -173,7 +187,7 @@ def test_cli_export_once_check(tmp_path):
     with contextlib.redirect_stdout(out):
         rc = cli_main(["export", str(path), "--once", "--check"])
     assert rc == 0
-    assert "apex_serve_preemptions_total 3" in out.getvalue()
+    assert expected in out.getvalue()
 
 
 def test_monitor_import_does_not_import_export():

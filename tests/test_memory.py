@@ -176,8 +176,8 @@ def test_sampled_step_jaxpr_byte_identity():
 def test_snapshot_degrades_to_nominal_row_on_cpu():
     """The CPU backend reports no memory_stats: the snapshot degrades
     to the nominal row — real live-array resident bytes against the
-    HBM_BYTES table limit, stamped nominal (the PEAK_FLOPS cpu-row
-    convention) — and still records the headline gauges."""
+    DEVICE_PEAKS table limit, stamped nominal — and still records the
+    headline gauges."""
     keep = jnp.ones((1024,), jnp.float32)   # noqa: F841  (resident)
     rec = monitor.Recorder(name="t")
     with monitor.attached(rec):
@@ -186,11 +186,11 @@ def test_snapshot_degrades_to_nominal_row_on_cpu():
     row = rows[0]
     assert row.get("nominal") is True
     assert row["bytes_in_use"] >= keep.nbytes
-    assert row["limit_bytes"] == memory.HBM_BYTES["cpu"]
+    assert row["limit_bytes"] == memory.hbm_limit_for("cpu")
     assert 0.0 <= row["utilization"] < 1.0
     g = rec.gauges()
     assert g["memory/hbm_bytes_in_use"] >= keep.nbytes
-    assert g["memory/hbm_limit_bytes"] == memory.HBM_BYTES["cpu"]
+    assert g["memory/hbm_limit_bytes"] == memory.hbm_limit_for("cpu")
     assert "memory/hbm_utilization" in g
 
 
@@ -198,6 +198,21 @@ def test_hbm_limit_table_lookup():
     assert memory.hbm_limit_for("TPU v5e") == 16 << 30
     assert memory.hbm_limit_for("TPU v5p chip") == 95 << 30
     assert memory.hbm_limit_for("warp-drive-9000") is None
+
+
+def test_program_and_benchmark_agree_on_bf16_peak():
+    """The program may not import ``benchmarks/``, so the peaks live
+    twice: ``profile.DEVICE_PEAKS`` for the program's own MFU gauge and
+    ``benchmarks/harness/peaks.py`` for the ledger's rooflines. Every
+    ``device_kind`` both list has ONE bf16 peak (run from the root of a
+    checkout, as ``tests/test_deepseek.py`` is)."""
+    from apex_tpu.monitor import profile
+    from benchmarks.harness import peaks
+    shared = [kind for kind in peaks.PEAKS
+              if profile.peak_flops_for(kind) is not None]
+    assert "TPU v5 lite" in shared
+    for kind in shared:
+        assert profile.peak_flops_for(kind) == peaks.PEAKS[kind].bf16_flops
 
 
 def test_memory_sampler_thread_and_detach():

@@ -425,19 +425,24 @@ def test_measured_mfu_records_gauges():
         assert g["profile/mfu_pct"] == row["mfu_pct"]
 
 
-def test_serve_method_exports_during_drain(params):
-    """ServeEngine.serve(export_port=0) binds an ephemeral /metrics
-    endpoint for the drain and stops it after; outputs == run()."""
+def test_serve_engine_exports_during_drain(params):
+    """export.serve_engine(eng, export_port=0) binds an ephemeral
+    /metrics endpoint for the drain and stops it after; outputs ==
+    run()."""
+    from apex_tpu.monitor import export
     rec = monitor.Recorder(traced_hooks=False)
     eng = _engine(params)
+    ports = []
     with monitor.attached(rec):
         for p in PROMPTS:
             eng.add_request(p, N_NEW)
-        out = eng.serve(export_port=0)
-    assert eng.export_port > 0
+        out = export.serve_engine(
+            eng, export_port=0,
+            on_export=lambda _eng, port: ports.append(port))
+    assert ports[0] > 0
     assert all(len(v) == N_NEW for v in out.values())
     import urllib.error
     import urllib.request
     with pytest.raises(urllib.error.URLError):     # stopped after drain
         urllib.request.urlopen(
-            f"http://127.0.0.1:{eng.export_port}/metrics", timeout=2)
+            f"http://127.0.0.1:{ports[0]}/metrics", timeout=2)

@@ -218,7 +218,7 @@ def test_sweep_per_config_timeout():
 
 def test_sweep_preserves_enclosing_alarm_budget():
     """ITIMER_REAL is process-global: a sweep running inside an outer
-    SIGALRM budget (bench.py's per-section alarm) must leave that
+    SIGALRM budget (a caller's own alarm) must leave that
     budget armed with its remaining time, not cancel it."""
     import signal
 
@@ -242,8 +242,8 @@ def test_sweep_preserves_enclosing_alarm_budget():
 
 
 def test_sweep_propagates_base_exceptions():
-    """BaseException control flow (bench.py's SectionTimeout is a
-    BaseException precisely so broad excepts can't eat it) escapes the
+    """BaseException control flow (a caller's timeout raised from an
+    outer SIGALRM handler, which broad excepts must not eat) escapes the
     sweep instead of being recorded as a failed config."""
     class _SectionTimeout(BaseException):
         pass
