@@ -36,6 +36,23 @@ weight bytes every decode step streams ~2x vs bf16; the forward reads
 them through the fused dequant-matmul (``ops.fp8_matmul``). Orthogonal
 to and composable with speculative decoding.
 
+One round ahead of the tokens read: nothing the host decides between
+two rounds depends on a token's VALUE (a sequence ends by count, pages
+grow by position, admission goes by slots and pages), so sampled tokens
+stay on the device — ``last_tok[max_batch]``, which the decode program
+reads its input from and returns with the round's argmaxes merged in,
+and in which the prefill program sets the prompt's first token — and
+``step()`` N dispatches its prefills and its decode BEFORE it reads
+round N-1's tokens (``copy_to_host_async`` at dispatch, the wait one
+round late). ``seq.tokens``, ``tokens_generated``, ``done`` and TTFT
+advance only when a value is on the host; scheduling goes by
+``Sequence.num_dispatched``. Where a value IS needed the engine drains
+first (reads what is in flight early, nothing else): preemption and
+replay, the speculative rounds, ``preempt()``. A step that sends out
+no decode round reads everything itself (``_due``); ``run()`` and the
+end of work leave nothing in flight. Same programs' arithmetic, same tokens,
+bit for bit (``tests/test_serve.py``).
+
 Tensor parallelism: with a model-parallel mesh installed
 (``parallel_state.initialize_model_parallel(tp)``), both steps wrap in
 ``shard_map`` with layouts from :mod:`apex_tpu.serve.rules` — the FULL
@@ -58,9 +75,10 @@ costs one global read per hook.
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +108,20 @@ def _default_impls():
 # engines constructed without an explicit replica_id
 _REPLICA_SEQ = 0
 _REPLICA_SEQ_LOCK = threading.Lock()
+
+
+@dataclasses.dataclass
+class _InFlight:
+    """What one dispatch left on the device for the host to read later:
+    a prefill's first token (one row) or a decode round's tokens."""
+
+    step: int                          # the step() that dispatched it
+    rows: List[Tuple[Sequence, int]]   # (sequence, batch row it took)
+    toks: Any                          # ``last_tok`` after the dispatch
+    logits: Any                        # under ``record_logits``, else None
+    aux: Any                           # the model's own outputs, or None
+    decode: bool
+    t_dispatch: float
 
 
 class ServeEngine:
@@ -213,8 +245,15 @@ class ServeEngine:
         self.record_logits = record_logits
         self.logits_log: Dict[int, Dict[int, np.ndarray]] = {}
         self.aux_log: Dict[int, Dict[int, dict]] = {}
+        # one entry a decode round: the round's period as the consumer
+        # of its tokens sees it (``_fetch``)
         self.decode_step_times: List[float] = []
         self.tokens_generated = 0
+        # the engine runs one round ahead of the tokens it has read: the
+        # dispatches whose tokens are still on the device, oldest first
+        self._in_flight: List[_InFlight] = []
+        self._step_no = 0
+        self._t_tokens = 0.0           # a decode round's tokens last arrived
         self._next_id = 0
         self.seqs: Dict[int, Sequence] = {}    # every request ever added
         self._build_steps()
@@ -225,22 +264,27 @@ class ServeEngine:
         model, ccfg = self.model, self.ccfg
 
         # ``aux`` (the model's own small outputs, {} for GPT) leaves the
-        # program beside the logits: no leaf, no output, same program
-        def decode(params, state, bt, pos, tok, act):
+        # program beside the logits: no leaf, no output, same program.
+        # Sampled tokens are fed back ON THE DEVICE: ``last_tok`` [B] is
+        # the token each batch row feeds next. The decode program reads
+        # its input from it and returns it with the active rows' argmaxes
+        # merged in; the prefill program sets the prompt's first token at
+        # the sequence's row. The host reads a round's tokens a round late.
+        def decode(params, state, bt, pos, last_tok, act):
             logits, state, aux = model.decode(
-                ccfg, params, state, bt, pos, tok, act,
-                paged_impl=self.paged_impl, interpret=self.interpret,
+                ccfg, params, state, bt, pos, jnp.where(act, last_tok, 0),
+                act, paged_impl=self.paged_impl, interpret=self.interpret,
                 autotune=self.autotune)
-            return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-                state, aux
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return logits, jnp.where(act, nxt, last_tok), state, aux
 
-        def prefill(params, state, bt, length, ids):
+        def prefill(params, state, bt, length, ids, last_tok, slot):
             logits, state, aux = model.prefill(
                 ccfg, params, state, bt, length, ids,
                 attention_impl=self.attention_impl,
                 interpret=self.interpret, autotune=self.autotune)
-            return logits, jnp.argmax(logits, axis=-1).astype(jnp.int32), \
-                state, aux
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return logits, last_tok.at[slot].set(nxt), state, aux
 
         draft = None
         if self.spec_k:
@@ -254,6 +298,7 @@ class ServeEngine:
                     autotune=self.autotune)
                 return jnp.argmax(logits, axis=-1).astype(jnp.int32), state
 
+        self._tok_sharding = None      # where ``last_tok`` lives
         if self.tp > 1:
             mesh = ps.get_mesh()
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -273,13 +318,14 @@ class ServeEngine:
 
             self.params = place(self.params, pspec)
             self.state = place(self.state, cspec)
+            self._tok_sharding = NamedSharding(mesh, P())
             decode = shard_map(
                 decode, mesh=mesh,
                 in_specs=(pspec, cspec, P(), P(), P(), P()),
                 out_specs=(P(), P(), cspec, P()), check_vma=False)
             prefill = shard_map(
                 prefill, mesh=mesh,
-                in_specs=(pspec, cspec, P(), P(), P()),
+                in_specs=(pspec, cspec, P(), P(), P(), P(), P()),
                 out_specs=(P(), P(), cspec, P()), check_vma=False)
             if draft is not None:
                 dpspec = rules_mod.match_serve_rules(
@@ -299,6 +345,8 @@ class ServeEngine:
         self._prefill = jax.jit(prefill, donate_argnums=(1,))
         self._draft_decode = (jax.jit(draft, donate_argnums=(1,))
                               if draft is not None else None)
+        self._last_tok = jax.device_put(
+            np.zeros((self.max_batch,), np.int32), self._tok_sharding)
 
     # -- request intake ----------------------------------------------
 
@@ -344,12 +392,83 @@ class ServeEngine:
                 k: np.asarray(v if row is None else v[row])
                 for k, v in aux["rows"].items()}
 
-    def _record_round(self, aux) -> None:
-        """The model's own counters of a decode round (``aux["round"]``):
-        fetched once the tokens are on the host, so the fetch waits for
-        nothing; skipped without a recorder."""
-        if aux and aux.get("round") and _mhooks.enabled():
-            self.model.record_round(jax.device_get(aux["round"]))
+    # -- one round ahead of the tokens read ---------------------------
+
+    def _hold(self, rows, logits, aux, *, decode: bool,
+              t_dispatch: float) -> None:
+        """A dispatch's tokens stay on the device (``self._last_tok``
+        feeds the next round there). Start their copy to the host
+        without waiting for it, with whatever else a reader wants of the
+        dispatch, and count a token in flight for each row."""
+        held = {}
+        if aux and self.record_logits and aux.get("rows"):
+            held["rows"] = aux["rows"]
+        if aux and decode and aux.get("round") and _mhooks.enabled():
+            # the model's own counters of a decode round
+            held["round"] = aux["round"]
+        e = _InFlight(self._step_no, rows, self._last_tok,
+                      logits if self.record_logits else None, held,
+                      decode, t_dispatch)
+        for a in jax.tree.leaves((e.toks, e.logits, e.aux)):
+            a.copy_to_host_async()
+        for seq, _ in rows:
+            seq.in_flight += 1
+        self._in_flight.append(e)
+
+    def _fetch(self, e: _InFlight):
+        """Wait until one dispatch's tokens are on the host. A decode
+        round's ``decode_step_times`` entry is its period as the reader
+        sees it: from the previous round's tokens arriving (or this
+        round's dispatch, whichever is later) to its own arriving, so
+        overlapped time counts once and the entry never falls to ~0."""
+        toks = np.asarray(e.toks)
+        dt = None
+        if e.decode:
+            now = time.perf_counter()
+            dt = now - max(self._t_tokens, e.t_dispatch)
+            self._t_tokens = now
+            self.decode_step_times.append(dt)
+        logits = None if e.logits is None else np.asarray(e.logits)
+        return e, toks, logits, jax.device_get(e.aux), dt
+
+    def _commit(self, e: _InFlight, toks, logits, aux, dt) -> None:
+        """Count one dispatch's tokens, now that their values are here:
+        only this advances ``seq.tokens``, ``tokens_generated``, TTFT
+        and ``done``."""
+        if e.decode:
+            if "round" in aux:
+                self.model.record_round(aux["round"])
+            if _mhooks.enabled():
+                # per-TOKEN latency: each row of the round produced one
+                # token — the streaming-percentile source of the serve
+                # SLO numbers (p50/p95/p99)
+                for _ in e.rows:
+                    _mhooks.observe("serve/token_latency_ms", 1e3 * dt)
+                _mhooks.gauge("serve/batch_fill",
+                              len(e.rows) / self.max_batch)
+        for seq, row in e.rows:
+            seq.in_flight -= 1
+            if logits is not None:
+                self._record(seq, seq.num_tokens,
+                             logits[row] if e.decode else logits, aux,
+                             row if e.decode else None)
+            self._sample(seq, toks[row])
+
+    def _take(self, n: int) -> list:
+        """Fetch the ``n`` oldest dispatches in flight."""
+        batch = self._in_flight[:n]
+        del self._in_flight[:n]
+        return [self._fetch(e) for e in batch]
+
+    def _drain(self, reason: str) -> None:
+        """Read everything in flight NOW, because a token's value is
+        needed (``preempt``, ``replay``, ``spec``). Reading early is all
+        it is."""
+        if not self._in_flight:
+            return
+        _mhooks.counter("serve/pipeline_drains", reason=reason)
+        for f in self._take(len(self._in_flight)):
+            self._commit(*f)
 
     def _free_slot(self, seq: Sequence) -> None:
         for i, s in enumerate(self.slots):
@@ -384,7 +503,9 @@ class ServeEngine:
         tokens through the decode program (single-slot-active batches):
         the same compiled rows as the original steps, hence bit-exact.
         The last token is NOT replayed — it is the next decode's
-        input."""
+        input. The fed tokens are VALUES (``seq.tokens``), so the caller
+        has drained: the host holds every row's next token, and the
+        device's ``last_tok`` is set from them at the end."""
         slot = self.slots.index(seq)
         for j in range(len(seq.prompt), seq.num_tokens - 1):
             tok = np.zeros((self.max_batch,), np.int32)
@@ -400,6 +521,11 @@ class ServeEngine:
                 jnp.asarray(pos), jnp.asarray(tok), jnp.asarray(act))
             self._record(seq, j + 1, logits[slot], aux, slot)
             seq.num_cached = j + 1
+        tok = np.zeros((self.max_batch,), np.int32)
+        for i, s in enumerate(self.slots):
+            if s is not None:
+                tok[i] = s.tokens[-1]
+        self._last_tok = jax.device_put(tok, self._tok_sharding)
 
     # -- speculative decoding ----------------------------------------
 
@@ -511,35 +637,36 @@ class ServeEngine:
                           (k + 1) / self.max_batch)
 
     def _do_prefill(self, seq: Sequence) -> None:
+        resumed = seq.num_generated > 0
+        if resumed:
+            self._drain("replay")
         slot = self.slots.index(None)
         self.slots[slot] = seq
         seq.slot = slot
-        resumed = seq.num_generated > 0
         S = self.max_prompt_len
         ids = np.zeros((S,), np.int32)
         ids[:len(seq.prompt)] = seq.prompt
-        # dispatch to the first token on the host: the fetch closes the
-        # span, so its duration is the prefill as the request feels it
-        # (closed at dispatch it read 2 ms of a 55 ms prefill). A child
-        # of the round by nesting; ``seq_id`` links it to the request.
-        # A resumed prefill samples nothing and fetches nothing: its
-        # span closes at dispatch and says ``resumed``.
+        # the span closes at DISPATCH: the prompt's first token stays on
+        # the device (row ``slot`` of ``last_tok``) and is counted when a
+        # later step reads it. A child of the round by nesting;
+        # ``seq_id`` links it to the request.
         with _mspans.span("serve/prefill", seq_id=seq.seq_id,
                           resumed=resumed,
                           prompt_tokens=len(seq.prompt)):
-            logits, next_tok, self.state, aux = self._prefill(
+            t0 = time.perf_counter()
+            logits, self._last_tok, self.state, aux = self._prefill(
                 self.params, self.state, jnp.asarray(self._bt_row(seq)),
-                jnp.int32(len(seq.prompt)), jnp.asarray(ids))
+                np.int32(len(seq.prompt)), jnp.asarray(ids),
+                self._last_tok, np.int32(slot))
             seq.num_cached = len(seq.prompt)
-            if not resumed:
-                next_tok = int(next_tok)
         _mhooks.counter("serve/prefills")
-        self._record(seq, len(seq.prompt), logits, aux)
         if not resumed:
-            self._sample(seq, next_tok)
+            self._hold([(seq, slot)], logits, aux, decode=False,
+                       t_dispatch=t0)
         else:
             # resumed: the generated tokens already exist; rebuild the
             # cache deterministically instead of re-sampling
+            self._record(seq, len(seq.prompt), logits, aux)
             with _mspans.span("serve/replay", parent=seq.span,
                               seq_id=seq.seq_id,
                               tokens=max(0, seq.num_generated - 1)):
@@ -561,75 +688,94 @@ class ServeEngine:
         table): ``serve/round`` holds ``serve/schedule``, one
         ``serve/prefill`` per admitted sequence, ``serve/decode_inputs``,
         ``serve/decode_step``, ``serve/sample`` and ``serve/gauges``.
-        The round's duration minus its ``serve/prefill`` and
-        ``serve/decode_step`` children is the host's time outside the
-        dispatching spans (inside them the device still waits for the
-        dispatch to arrive). Detached, each span is one global read."""
+        The round DISPATCHES its prefills and its decode, and only then
+        reads the tokens the previous round left in flight
+        (``_decode_round``): while the host samples them, lets the
+        caller admit, schedules and builds the next inputs, the device
+        runs this round. Detached, each span is one global read."""
+        self._step_no += 1
         with _mspans.span("serve/round"):
             with _mspans.span("serve/schedule"):
                 plan = self.sched.schedule()
+                if plan.preempted:
+                    # a preempted sequence re-enters by its tokens' values
+                    self._drain("preempt")
                 for seq in plan.preempted:
                     self._free_slot(seq)
             for seq in plan.prefill:
                 self._do_prefill(seq)
-            decodes = [s for s in plan.decode
-                       if not s.done and s.state == RUNNING]
+            decodes = [s for s in plan.decode if s.state == RUNNING]
             if decodes and self.spec_k:
                 # speculative mode: one draft+verify round per sequence
-                # (the verify window owns the batch rows)
+                # (the verify window owns the batch rows), accepted by
+                # VALUE: synchronous as it always was
+                self._drain("spec")
                 for seq in decodes:
                     if seq.done or seq.state != RUNNING:
                         continue
                     self._spec_round(seq)
-            elif decodes:
+            else:
                 self._decode_round(decodes)
             with _mspans.span("serve/gauges"):
                 self._record_step_gauges()
         return self.sched.has_work
 
+    def _due(self, decoding: bool) -> int:
+        """How many of the dispatches in flight this step reads. With a
+        decode round just dispatched (``decoding``) the device has work
+        to do while the host reads and counts: the step reads what
+        EARLIER steps left. Otherwise it reads everything, because no
+        read could hide behind device work: no decode went out (a cold
+        start, or a burst's first step: the next round waits for these
+        prefills anyway, and a first wave of them would otherwise be
+        left queued behind the caller's back), or no sequence is left
+        that a next step could dispatch for (the end of the work)."""
+        if decoding and (self.sched.waiting or
+                         not all(s.sent for s in self.sched.running)):
+            return sum(e.step < self._step_no for e in self._in_flight)
+        if self._in_flight:
+            # this read empties the pipeline: it overlaps nothing
+            _mhooks.counter("serve/pipeline_drains", reason="idle")
+        return len(self._in_flight)
+
     def _decode_round(self, decodes: List[Sequence]) -> None:
-        """One batched decode for the running sequences."""
-        with _mspans.span("serve/decode_inputs"):
-            tok = np.zeros((self.max_batch,), np.int32)
-            pos = np.zeros((self.max_batch,), np.int32)
-            act = np.zeros((self.max_batch,), bool)
-            bts = np.zeros((self.max_batch, self.pages_per_seq), np.int32)
-            for seq in decodes:
-                slot = seq.slot
-                tok[slot] = seq.tokens[-1]
-                pos[slot] = seq.num_tokens - 1
-                act[slot] = True
-                bts[slot] = self._bt_row(seq)
-            # decode_step_times has always counted the four uploads
-            t0 = time.perf_counter()
-            batch = (jnp.asarray(bts), jnp.asarray(pos), jnp.asarray(tok),
-                     jnp.asarray(act))
-        with _mspans.span("serve/decode_step", n_active=len(decodes)):
-            logits, next_toks, self.state, aux = self._decode(
-                self.params, self.state, *batch)
-            next_np = np.asarray(next_toks)
-        logits_np = np.asarray(logits) if self.record_logits else None
-        dt = time.perf_counter() - t0
-        self.decode_step_times.append(dt)
+        """Dispatch one batched decode for the running sequences, THEN
+        read what earlier steps left in flight. ``serve/decode_step``
+        holds this round's dispatch and the wait for the previous
+        round's tokens; ``serve/sample`` counts those (and, in a step
+        without a decode round, holds the wait too)."""
+        if not decodes and not self._in_flight:
+            return
+        fetched = None
+        if decodes:
+            with _mspans.span("serve/decode_inputs"):
+                pos = np.zeros((self.max_batch,), np.int32)
+                act = np.zeros((self.max_batch,), bool)
+                bts = np.zeros((self.max_batch, self.pages_per_seq),
+                               np.int32)
+                for seq in decodes:
+                    slot = seq.slot
+                    pos[slot] = seq.num_dispatched - 1
+                    act[slot] = True
+                    bts[slot] = self._bt_row(seq)
+                    seq.num_cached = seq.num_dispatched
+                # a round's time has always counted its uploads
+                t0 = time.perf_counter()
+                batch = (jnp.asarray(bts), jnp.asarray(pos),
+                         self._last_tok, jnp.asarray(act))
+            with _mspans.span("serve/decode_step", n_active=len(decodes)):
+                if any(e.decode for e in self._in_flight):
+                    _mhooks.counter("serve/rounds_overlapped")
+                logits, self._last_tok, self.state, aux = self._decode(
+                    self.params, self.state, *batch)
+                self._hold([(s, s.slot) for s in decodes], logits, aux,
+                           decode=True, t_dispatch=t0)
+                fetched = self._take(self._due(True))
         with _mspans.span("serve/sample"):
-            self._record_round(aux)
-            if logits_np is not None:
-                aux = jax.device_get(aux)
-            if _mhooks.enabled():
-                # per-TOKEN latency: each active slot produced one
-                # token this step — the streaming-percentile source of
-                # the serve SLO numbers (p50/p95/p99)
-                for _ in decodes:
-                    _mhooks.observe("serve/token_latency_ms", 1e3 * dt)
-                _mhooks.gauge("serve/batch_fill",
-                              len(decodes) / self.max_batch)
-            for seq in decodes:
-                slot = seq.slot
-                seq.num_cached = seq.num_tokens
-                if logits_np is not None:
-                    self._record(seq, seq.num_tokens, logits_np[slot], aux,
-                                 slot)
-                self._sample(seq, next_np[slot])
+            if fetched is None:
+                fetched = self._take(self._due(False))
+            for f in fetched:
+                self._commit(*f)
 
     def _record_step_gauges(self) -> None:
         """Pool-occupancy + queue-state gauges, once per scheduler
@@ -654,7 +800,9 @@ class ServeEngine:
 
     def preempt(self, seq_id: int) -> None:
         """Force-preempt a running sequence (tests/benchmarks; the
-        organic path is the scheduler's evict-on-exhaustion)."""
+        organic path is the scheduler's evict-on-exhaustion). Its
+        tokens in flight are read first: it re-enters by their values."""
+        self._drain("preempt")
         for seq in self.sched.running:
             if seq.seq_id == seq_id:
                 self.sched._preempt(seq)

@@ -69,6 +69,10 @@ class Sequence:
     queued_t: float = 0.0              # perf_counter at last (re)queue
     queue_wait_s: float = 0.0          # total time spent WAITING
     ttft_ms: Optional[float] = None    # arrival -> first generated token
+    # tokens the engine has dispatched whose VALUES are not on the host
+    # yet (it runs one round ahead of the tokens it has read): scheduling
+    # goes by ``num_dispatched``, everything a caller counts by ``tokens``
+    in_flight: int = 0
 
     def __post_init__(self):
         if not self.prompt:
@@ -87,6 +91,17 @@ class Sequence:
     @property
     def done(self) -> bool:
         return self.num_generated >= self.max_new_tokens
+
+    @property
+    def num_dispatched(self) -> int:
+        """Positions that exist on the device: read tokens + in flight."""
+        return len(self.tokens) + self.in_flight
+
+    @property
+    def sent(self) -> bool:
+        """The request's last round has gone out: it takes no further
+        row, and ends when the tokens in flight are read."""
+        return self.num_generated + self.in_flight >= self.max_new_tokens
 
 
 class PageAllocator:
@@ -193,7 +208,7 @@ class Scheduler:
                          seq_id=seq.seq_id,
                          n_preemptions=seq.n_preemptions,
                          freed_pages=freed,
-                         tokens_kept=seq.num_tokens)
+                         tokens_kept=seq.num_dispatched)
         seq.queued_t = time.perf_counter()
         seq.queue_span = _mspans.start(
             "serve/queue_wait", parent=seq.span, seq_id=seq.seq_id,
@@ -209,23 +224,26 @@ class Scheduler:
         plan = StepPlan()
 
         # 1. growth: every running sequence must hold pages for its
-        # next decode write (position num_tokens-1) plus the
+        # next decode write (position num_dispatched-1) plus the
         # speculative lookahead window. Earliest arrivals
         # are served first; exhaustion preempts the LATEST-arrived
         # running sequence — possibly the grower itself, when it is the
-        # latest.
+        # latest. A ``sent`` sequence only waits for its tokens to be
+        # read: it neither grows nor decodes, and is no victim (it
+        # frees its pages at that read).
         for seq in sorted(self.running, key=lambda s: s.arrival):
-            if seq.state != RUNNING:
+            if seq.state != RUNNING or seq.sent:
                 continue                    # preempted earlier this pass
             grown = True
-            want = self._pages_needed(seq.num_tokens + self.lookahead)
+            want = self._pages_needed(seq.num_dispatched + self.lookahead)
             while want > len(seq.pages):
                 need = want - len(seq.pages)
                 got = self.allocator.alloc(need)
                 if got is not None:
                     seq.pages.extend(got)
                     break
-                victim = max(self.running, key=lambda s: s.arrival)
+                victim = max((s for s in self.running if not s.sent),
+                             key=lambda s: s.arrival)
                 self._preempt(victim)
                 plan.preempted.append(victim)
                 if victim is seq:
@@ -239,7 +257,8 @@ class Scheduler:
         # recompute) plus the next write.
         while self.waiting and len(self.running) < self.max_batch:
             seq = self.waiting[0]
-            need = self._pages_needed(seq.num_tokens + 1 + self.lookahead)
+            need = self._pages_needed(seq.num_dispatched + 1
+                                      + self.lookahead)
             if need > self.allocator.num_pages - 1:
                 raise RuntimeError(
                     f"sequence {seq.seq_id} needs {need} pages; the pool "

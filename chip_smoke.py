@@ -310,7 +310,7 @@ def _serve_programs(eng, sharding=None):
         eng.params, eng.state, i32(B, m), i32(B), i32(B),
         i32(B, dtype=jnp.bool_))
     prefill = eng._prefill.lower(eng.params, eng.state, i32(m), i32(),
-                                 i32(S))
+                                 i32(S), i32(B), i32())
     return decode, prefill
 
 

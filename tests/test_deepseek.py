@@ -364,12 +364,19 @@ def test_absorbed_attention_is_expanded_attention(params):
     through a decode step (absorbed form over the cached latent)."""
     tokens = list(np.random.RandomState(4).randint(0, 96, 12))
     eng, (a, b) = _serve(params, prompts=[tokens, tokens[:11]], n_new=2)
-    # request b decodes token 11 only if it sampled it; feed it by hand
+    # request b decodes token 11 only if it sampled it; feed it by hand.
+    # The fed token lives on the device (the engine's ``last_tok`` row of
+    # the sequence), so teacher-forcing sets it there too, after a drain
+    # has put the prefill's own token on the host
     eng2 = _engine(params)
     sid = eng2.add_request(tokens[:11], 2)
-    eng2.step()                                   # prefill: samples one
-    eng2.seqs[sid].tokens[-1] = tokens[11]        # teacher-force token 11
+    eng2.step()                                   # prefill: dispatches one
+    eng2._drain("test")                           # its token, by value
+    seq = eng2.seqs[sid]
+    seq.tokens[-1] = tokens[11]                   # teacher-force token 11
+    eng2._last_tok = eng2._last_tok.at[seq.slot].set(tokens[11])
     eng2.step()                                   # decode it (absorbed)
+    eng2._drain("test")
     np.testing.assert_allclose(eng2.logits_log[sid][12],
                                eng.logits_log[a][12], rtol=1e-4, atol=1e-5)
 
@@ -410,6 +417,37 @@ def test_evict_and_readmit_through_the_latent_pool_is_bit_exact(params,
     assert [roomy.seqs[i].tokens for i in ids] == \
         [tight.seqs[i].tokens for i in ids]
     _assert_bitwise_equal(roomy, tight, ids)
+
+
+def test_one_round_ahead_equals_the_synchronous_order_bit_for_bit(params):
+    """The engine dispatches a round before it has read the last one's
+    tokens. Through the latent pool and the expert layers that changes
+    nothing: tokens, every logits row and every routing choice equal those
+    of the synchronous order (a drain after every step), a token is
+    counted only once its value is in ``seq.tokens``, and ``run()`` leaves
+    nothing in flight."""
+    requests = [(PROMPTS[0], 6), (PROMPTS[2], 1), (PROMPTS[1], 4),
+                (PROMPTS[0][:2], 7), (PROMPTS[2][3:], 3), (PROMPTS[1], 2)]
+    ahead, sync = _engine(params), _engine(params)
+    for eng in (ahead, sync):
+        ids = [eng.add_request(p, n) for p, n in requests]
+        while eng.sched.has_work:
+            eng.step()
+            assert eng.tokens_generated == sum(
+                s.num_generated for s in eng.seqs.values())
+            if eng is sync:
+                eng._drain("test")
+    assert ahead._in_flight == [] and ahead.run() == sync.run()
+    _assert_bitwise_equal(ahead, sync, ids)
+    for sid, (prompt, n) in zip(ids, requests):
+        rows = list(range(len(prompt), len(prompt) + n))
+        assert sorted(ahead.logits_log[sid]) == rows
+        assert sorted(ahead.aux_log[sid]) == rows
+        for pos in rows:
+            assert np.array_equal(ahead.aux_log[sid][pos]["moe_idx"],
+                                  sync.aux_log[sid][pos]["moe_idx"])
+    assert (ahead._decode._cache_size(), ahead._prefill._cache_size()) == \
+        (1, 1)
 
 
 # -- the pool's geometry and what the engine refuses ----------------------------------------

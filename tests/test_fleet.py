@@ -543,7 +543,7 @@ def test_purity_jaxprs_byte_identical_under_scraping(params):
         d = jax.make_jaxpr(eng._decode)(
             params, eng.state, bts, pos, tok, act)
         p = jax.make_jaxpr(eng._prefill)(
-            params, eng.state, bt1, jnp.int32(4), ids)
+            params, eng.state, bt1, jnp.int32(4), ids, tok, jnp.int32(0))
         return str(d), str(p)
 
     detached = trace_both()

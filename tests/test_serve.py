@@ -723,3 +723,183 @@ def test_naive_generate_baseline_matches_engine(params):
                                     [(p, 6) for p in PROMPTS],
                                     max_seq_len=32)
     assert naive == [out[i] for i in ids]
+
+
+# ---------------------------------------------------------------------------
+# one round ahead of the tokens read (PR 30)
+# ---------------------------------------------------------------------------
+
+MIXED = [([5, 9, 17, 3, 40, 22, 8], 9), ([11, 2, 33], 1), ([7] * 16, 12),
+         ([1, 2, 3, 4, 5, 6, 7, 8, 9], 2), ([60, 50, 40, 30], 6)]
+
+
+def _drive(eng, requests, *, after_step=None):
+    """Add ``requests`` and step until the work ends, holding at every
+    step what the engine promises of its counts: a token counts only once
+    its value is in ``seq.tokens``."""
+    ids = [eng.add_request(p, n) for p, n in requests]
+    steps = 0
+    while eng.sched.has_work:
+        eng.step()
+        steps += 1
+        seqs = eng.seqs.values()
+        assert eng.tokens_generated == sum(s.num_generated for s in seqs)
+        assert sum(s.in_flight for s in seqs) == \
+            sum(len(e.rows) for e in eng._in_flight)
+        if after_step:
+            after_step(eng, steps)
+        assert steps < 500
+    return ids
+
+
+def _synchronous(eng, steps):
+    """The parent's order of work: every token read in the step that
+    dispatched it."""
+    eng._drain("test")
+
+
+def test_scheduler_goes_by_positions_dispatched():
+    """Growth and rows go by ``num_dispatched``; a ``sent`` sequence (its
+    last round has gone out) takes no row, grows nothing and is no
+    victim."""
+    sched = Scheduler(num_pages=4, page_size=4, max_batch=2)
+    a, b = _seq(0, n_prompt=6, max_new=4), _seq(1, n_prompt=3, max_new=1)
+    sched.add(a)
+    sched.add(b)
+    assert len(sched.schedule().prefill) == 2   # 2 + 1 pages of 3 usable
+    a.in_flight = b.in_flight = 1               # the prefills' tokens
+    assert (a.num_dispatched, a.sent, b.sent, b.done) == (7, False, True,
+                                                          False)
+    assert sched.schedule().decode == [a] and len(a.pages) == 2
+    a.in_flight = 2                             # position 8 is next: a page
+    a.tokens.append(9)
+    plan = sched.schedule()                     # none free: A is the only
+    assert plan.preempted == [a]                # victim, B is ``sent``
+    assert b.state == RUNNING and plan.decode == []
+    # re-admission covers the tokens in flight too: 9 positions + 1
+    assert sched._pages_needed(a.num_dispatched + 1) == 3
+
+
+@pytest.mark.parametrize("kw", [{}, {"fp8": True}], ids=["bf16", "fp8-kv"])
+def test_one_round_ahead_equals_the_synchronous_order_bit_for_bit(params,
+                                                                  kw):
+    """Mixed prompts and lengths through five batch rows' worth of
+    admissions: tokens and every ``record_logits`` row equal those of the
+    synchronous order (a drain after every step), and the tokens are
+    ``naive_generate``'s."""
+    ahead, sync = (_engine(params, max_batch=3, **kw) for _ in range(2))
+    ids = _drive(ahead, MIXED)
+    assert _drive(sync, MIXED, after_step=_synchronous) == ids
+    for sid, (prompt, n) in zip(ids, MIXED):
+        assert ahead.seqs[sid].tokens == sync.seqs[sid].tokens
+        assert sorted(ahead.logits_log[sid]) == \
+            list(range(len(prompt), len(prompt) + n))
+    _assert_logits_bitwise_equal(ahead, sync, ids)
+    if not kw:
+        naive, _ = serve.naive_generate(CFG, params, MIXED, max_seq_len=32)
+        assert naive == [ahead.seqs[i].tokens[len(p):]
+                         for i, (p, _) in zip(ids, MIXED)]
+
+
+def test_run_leaves_nothing_in_flight_and_compiles_nothing_new(params):
+    eng = _engine(params, max_batch=3)
+    prefill, scalars = eng._prefill, []
+
+    def seen_prefill(*args):
+        scalars.extend((args[3], args[6]))       # length, slot
+        return prefill(*args)
+
+    eng._prefill = seen_prefill
+    ids = [eng.add_request(p, n) for p, n in MIXED]
+    out = eng.run()
+    eng._prefill = prefill
+    # host scalars ride the call as operands: a ``jnp.int32(x)`` would be
+    # one more (tiny) compiled program a prefill
+    assert len(scalars) == 2 * len(MIXED)
+    assert all(isinstance(x, np.int32) for x in scalars)
+    assert [len(out[i]) for i in ids] == [n for _, n in MIXED]
+    assert eng._in_flight == []
+    assert all(s.in_flight == 0 and s.done for s in eng.seqs.values())
+    assert eng.slots == [None] * 3
+    assert eng.sched.allocator.free_pages == eng.ccfg.num_pages - 1
+    # still one decode and one prefill program, whatever fed them
+    assert (eng._decode._cache_size(), eng._prefill._cache_size()) == (1, 1)
+
+
+def test_steady_run_overlaps_every_round_but_the_first(params):
+    """``serve/rounds_overlapped`` == decode rounds - 1, and no drain
+    while decode rounds go out: the reads that overlap nothing are the
+    first step's (prefills only: no decode went out) and the last, when
+    the work has ended (both ``idle``)."""
+    from apex_tpu import monitor
+    rec = monitor.Recorder(traced_hooks=False)
+    eng = _engine(params)
+    with monitor.attached(rec):
+        for p in PROMPTS:
+            eng.add_request(p, N_NEW)
+        eng.run()
+    rounds = len(eng.decode_step_times)
+    assert rounds == N_NEW - 1
+    assert rec.counters()["serve/rounds_overlapped"] == rounds - 1
+    events = [e.get("reason", e["name"]) for e in rec.records("counter")
+              if e["name"] in ("serve/pipeline_drains",
+                               "serve/rounds_overlapped")]
+    assert events == (["idle"] + ["serve/rounds_overlapped"] * (rounds - 1)
+                      + ["idle"])
+    assert all(t > 0 for t in eng.decode_step_times)
+
+
+def test_forced_preempt_with_a_token_in_flight_drains_once(params):
+    """``preempt()`` reads the sequence's token in flight first (one
+    drain, reason ``preempt``); the sequence re-enters by its values and
+    resumes BIT-exact."""
+    from apex_tpu import monitor
+    plain, ids, out, _ = _run(params)
+    rec = monitor.Recorder(traced_hooks=False)
+    eng = _engine(params)
+    with monitor.attached(rec):
+        assert [eng.add_request(p, N_NEW) for p in PROMPTS] == ids
+        for _ in range(4):
+            eng.step()
+        seq = eng.seqs[ids[0]]
+        kept = seq.num_dispatched
+        assert seq.in_flight == 1
+        eng.preempt(ids[0])
+        assert (seq.in_flight, seq.num_tokens, seq.state) == (0, kept,
+                                                              WAITING)
+        assert eng._in_flight == []
+        eng.run()
+    reasons = [e["reason"] for e in rec.records("counter")
+               if e["name"] == "serve/pipeline_drains"]
+    assert reasons == ["idle", "preempt", "idle"]
+    assert {i: eng.seqs[i].tokens[len(eng.seqs[i].prompt):]
+            for i in ids} == out
+    _assert_logits_bitwise_equal(plain, eng, ids)
+    # replay went through the same two programs
+    assert (eng._decode._cache_size(), eng._prefill._cache_size()) == (1, 1)
+
+
+def test_round_is_dispatched_before_the_previous_rounds_tokens_are_read(
+        params):
+    """The order of a steady run, with ``_decode`` and ``_fetch`` stubbed
+    to log: dispatch 1, dispatch 2, read 1, dispatch 3, read 2, ..."""
+    eng = _engine(params)
+    log, decode, fetch = [], eng._decode, eng._fetch
+
+    def logged_decode(*args):
+        log.append(("dispatch", sum(k == "dispatch" for k, _ in log) + 1))
+        return decode(*args)
+
+    def logged_fetch(e):
+        if e.decode:
+            log.append(("read", sum(k == "read" for k, _ in log) + 1))
+        return fetch(e)
+
+    eng._decode, eng._fetch = logged_decode, logged_fetch
+    for p in PROMPTS:
+        eng.add_request(p, 5)
+    eng.run()
+    want = [("dispatch", 1)]
+    for n in range(2, 5):
+        want += [("dispatch", n), ("read", n - 1)]
+    assert log == want + [("read", 4)]

@@ -188,8 +188,9 @@ def test_round_span_tree_every_child_inside_its_parent(params):
         eng.step()
     spans_ = _closed(rec)
     first, rid = [i for i, s in spans_.items() if s[0] == "serve/round"]
+    # no decode went out: the prefill's token is read in the round itself
     assert [s[0] for s in spans_.values() if s[1] == first] == [
-        "serve/schedule", "serve/prefill", "serve/gauges"]
+        "serve/schedule", "serve/prefill", "serve/sample", "serve/gauges"]
     _, parent, r0, r1, _ = spans_[rid]
     assert parent is None
     kids = sorted((s for s in spans_.values() if s[1] == rid),
@@ -212,41 +213,54 @@ def test_round_span_tree_every_child_inside_its_parent(params):
     assert 0 < (r1 - r0) - covered < r1 - r0
 
 
-def test_prefill_span_closes_after_the_first_token_is_on_the_host(params):
-    """``serve/prefill`` runs from the dispatch to the fetch of the first
-    token: at least as long as ``block_until_ready`` on the same call, and
-    it holds the fetch itself (closed at dispatch it would hold
-    neither)."""
-    import time
-    FETCH_S = 0.05
-    seen = {}
-
-    class SlowToken:
-        def __init__(self, tok):
-            self.tok = tok
-
-        def __int__(self):
-            time.sleep(FETCH_S)
-            return int(self.tok)
-
+def test_prefill_span_closes_at_dispatch_and_its_token_counts_when_read(
+        params):
+    """``serve/prefill`` closes at the dispatch: the prompt's first token
+    stays on the device and nothing of it is counted. With a decode round
+    going out, the NEXT step reads it, inside ``serve/decode_step`` and
+    after that round's own dispatch, and only ``serve/sample`` counts
+    it."""
+    from apex_tpu.monitor import spans
     rec = monitor.Recorder(traced_hooks=False)
     eng = _engine(params)
-    prefill = eng._prefill
+    fetch, decode = eng._fetch, eng._decode
 
-    def timed_prefill(*args):
-        t0 = time.perf_counter()
-        logits, tok, *rest = jax.block_until_ready(prefill(*args))
-        seen["block_s"] = time.perf_counter() - t0
-        return (logits, SlowToken(tok), *rest)
+    def marked_fetch(e):
+        spans.annotate("test/fetch", decode=e.decode)
+        return fetch(e)
 
-    eng._prefill = timed_prefill
+    def marked_decode(*args):
+        spans.annotate("test/dispatch")
+        return decode(*args)
+
+    def names():
+        return [(e["kind"], e["name"]) for e in rec.records()]
+
+    eng.add_request(PROMPTS[0], N_NEW)
+    eng.step()
+    eng.step()                            # the first request is decoding
+    eng._fetch, eng._decode = marked_fetch, marked_decode
     with monitor.attached(rec):
-        sid = eng.add_request(PROMPTS[0], 2)
+        sid = eng.add_request(PROMPTS[1], 3)
+        seq = eng.seqs[sid]
+        generated = eng.tokens_generated
+        eng.step()        # prefills it, dispatches a decode, reads the last
+        assert ("span_end", "serve/prefill") in names()
+        assert [e["decode"] for e in rec.records("span_event")
+                if e["name"] == "test/fetch"] == [True]
+        assert (seq.num_generated, seq.in_flight, seq.ttft_ms) == (0, 1, None)
+        assert eng.tokens_generated == generated + 1     # the other's token
+        n = len(rec.records())
         eng.step()
-    (pre,) = [e for e in rec.records("span_end")
-              if e["name"] == "serve/prefill"]
-    assert pre["value"] >= seen["block_s"] + FETCH_S - 1e-3
-    assert eng.seqs[sid].num_generated >= 1
+    assert (seq.num_generated, seq.in_flight) == (1, 1)
+    assert seq.ttft_ms > 0
+    later = [(e["kind"], e["name"]) for e in rec.records()[n:]]
+    order = [later.index(k) for k in (
+        ("span_start", "serve/decode_step"), ("span_event", "test/dispatch"),
+        ("span_event", "test/fetch"), ("span_end", "serve/decode_step"),
+        ("span_start", "serve/sample"), ("counter", "serve/tokens_generated"),
+        ("span_end", "serve/sample"))]
+    assert order == sorted(order), order
 
 
 # ---------------------------------------------------------------------------
@@ -272,7 +286,7 @@ def test_decode_prefill_jaxprs_byte_identical_spans_on_vs_off(params,
         d = jax.make_jaxpr(eng._decode)(
             params, eng.state, bts, pos, tok, act)
         p = jax.make_jaxpr(eng._prefill)(
-            params, eng.state, bt1, jnp.int32(4), ids)
+            params, eng.state, bt1, jnp.int32(4), ids, tok, jnp.int32(0))
         return str(d), str(p)
 
     detached = trace_both()
