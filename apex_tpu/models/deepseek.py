@@ -8,7 +8,8 @@ This module is the model's static description and the mathematics every
 path shares: :class:`DeepseekConfig`, the parameter tree
 (:func:`init_params`), the YaRN constants, the rotary rotation and the
 attention projections. The serving forwards over the paged latent cache
-are in :mod:`apex_tpu.serve.deepseek`, the expert layer in
+are in :mod:`apex_tpu.serve.latent` (this model's layer in
+:mod:`apex_tpu.serve.deepseek`), the expert layer in
 :mod:`apex_tpu.transformer.moe_dropless`. Nothing here is imported by
 ``apex_tpu.models`` itself: import the module by name.
 
@@ -38,6 +39,7 @@ columns and up in the rest (one matmul for both).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional, Tuple
 
@@ -80,6 +82,12 @@ class DeepseekConfig:
     dtype: Any = jnp.bfloat16
     init_std: float = 0.02
 
+    #: what the expert layer asks of a description beside the share
+    #: (``transformer/moe_dropless.py``): the rule, and how many of the
+    #: router's slots past the routed experts compute nothing
+    routing = "sigmoid_group_limited"
+    zero_expert_num = 0
+
     def __post_init__(self):
         if self.n_routed_experts % self.n_group:
             raise ValueError("n_routed_experts must divide into n_group")
@@ -114,7 +122,7 @@ def _yarn_mscale(scale: float, mscale: float) -> float:
     return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
 
 
-def yarn_inv_freq(cfg: DeepseekConfig):
+def yarn_inv_freq(cfg):
     """Rotary inverse frequencies, float32 ``[rope_dim / 2]``: between the
     correction dims of ``beta_fast`` and ``beta_slow`` a linear ramp blends
     ``theta^(-2i/d)`` (fast dims, kept) into the same over ``factor`` (slow
@@ -141,7 +149,7 @@ def yarn_inv_freq(cfg: DeepseekConfig):
                         for f, r in zip(plain, ramp)], jnp.float32)
 
 
-def rope_factor(cfg: DeepseekConfig) -> float:
+def rope_factor(cfg) -> float:
     """The factor on cos and sin (``mscale / mscale_all_dim`` scales)."""
     rs = dict(cfg.rope_scaling)
     if not rs:
@@ -153,7 +161,7 @@ def rope_factor(cfg: DeepseekConfig) -> float:
     return _yarn_mscale(f, 1.0)
 
 
-def softmax_scale(cfg: DeepseekConfig) -> float:
+def softmax_scale(cfg) -> float:
     """``qk_head_dim^-0.5``, times the squared YaRN ``mscale_all_dim``
     scale where the configuration has one."""
     rs = dict(cfg.rope_scaling)
@@ -164,7 +172,7 @@ def softmax_scale(cfg: DeepseekConfig) -> float:
     return s
 
 
-def rope(x, positions, cfg: DeepseekConfig):
+def rope(x, positions, cfg):
     """Rotate the interleaved pairs ``(2i, 2i+1)`` of ``x`` ``[t, ...,
     rope_dim]`` by ``positions`` ``[t]``, in float32, back in ``x.dtype``."""
     ang = positions.astype(jnp.float32)[:, None] * yarn_inv_freq(cfg)[None]
@@ -180,11 +188,26 @@ def rope(x, positions, cfg: DeepseekConfig):
 
 # -- parameters -------------------------------------------------------------------
 
+def attention_params(cfg, w, ones):
+    """One latent attention's sub-tree: ``w(*shape)`` draws a matrix,
+    ``ones(d)`` is a norm weight (the order of the draws is part of what a
+    seed means)."""
+    h, n = cfg.hidden_size, cfg.num_heads
+    return {"q_a": w(h, cfg.q_lora_rank),
+            "q_norm": ones(cfg.q_lora_rank),
+            "q_b": w(cfg.q_lora_rank, n * cfg.qk_head_dim),
+            "kv_a": w(h, cfg.latent_dim),
+            "kv_norm": ones(cfg.kv_lora_rank),
+            "kv_b": w(cfg.kv_lora_rank,
+                      n * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            "o": w(n * cfg.v_head_dim, h)}
+
+
 def init_params(cfg: DeepseekConfig, key):
     """Seeded random weights in ``cfg.dtype`` (normal, ``init_std``; norm
     weights 1; router and its correction bias float32, the bias small and
     non-zero so that it is exercised). Jit-pure."""
-    h, n, dt, std = cfg.hidden_size, cfg.num_heads, cfg.dtype, cfg.init_std
+    h, dt, std = cfg.hidden_size, cfg.dtype, cfg.init_std
     im, nl = cfg.moe_intermediate_size, cfg.local_experts
     keys = iter(jax.random.split(key, 4 + 16 * cfg.num_layers))
 
@@ -198,17 +221,8 @@ def init_params(cfg: DeepseekConfig, key):
     params = {"embed": w(cfg.vocab_size, h), "head": w(h, cfg.vocab_size),
               "norm_f": ones(h)}
     for i in range(cfg.num_layers):
-        layer = {
-            "attn_norm": ones(h), "ffn_norm": ones(h),
-            "attn": {
-                "q_a": w(h, cfg.q_lora_rank),
-                "q_norm": ones(cfg.q_lora_rank),
-                "q_b": w(cfg.q_lora_rank, n * cfg.qk_head_dim),
-                "kv_a": w(h, cfg.latent_dim),
-                "kv_norm": ones(cfg.kv_lora_rank),
-                "kv_b": w(cfg.kv_lora_rank,
-                          n * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
-                "o": w(n * cfg.v_head_dim, h)}}
+        layer = {"attn_norm": ones(h), "ffn_norm": ones(h),
+                 "attn": attention_params(cfg, w, ones)}
         if cfg.is_moe(i):
             sh = im * cfg.n_shared_experts
             layer["moe"] = {
@@ -233,31 +247,53 @@ def rms_norm(x, weight, eps):
                                  eps)
 
 
-def gated_mlp(x, gate, up, down):
-    """``down(silu(gate x) * up x)``; the product in float32."""
-    g = jnp.dot(x, gate).astype(jnp.float32)
-    u = jnp.dot(x, up).astype(jnp.float32)
-    return jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), down)
+def gated_mlp(x, gate, up, down, *, acc=None):
+    """``down(silu(gate x) * up x)``; the product in float32. ``acc``: the
+    dtype the three matmuls leave their outputs in (None = ``x.dtype``;
+    float32 spares gate and up a rounding before the product and hands a
+    float32 residual stream an unrounded result: ``attention_inputs``)."""
+    dot = functools.partial(jnp.dot, preferred_element_type=acc or x.dtype)
+    g = dot(x, gate).astype(jnp.float32)
+    u = dot(x, up).astype(jnp.float32)
+    return dot((jax.nn.silu(g) * u).astype(x.dtype), down)
 
 
-def attention_inputs(cfg: DeepseekConfig, p, x, positions):
+def attention_inputs(cfg, p, x, positions, *, q_scale: float = 1.0,
+                     kv_scale: float = 1.0, acc=None):
     """The projections both attention paths start from, for rows ``x``
     ``[t, h]`` at ``positions`` ``[t]``: ``q_nope [t, n, nope]``, rotated
     ``q_pe [t, n, rope]``, and the token's cache row parts: the normalised
     latent ``c [t, kv_lora]`` and the rotated shared key ``k_pe [t, rope]``.
+    ``q_scale`` / ``kv_scale`` multiply the normalised query / key-value
+    latent (LongCat's ``mla_scale_q_lora`` / ``mla_scale_kv_lora``; at 1
+    nothing is multiplied). ``acc``: the dtype in which a projection's
+    output stays until the next matmul, the cache or the kernel needs it in
+    ``x.dtype`` (None = ``x.dtype``: every output is rounded as it leaves
+    its matmul; float32 rounds each of the four results once, after its
+    norm and scale or its rotation: with the scales the attention scores
+    are ~7 x as large, and every rounding on their way costs that much
+    more). ``cfg``: any description with this module's attention sizes
+    (:mod:`apex_tpu.models.longcat` has them too).
     """
-    t = x.shape[0]
+    t, dt = x.shape[0], x.dtype
     eps = cfg.rms_norm_eps
-    q = jnp.dot(rms_norm(jnp.dot(x, p["q_a"]), p["q_norm"], eps), p["q_b"])
+    dot = functools.partial(jnp.dot, preferred_element_type=acc or dt)
+    cq = rms_norm(dot(x, p["q_a"]), p["q_norm"], eps)
+    if q_scale != 1.0:
+        cq = cq * q_scale
+    q = dot(cq.astype(dt), p["q_b"])
     q = q.reshape(t, cfg.num_heads, cfg.qk_head_dim)
     q_nope, q_pe = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
-    ckv = jnp.dot(x, p["kv_a"])
+    ckv = dot(x, p["kv_a"])
     c = rms_norm(ckv[:, :cfg.kv_lora_rank], p["kv_norm"], eps)
+    if kv_scale != 1.0:
+        c = c * kv_scale
     k_pe = rope(ckv[:, cfg.kv_lora_rank:], positions, cfg)
-    return q_nope, rope(q_pe, positions, cfg), c, k_pe
+    return (q_nope.astype(dt), rope(q_pe, positions, cfg).astype(dt),
+            c.astype(dt), k_pe.astype(dt))
 
 
-def kv_b_heads(cfg: DeepseekConfig, p):
+def kv_b_heads(cfg, p):
     """``kv_b`` by head: ``(W_k [kv_lora, n, nope], W_v [kv_lora, n, v])``."""
     w = p["kv_b"].reshape(cfg.kv_lora_rank, cfg.num_heads,
                           cfg.qk_nope_head_dim + cfg.v_head_dim)

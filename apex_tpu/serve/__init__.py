@@ -25,7 +25,8 @@ existing subsystems on the decode hot path:
   the existing analytic walk;
 - the **model interface** (:class:`~apex_tpu.serve.model.GPTServed`):
   what the engine asks of a model. GPT answers it here; the
-  latent-attention model answers it in :mod:`apex_tpu.serve.deepseek`
+  latent-attention models answer it in :mod:`apex_tpu.serve.deepseek` and
+  :mod:`apex_tpu.serve.longcat` over :mod:`apex_tpu.serve.latent`
   (imported on demand, not by this package).
 
 Quick start (see ``examples/serve_gpt.py`` / ``docs/serve.md``)::

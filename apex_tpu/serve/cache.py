@@ -11,7 +11,9 @@ contract in ``ops.flash_attention``, above its paged kernels):
                       (a model that caches something else a token gives
                       the lanes of its row as ``row_width``: a latent
                       cache is ``kv_heads=1`` and one padded latent row,
-                      ``serve/deepseek.py``)
+                      ``serve/latent.py``; ``num_layers`` counts the
+                      pool's leaves, so a model with two attentions a
+                      layer asks for twice its layers)
     k_scale / v_scale [num_layers, kv_heads, num_pages]  f32 (fp8 mode)
 
 One leaf a layer, because a program that updates and reads 2 x L slices

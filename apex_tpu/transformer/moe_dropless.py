@@ -2,26 +2,41 @@
 
 The capacity-based layer of :mod:`apex_tpu.transformer.moe` (top-1/top-2,
 one-hot ``[t, E, C]`` dispatch, dropped tokens) cannot express top-8 of
-256. This one routes every token over ALL ``n_routed_experts`` with the
-DeepSeek-V3 rule, keeps the assignments whose expert lies in
-``[first_expert, first_expert + n_local)``, lays them out by expert
-(``ops.grouped_matmul.tile_layout``), runs gate/up and down as two grouped
-matmuls, gathers each token's rows back weighted, and adds the shared
-expert. No token is dropped, whatever the load.
+256. This one routes every token over ALL of the router's slots by the
+rule its model names (``cfg.routing``, below), keeps the assignments whose
+expert lies in ``[first_expert, first_expert + n_local)``, lays them out by
+expert (``ops.grouped_matmul.tile_layout``), runs gate/up and down as two
+grouped matmuls, gathers each token's rows back weighted, and adds what
+every chip computes alike: the shared expert where the layer has one, and
+for the zero-compute slots a token chose (slots past ``n_routed_experts``:
+identity experts that hold no weights) the sum of their weights times the
+token's own input. No token is dropped, whatever the load.
 
 With ``n_local == n_routed_experts`` that is the whole layer. With fewer
 it is one chip's part of an expert-parallel layer: what the absent experts
 would add is NOT here (no exchange, and nothing that stands in for the
-other chips); summed over the shares, with the shared expert (which every
-chip computes alike) counted once, the parts are the whole layer
-(``tests/test_deepseek.py``).
+other chips); summed over the shares, with the shared expert and the
+identity part (which every chip computes alike for the tokens it holds)
+counted once, the parts are the whole layer (``tests/test_deepseek.py``,
+``tests/test_longcat.py``).
 
-Routing (float32, as published): ``sc = sigmoid(x W_g)``; the correction
-bias moves the CHOICE only (``sc + b``); a group's score is the sum of its
-two best corrected scores, the best ``topk_group`` groups stay, the others'
-scores become 0; the top ``k`` of what is left are chosen; the weights are
-the UNcorrected scores of the chosen, normalised to sum to
-``routed_scaling_factor``.
+What a model's description has to answer: ``routing`` (a key of
+:data:`ROUTING`) with the sizes that rule reads, ``n_routed_experts``,
+``zero_expert_num``, ``first_expert``, ``local_experts``.
+
+Routing (float32, as published), two rules:
+
+- ``sigmoid_group_limited`` (DeepSeek-V3): ``sc = sigmoid(x W_g)``; the
+  correction bias moves the CHOICE only (``sc + b``); a group's score is
+  the sum of its two best corrected scores, the best ``topk_group`` groups
+  stay, the others' scores become 0; the top ``k`` of what is left are
+  chosen; the weights are the UNcorrected scores of the chosen, normalised
+  to sum to ``routed_scaling_factor``.
+- ``softmax_topk`` (LongCat-Flash): ``p = softmax(x W_r)`` over all
+  ``n_routed_experts + zero_expert_num`` slots; the correction bias moves
+  the choice only; the top ``moe_topk`` of ``p + b``, no groups; the
+  weights are ``p`` of the chosen times ``routed_scaling_factor``, NOT
+  renormalised.
 
 No operation mixes tokens: a token's output row depends on its own input
 alone (its position among an expert's rows changes which tile row computes
@@ -36,7 +51,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.models.deepseek import DeepseekConfig, gated_mlp
+from apex_tpu.models.deepseek import gated_mlp
 from apex_tpu.monitor import profile as _prof
 from apex_tpu.ops import grouped_matmul as gmm
 
@@ -46,13 +61,15 @@ from apex_tpu.ops import grouped_matmul as gmm
 BLOCK_M_DECODE, BLOCK_M_PREFILL = 32, 128
 
 
-def route(cfg: DeepseekConfig, router, bias, x):
-    """``(idx [t, k] int32, w [t, k] f32)`` over all routed experts."""
+def _router_logits(router, x):
+    return jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+def _route_sigmoid_group_limited(cfg, router, bias, x):
     t = x.shape[0]
     E, G = cfg.n_routed_experts, cfg.n_group
-    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
-                     precision=jax.lax.Precision.HIGHEST)
-    sc = jax.nn.sigmoid(logits)
+    sc = jax.nn.sigmoid(_router_logits(router, x))
     cor = sc + bias.astype(jnp.float32)
     best2 = jax.lax.top_k(cor.reshape(t, G, E // G), 2)[0].sum(-1)  # [t, G]
     groups = jax.lax.top_k(best2, cfg.topk_group)[1]                # [t, kg]
@@ -65,26 +82,49 @@ def route(cfg: DeepseekConfig, router, bias, x):
     return idx.astype(jnp.int32), w * cfg.routed_scaling_factor
 
 
-def expert_layer(cfg: DeepseekConfig, p, x, *, active=None,
-                 impl: str = "kernel", interpret: Optional[bool] = None):
+def _route_softmax_topk(cfg, router, bias, x):
+    p = jax.nn.softmax(_router_logits(router, x), axis=-1)
+    idx = jax.lax.top_k(p + bias.astype(jnp.float32), cfg.moe_topk)[1]
+    w = jnp.take_along_axis(p, idx, axis=1)
+    return idx.astype(jnp.int32), w * cfg.routed_scaling_factor
+
+
+#: the rules a description can name as its ``routing``
+ROUTING = {"sigmoid_group_limited": _route_sigmoid_group_limited,
+           "softmax_topk": _route_softmax_topk}
+
+
+def route(cfg, router, bias, x):
+    """``(idx [t, k] int32, w [t, k] f32)`` over all of the router's slots,
+    by the rule ``cfg.routing`` names."""
+    return ROUTING[cfg.routing](cfg, router, bias, x)
+
+
+def expert_layer(cfg, p, x, *, active=None, impl: str = "kernel",
+                 interpret: Optional[bool] = None):
     """This share's part of the expert layer for rows ``x`` ``[t, h]``.
 
-    ``p``: a layer's ``moe`` sub-tree (``models.deepseek``). ``active``
+    ``p``: a layer's ``moe`` sub-tree (``models.deepseek``,
+    ``models.longcat``; the shared expert is there or not). ``active``
     ``[t]`` bool masks rows that carry no token (a decode batch's empty
     slots): they are routed nowhere and counted nowhere. Returns ``(y [t,
-    h], stats)`` with ``stats`` = ``{"idx": chosen experts [t, k],
+    h], stats)`` with ``stats`` = ``{"idx": chosen slots [t, k],
     "assignments_local": [], "expert_load_max": [], "experts_touched": []}``
     (int32 scalars over the active rows: what this share was handed, its
-    fullest expert's rows, and how many of its experts got any).
+    fullest expert's rows, and how many of its experts got any) and, where
+    the router has zero-compute slots, ``"assignments_zero"`` (how many of
+    the rows' choices fell on them) and ``"real_experts_per_token_max"``
+    (the most real experts, held here or not, that one row chose).
     ``impl``: the grouped matmul's (``ops.grouped_matmul.IMPLS``)."""
     t, h = x.shape
-    k, nl = cfg.num_experts_per_tok, cfg.local_experts
-    bm = BLOCK_M_DECODE if t * k <= 4096 else BLOCK_M_PREFILL
-    max_rows = t * min(k, nl)
-    rows_padded = gmm.num_tiles(nl, bm, max_rows) * bm
+    nl = cfg.local_experts
     with _prof.scope("moe"):
         with _prof.scope("moe_route"):
             idx, w = route(cfg, p["router"], p["bias"], x)
+            k = idx.shape[1]
+            bm = BLOCK_M_DECODE if t * k <= 4096 else BLOCK_M_PREFILL
+            max_rows = t * min(k, nl)
+            rows_padded = gmm.num_tiles(nl, bm, max_rows) * bm
             local = idx - cfg.first_expert
             here = (local >= 0) & (local < nl)
             if active is not None:
@@ -119,11 +159,21 @@ def expert_layer(cfg: DeepseekConfig, p, x, *, active=None,
             # an absent expert's row index points at a row nobody wrote
             rows = jnp.where(here[:, :, None], rows.astype(jnp.float32), 0.0)
             y = jnp.einsum("tk,tkh->th", jnp.where(here, w, 0.0), rows)
-        with _prof.scope("moe_shared"):
-            sh = p["shared"]
-            y = y + gated_mlp(x, sh["gate"], sh["up"],
-                              sh["down"]).astype(jnp.float32)
-    stats = {"idx": idx, "assignments_local": counts.sum(),
-             "expert_load_max": counts.max(),
-             "experts_touched": (counts > 0).sum()}
+        stats = {"idx": idx, "assignments_local": counts.sum(),
+                 "expert_load_max": counts.max(),
+                 "experts_touched": (counts > 0).sum()}
+        if "shared" in p:
+            with _prof.scope("moe_shared"):
+                sh = p["shared"]
+                y = y + gated_mlp(x, sh["gate"], sh["up"],
+                                  sh["down"]).astype(jnp.float32)
+        if cfg.zero_expert_num:
+            with _prof.scope("moe_zero"):
+                zero = idx >= cfg.n_routed_experts
+                y = y + jnp.where(zero, w, 0.0).sum(-1, keepdims=True) \
+                    * x.astype(jnp.float32)
+                on = True if active is None else active[:, None]
+                stats["assignments_zero"] = (zero & on).sum()
+                stats["real_experts_per_token_max"] = \
+                    (~zero & on).sum(-1).max()
     return y.astype(x.dtype), stats

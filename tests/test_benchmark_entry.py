@@ -22,13 +22,14 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-FILES = ("test_rehearsal.py", "test_deepseek.py", "test_reference.py",
-         "test_manifest.py", "test_flops_bytes.py", "test_span_reduce.py")
+FILES = ("test_rehearsal.py", "test_deepseek.py", "test_longcat.py",
+         "test_reference.py", "test_manifest.py", "test_flops_bytes.py",
+         "test_span_reduce.py")
 
 
 @pytest.fixture(scope="module")
 def children():
-    """All six start together: under ``--dist loadfile`` this file is
+    """All seven start together: under ``--dist loadfile`` this file is
     one worker's, and run one after another they are three minutes of
     it, the last of them after every other worker has finished."""
     # tier-1's XLA_FLAGS asks for eight devices; without it

@@ -418,23 +418,52 @@ LATENT = dict(vocab_size=16032, hidden_size=7168, num_layers=2, num_heads=64,
                             ("original_max_position_embeddings", 4096)))
 
 
-def test_latent_serve_programs_update_the_pool_in_place(chip, chip_smoke):
-    """The same engine over the latent-attention model: its decode
-    (absorbed attention, grouped expert matmuls) and prefill (flash at d =
-    192) compile with their kernels, update the latent pool where it lies
+#: cell 6 of the benchmark (LongCat-Flash-Chat, one chip's share of EP32) at
+#: one of its four layers: two latent attentions (two leaves), two dense
+#: feed-forwards, the expert layer on its shortcut
+LONGCAT = dict(vocab_size=16384, hidden_size=6144, num_layers=1, num_heads=64,
+               q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+               qk_rope_head_dim=64, v_head_dim=128, ffn_hidden_size=12288,
+               expert_ffn_hidden_size=2048, n_routed_experts=512,
+               zero_expert_num=256, moe_topk=12, routed_scaling_factor=6.0,
+               n_local_experts=16, max_seq_len=1536)
+
+
+def _latent_model(name):
+    """``(served model, its init_params, the cell's engine sizes)``."""
+    if name == "deepseek":
+        from apex_tpu.models import deepseek as ds
+        from apex_tpu.serve.deepseek import DeepseekServed
+        cfg = ds.DeepseekConfig(**LATENT)
+        return DeepseekServed(cfg), ds.init_params, dict(
+            num_pages=5121, max_seq_len=2560, max_prompt_len=1024)
+    from apex_tpu.models import longcat as lc
+    from apex_tpu.serve.longcat import LongcatServed
+    cfg = lc.LongcatConfig(**LONGCAT)
+    return LongcatServed(cfg), lc.init_params, dict(
+        num_pages=3073, max_seq_len=1536, max_prompt_len=512)
+
+
+@pytest.mark.parametrize("name", ["deepseek", "longcat"])
+def test_latent_serve_programs_update_the_pool_in_place(chip, chip_smoke,
+                                                        name):
+    """The same engine over the latent-attention models at their cells'
+    shapes: decode (absorbed attention, grouped expert matmuls) and prefill
+    (flash at d = 192; LongCat's 128-lane V padded to it) compile with their
+    kernels, update every latent leaf where it lies (LongCat: two a layer)
     and need next to nothing beside their arguments."""
     from apex_tpu import serve
-    from apex_tpu.models import deepseek as ds
-    from apex_tpu.serve.deepseek import DeepseekServed
-    cfg = ds.DeepseekConfig(**LATENT)
+    model, init_params, sizes = _latent_model(name)
     params = jax.eval_shape(
-        lambda: ds.init_params(cfg, jax.random.PRNGKey(0)))
+        lambda: init_params(model.cfg, jax.random.PRNGKey(0)))
     eng = serve.ServeEngine(
-        DeepseekServed(cfg), _place(chip, params), num_pages=5121,
-        max_seq_len=2560, max_prompt_len=1024, page_size=128, max_batch=256,
-        paged_impl="kernel", attention_impl="flash", interpret=False)
+        model, _place(chip, params), page_size=128, max_batch=256,
+        paged_impl="kernel", attention_impl="flash", interpret=False,
+        **sizes)
     eng.state = _place(chip, eng.state)
-    assert eng.state.pools[0].shape == (1, 5121, 128, 640)
+    assert [p.shape for p in eng.state.pools] == \
+        [(1, sizes["num_pages"], 128, 640)] * (
+            model.cfg.num_layers * model.leaves_per_layer)
     decode, prefill = (p.compile() for p in chip_smoke._serve_programs(
         eng, sharding=chip))
     leaf = eng.state.pools[0].size
