@@ -15,6 +15,13 @@ Demonstrates every transformer-tier capability in one loop:
   (``allreduce_sequence_parallel_gradients``), dp gradient psum;
 - fp32 checkpoint save/resume round trip (``master_state_dict``).
 
+The train step DONATES its state, as ``amp.make_train_step`` does: the
+variables, the optimizer state and the scaler state passed in are updated
+where they lie and are deleted for the caller, who rebinds the step's
+outputs (``variables, opt_state, sstate, loss = step_f(variables, ...)``).
+A caller that needs the old state after a step copies it first
+(``jax.tree.map(jnp.copy, state)``); the batch is never donated.
+
 Run (8 virtual devices, dp=4 x tp=2):
     XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
         python examples/gpt/main_gpt.py --tp 2 --steps 30
@@ -53,7 +60,9 @@ def make_step_fns(mesh, model, opt):
     """``(init_f, step_f)`` for ``model`` on ``mesh``: the jitted
     ``shard_map`` programs of this example (rank-aware init; dp x tp
     train step with Megatron-SP grad reduction, vocab-parallel CE and a
-    dynamic loss scaler). ``chip_smoke.py --chips 4`` runs exactly these
+    dynamic loss scaler). ``step_f`` donates its first three arguments
+    (module docstring): every output leaf of the state is written over the
+    input leaf it replaces. ``chip_smoke.py --chips 4`` runs exactly these
     on real chips."""
     def init_state(ids):
         """Rank-aware init inside shard_map: each tp rank initializes its
@@ -97,7 +106,10 @@ def make_step_fns(mesh, model, opt):
     step_f = jax.jit(shard_map(
         train_step, mesh=mesh,
         in_specs=(P(), P(), P(), P(ps.DATA_AXIS), P(ps.DATA_AXIS)),
-        out_specs=(P(), P(), P(), P()), check_vma=False))
+        out_specs=(P(), P(), P(), P()), check_vma=False),
+        # keep_unused: the step never reads the old ``overflow`` flag, and
+        # an argument jit drops cannot give its buffer to the new one
+        donate_argnums=(0, 1, 2), keep_unused=True)
     return init_f, step_f
 
 
@@ -153,8 +165,11 @@ def main():
     # restore, continue bitwise
     fp32 = opt.master_params(opt_state, variables)
     variables2, opt_state2 = opt.restore_master(opt_state, fp32)
-    _, _, _, loss_resumed = step_f(variables2, opt_state2, sstate, ids, labels)
-    _, _, _, loss_direct = step_f(variables, opt_state, sstate, ids, labels)
+    # the step donates its state, and the restored state shares the Adam
+    # slots and the scaler with the live one: the resumed call gets a copy
+    resumed = jax.tree.map(jnp.copy, (variables2, opt_state2, sstate))
+    *_, loss_resumed = step_f(*resumed, ids, labels)
+    *_, loss_direct = step_f(variables, opt_state, sstate, ids, labels)
     assert float(loss_resumed) == float(loss_direct), (
         float(loss_resumed), float(loss_direct))
     print(f"loss {first:.4f} -> {last:.4f}; fp32 checkpoint round trip: "

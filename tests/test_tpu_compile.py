@@ -44,14 +44,19 @@ B, H, D, HID, V = 8, 16, 64, 1024, 32768
 
 
 @pytest.fixture(scope="module")
-def chip():
-    """One described v5e chip (of a 2x2 host), or skip the module."""
+def topo():
+    """A described v5e 2x2 host (four chips), or skip the module."""
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:   # no libtpu / no TPU compiler on this machine
         pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """One chip of that host."""
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -337,6 +342,58 @@ def test_train_step_compiles_for_v5e(chip, no_interpret, chip_smoke):
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15e9
+
+
+#: cell 4 of the benchmark (gpt2-large on four chips, tp=4 + SP, global
+#: batch 8 x 1,024) at two of its 36 layers
+CELL4 = dict(vocab=50304, hidden=1280, heads=20, seq=1024, batch=8,
+             mc_layers=2)
+
+
+def test_example_train_step_updates_its_state_in_place(topo, no_interpret,
+                                                       chip_smoke):
+    """``examples/gpt/main_gpt.py:make_step_fns`` on the four described
+    chips, as cell 4 and ``chip_smoke.py --chips 4`` run it: the step
+    donates its variables, optimizer state and scaler state, and the
+    chip's compiler aliases every leaf of them onto the output. Before
+    PR 33 nothing was donated: the state was resident twice and each step
+    allocated an output buffer for every leaf on every chip while the
+    chips waited (~1,750 leaves a chip, ~170 ms a step, at full depth)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from apex_tpu.models import GPT
+    from apex_tpu.optimizers import FusedAdam
+    from apex_tpu.transformer import parallel_state as ps
+    smoke = chip_smoke
+    sz = dataclasses.replace(smoke.FULL, **CELL4)
+    ps.destroy_model_parallel()
+    try:
+        mesh = ps.initialize_model_parallel(
+            tensor_model_parallel_size_=4, devices=list(topo.devices))
+        model = GPT(smoke._gpt_config(sz, layers=sz.mc_layers,
+                                      sequence_parallel=True))
+        init_f, step_f = smoke._main_gpt().make_step_fns(
+            mesh, model, FusedAdam(lr=3e-4, master_weights=True))
+        ids = jax.ShapeDtypeStruct(
+            (sz.batch, sz.seq), I32,
+            sharding=NamedSharding(mesh, P(ps.DATA_AXIS)))
+        replicated = NamedSharding(mesh, P())
+        state = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=replicated),
+            jax.eval_shape(init_f, ids))
+        compiled = step_f.lower(*state, ids, ids).compile()
+    finally:
+        ps.destroy_model_parallel()
+    text = compiled.as_text()
+    smoke._require_kernels(smoke._kernel_calls(text), flash_attention=2)
+    leaves = jax.tree.leaves(state)
+    # the module's header line lists every aliased pair: the compiler pads
+    # small leaves, so the byte count alone would let one scalar go missing
+    aliased = re.findall(r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)",
+                         text.split("\n", 1)[0])
+    assert sorted(map(int, aliased)) == list(range(len(leaves)))
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        x.size * x.dtype.itemsize for x in leaves)
 
 
 def _compile_serve(chip, smoke, sz, fp8_kv=False):
