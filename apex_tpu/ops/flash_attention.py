@@ -1030,7 +1030,8 @@ def paged_attention_reference(q, kv_pages, block_tables, seq_lens,
 _DECODE_BUFFER_BYTES = 8 * 1024 * 1024
 
 
-def _paged_decode_kernel(*refs, scale, page_size, group, fp8, pages_per_seq):
+def _paged_decode_kernel(*refs, scale, page_size, group, fp8, pages_per_seq,
+                         per_head=False):
     it = iter(refs)
     bt_ref = next(it)                       # scalar prefetch: [b*m] int32
     sl_ref = next(it)                       # scalar prefetch: [b] int32
@@ -1049,10 +1050,13 @@ def _paged_decode_kernel(*refs, scale, page_size, group, fp8, pages_per_seq):
         return pl.cdiv(sl_ref[b], page_size)
 
     def fetch(p, j, slot):
-        # page j of program p: one page of ``hb`` kv heads into a buffer
+        # page j of program p: one page of ``hb`` kv heads into a buffer.
+        # ``per_head``: the table has a row a (sequence, head block), a
+        # list of pages CHOSEN for it (``ops.sparse_attention``)
         b, h = p // n_hb, p % n_hb
+        row = p if per_head else b
         return pltpu.make_async_copy(
-            pool_ref.at[pl.ds(h * hb, hb), bt_ref[b * pages_per_seq + j]],
+            pool_ref.at[pl.ds(h * hb, hb), bt_ref[row * pages_per_seq + j]],
             buf.at[slot], sem.at[slot])
 
     seq_len = sl_ref[bi]
@@ -1154,13 +1158,19 @@ def _paged_decode_kernel(*refs, scale, page_size, group, fp8, pages_per_seq):
                    ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "hb", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "hb", "interpret",
+                                             "per_head"))
 def _paged_decode_call(q, kv_pages, block_tables, seq_lens, k_scales,
-                       v_scales, *, scale, hb, interpret):
+                       v_scales, *, scale, hb, interpret, per_head=False):
     """The kernel call with ``hb`` kv heads a program. Jitted on its own:
     a decode program makes this call once a layer on the same shapes, and
     so traces and lowers the kernel (unrolled over the heads: ~0.2 s a
-    call on a host core) once, not once a layer."""
+    call on a host core) once, not once a layer. ``per_head``:
+    ``block_tables`` is ``[b * kv_heads / hb, m]``, a list of pages a
+    (sequence, head block), walked in the order given; ``seq_lens`` [b]
+    then counts the rows of that list's pages that are live, and the call
+    runs under the scope ``sparse_decode_attention`` (a walk of chosen
+    pages is another kernel to a roofline's reader than a walk of all)."""
     b, kv_heads, group, d = q.shape
     _, _, page_size, width = kv_pages.shape
     fp8 = k_scales is not None
@@ -1173,7 +1183,8 @@ def _paged_decode_call(q, kv_pages, block_tables, seq_lens, k_scales,
 
     kernel = functools.partial(
         _paged_decode_kernel, scale=scale, page_size=page_size,
-        group=group, fp8=fp8, pages_per_seq=block_tables.shape[1])
+        group=group, fp8=fp8, pages_per_seq=block_tables.shape[1],
+        per_head=per_head)
 
     in_specs = []
     operands = []
@@ -1201,7 +1212,8 @@ def _paged_decode_call(q, kv_pages, block_tables, seq_lens, k_scales,
                         pltpu.SMEM((1,), jnp.int32)],
     )
     from apex_tpu.monitor import profile as _prof
-    with _prof.scope("paged_decode_attention"):
+    with _prof.scope("sparse_decode_attention" if per_head
+                     else "paged_decode_attention"):
         out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,

@@ -16,6 +16,24 @@ contract in ``ops.flash_attention``, above its paged kernels):
                       layer asks for twice its layers)
     k_scale / v_scale [num_layers, kv_heads, num_pages]  f32 (fp8 mode)
 
+Two further kinds of leaf, for a model that asks for them through its
+``cache_config`` (``serve/minicpm_sala.py``; none by default, and a state
+without them is the pytree it always was):
+
+    states[i]         f32 [state_rows, *state_shape]
+                      a FIXED-SIZE state a sequence a recurrent layer,
+                      indexed by the sequence's batch row (``state_rows`` =
+                      the engine's ``max_batch``), not by pages: it lives
+                      and dies with the row, is taken as zero by the
+                      sequence's first prefill chunk, and is donated and
+                      updated in place like the pool. A preempted sequence
+                      loses it with its pages and is recomputed.
+    ckeys[layer]      [num_pages * keys_per_page, kv_heads * d]
+                      a small cache a pool leaf for what a model derives
+                      from a page's rows (compressed keys for a block
+                      selection, ``ops/sparse_attention.py``): found through
+                      the same block table, freed with the page
+
 One leaf a layer, because a program that updates and reads 2 x L slices
 of one stacked array makes XLA copy the whole stack; K beside V,
 because 2*d >= 128 in the lanes is what gives the leaf the row-major
@@ -108,6 +126,12 @@ class CacheConfig:
     fp8_margin: float = 2.0        # 2**margin headroom over the slot-0 amax
     #: lanes of one token's row in a head's page; None = K beside V
     row_width: Optional[int] = None
+    #: recurrent-state leaves, each f32 [state_rows, *state_shape]
+    state_leaves: int = 0
+    state_rows: int = 0
+    state_shape: Tuple[int, ...] = ()
+    #: derived rows a page a pool leaf ([.., kv_heads * head_dim] each)
+    keys_per_page: int = 0
 
     def __post_init__(self):
         if self.num_pages < 2:
@@ -142,10 +166,21 @@ class CacheConfig:
         per = elems * jnp.dtype(self.pool_dtype).itemsize
         if self.fp8:
             per += 2 * self.kv_heads * 4          # k_scale + v_scale rows
+        per += self.keys_per_page * self.kv_heads * self.head_dim \
+            * jnp.dtype(self.pool_dtype).itemsize
         return per * self.num_layers
 
+    def state_bytes(self) -> int:
+        """HBM bytes of the recurrent-state leaves (every row, always)."""
+        n = self.state_leaves * self.state_rows * 4
+        for s in self.state_shape:
+            n *= s
+        return n
+
     def pool_bytes(self) -> int:
-        return self.bytes_per_page() * self.num_pages
+        """Everything ``init_cache`` allocates: pages (with what is kept a
+        page) and recurrent state."""
+        return self.bytes_per_page() * self.num_pages + self.state_bytes()
 
     def pages_in_budget(self, budget_bytes: int) -> int:
         return int(budget_bytes) // self.bytes_per_page()
@@ -170,6 +205,8 @@ class CacheState(NamedTuple):
     pools: Tuple[jax.Array, ...]   # one [kv, pages, page_size, width] a layer
     k_scale: Optional[jax.Array]   # None outside fp8 mode
     v_scale: Optional[jax.Array]
+    states: Tuple[jax.Array, ...] = ()   # f32 [rows, *state_shape] each
+    ckeys: Tuple[jax.Array, ...] = ()    # one beside each pool leaf
 
 
 def init_cache(cfg: CacheConfig) -> CacheState:
@@ -178,8 +215,15 @@ def init_cache(cfg: CacheConfig) -> CacheState:
     # the donated step (donate-same-buffer-twice)
     pools = tuple(jnp.zeros(shape, cfg.pool_dtype)
                   for _ in range(cfg.num_layers))
+    states = tuple(jnp.zeros((cfg.state_rows,) + tuple(cfg.state_shape),
+                             jnp.float32) for _ in range(cfg.state_leaves))
+    ckeys = tuple(jnp.zeros((cfg.num_pages * cfg.keys_per_page,
+                             cfg.kv_heads * cfg.head_dim), cfg.pool_dtype)
+                  for _ in range(cfg.num_layers if cfg.keys_per_page else 0))
     if not cfg.fp8:
-        return CacheState(pools, None, None)
+        return CacheState(pools, None, None, states, ckeys)
+    if states or ckeys:
+        raise NotImplementedError("fp8 pages with state or derived leaves")
     # scales init to 1.0: finite and positive everywhere, so the
     # kernel's dequant divides are safe even for never-written pages
     sshape = (cfg.num_layers, cfg.kv_heads, cfg.num_pages)
@@ -206,7 +250,14 @@ def _check_impl(impl: str):
 def _with_layer(state: CacheState, layer: int, pool, k_scale,
                 v_scale) -> CacheState:
     pools = state.pools[:layer] + (pool,) + state.pools[layer + 1:]
-    return CacheState(pools, k_scale, v_scale)
+    return state._replace(pools=pools, k_scale=k_scale, v_scale=v_scale)
+
+
+def with_leaf(state: CacheState, kind: str, i: int, leaf) -> CacheState:
+    """``state`` with leaf ``i`` of ``kind`` (``"states"`` | ``"ckeys"``)
+    replaced."""
+    old = getattr(state, kind)
+    return state._replace(**{kind: old[:i] + (leaf,) + old[i + 1:]})
 
 
 @_prof.scoped("kv_write")

@@ -7,7 +7,13 @@ goes through the same decode program; that reuse IS the bit-exactness
 argument below):
 
 - the **prefill step** runs one sequence at the static padded prompt
-  length (``max_prompt_len``);
+  length (``max_prompt_len``) or, in an engine built with
+  ``prefill_chunk`` under its ``max_prompt_len``, ``prefill_chunk`` tokens
+  of one sequence's prompt from a start offset: a long prompt goes in a
+  chunk a round, at most one chunk a round, beside that round's decode
+  batch, and only its LAST chunk samples a token (the model carries what
+  a chunk leaves to the next: K|V pages, recurrent state). An engine
+  without ``prefill_chunk`` compiles and dispatches what it always has;
 - the **decode step** runs the full fixed-capacity batch
   (``max_batch`` slots, inactive slots masked to the null page). With
   ``spec_k > 0`` the SAME compiled decode program doubles as the
@@ -152,13 +158,18 @@ class ServeEngine:
                  draft_cfg: Optional[GPTConfig] = None,
                  draft_params=None,
                  fp8_weights: bool = False,
-                 fp8_weight_margin: float = 0.0):
+                 fp8_weight_margin: float = 0.0,
+                 prefill_chunk: Optional[int] = None):
         d_impl, p_impl = _default_impls()
         self.model = model = model_mod.as_served(cfg)
         self.cfg = cfg = model.cfg
         self.tp = ps.get_tensor_model_parallel_world_size()
+        # a prompt that fits one chunk is one prefill: the programs of an
+        # engine that was never asked to chunk
+        self.prefill_chunk = (int(prefill_chunk) if prefill_chunk
+                              and prefill_chunk < max_prompt_len else None)
         model.check(tp=self.tp, fp8_kv=fp8_kv, fp8_weights=fp8_weights,
-                    spec_k=spec_k)
+                    spec_k=spec_k, prefill_chunk=self.prefill_chunk or 0)
         self.fp8_weights = bool(fp8_weights)
         if fp8_weights:
             # one-time e4m3 encode of the block linear kernels: same
@@ -192,8 +203,12 @@ class ServeEngine:
         self.max_seq_len = max_seq_len
         self.max_prompt_len = max_prompt_len
         self.pages_per_seq = -(-max_seq_len // psize)
+        if self.prefill_chunk and self.prefill_chunk % psize:
+            raise ValueError(f"prefill_chunk {prefill_chunk} must be whole "
+                             f"pages of {psize}")
         self.ccfg = model.cache_config(num_pages=num_pages, page_size=psize,
-                                       fp8=fp8_kv, fp8_margin=fp8_margin)
+                                       fp8=fp8_kv, fp8_margin=fp8_margin,
+                                       max_batch=max_batch)
         self.state = cache_mod.init_cache(self.ccfg)
         self.spec_k = int(spec_k)
         if self.spec_k < 0:
@@ -237,9 +252,15 @@ class ServeEngine:
             self.draft_ccfg = self.draft_model.cache_config(
                 num_pages=num_pages, page_size=psize)
             self.draft_state = cache_mod.init_cache(self.draft_ccfg)
+        #: entries of the block table a chunk is given: the pages of the
+        #: longest prompt's chunks, which bound the context a chunk attends
+        self.prefill_pages = (
+            -(-max_prompt_len // self.prefill_chunk) * self.prefill_chunk
+            // psize if self.prefill_chunk else self.pages_per_seq)
         self.sched = Scheduler(num_pages=num_pages, page_size=psize,
                                max_batch=max_batch,
-                               lookahead=self.spec_k)
+                               lookahead=self.spec_k,
+                               prefill_chunk=self.prefill_chunk)
         self.max_batch = max_batch
         self.slots: List[Optional[Sequence]] = [None] * max_batch
         self.record_logits = record_logits
@@ -278,11 +299,16 @@ class ServeEngine:
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return logits, jnp.where(act, nxt, last_tok), state, aux
 
-        def prefill(params, state, bt, length, ids, last_tok, slot):
+        def prefill(params, state, bt, length, ids, last_tok, slot, *start):
+            # ``start``: an engine with ``prefill_chunk`` passes the chunk's
+            # offset; a chunk before the last then leaves its row's
+            # ``last_tok`` a token nobody reads (the row decodes only after
+            # the last chunk has set it)
             logits, state, aux = model.prefill(
-                ccfg, params, state, bt, length, ids,
+                ccfg, params, state, bt, length, ids, slot=slot,
                 attention_impl=self.attention_impl,
-                interpret=self.interpret, autotune=self.autotune)
+                interpret=self.interpret, autotune=self.autotune,
+                **dict(zip(("start",), start)))
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return logits, last_tok.at[slot].set(nxt), state, aux
 
@@ -325,7 +351,8 @@ class ServeEngine:
                 out_specs=(P(), P(), cspec, P()), check_vma=False)
             prefill = shard_map(
                 prefill, mesh=mesh,
-                in_specs=(pspec, cspec, P(), P(), P(), P(), P()),
+                in_specs=(pspec, cspec) + (P(),) * (
+                    6 if self.prefill_chunk else 5),
                 out_specs=(P(), P(), cspec, P()), check_vma=False)
             if draft is not None:
                 dpspec = rules_mod.match_serve_rules(
@@ -637,28 +664,52 @@ class ServeEngine:
                           (k + 1) / self.max_batch)
 
     def _do_prefill(self, seq: Sequence) -> None:
+        """The sequence's whole prompt or, chunked, its next chunk (from
+        ``seq.num_cached``). The first takes the batch row; the last (the
+        only one, unchunked) leaves the prompt's first token in flight or,
+        resumed, replays the generated tokens."""
+        chunk = self.prefill_chunk
+        start = seq.num_cached if chunk else 0
+        n = min(chunk or len(seq.prompt), len(seq.prompt) - start)
+        last = start + n == len(seq.prompt)
         resumed = seq.num_generated > 0
-        if resumed:
+        if resumed and last:
             self._drain("replay")
-        slot = self.slots.index(None)
-        self.slots[slot] = seq
-        seq.slot = slot
-        S = self.max_prompt_len
-        ids = np.zeros((S,), np.int32)
-        ids[:len(seq.prompt)] = seq.prompt
+        if start == 0:
+            seq.slot = self.slots.index(None)
+            self.slots[seq.slot] = seq
+        slot = seq.slot
+        ids = np.zeros((chunk or self.max_prompt_len,), np.int32)
+        ids[:n] = seq.prompt[start:start + n]
+        bt = self._bt_row(seq)
+        more, where = (), {}
+        if chunk:
+            # the null page past the sequence's own
+            bt = np.pad(bt, (0, max(0, self.prefill_pages - len(bt)))
+                        )[:self.prefill_pages]
+            more = (np.int32(start),)
+            where = dict(start=start, n_tokens=n, last=last)
         # the span closes at DISPATCH: the prompt's first token stays on
         # the device (row ``slot`` of ``last_tok``) and is counted when a
         # later step reads it. A child of the round by nesting;
         # ``seq_id`` links it to the request.
         with _mspans.span("serve/prefill", seq_id=seq.seq_id,
                           resumed=resumed,
-                          prompt_tokens=len(seq.prompt)):
+                          prompt_tokens=len(seq.prompt), **where):
             t0 = time.perf_counter()
             logits, self._last_tok, self.state, aux = self._prefill(
-                self.params, self.state, jnp.asarray(self._bt_row(seq)),
-                np.int32(len(seq.prompt)), jnp.asarray(ids),
-                self._last_tok, np.int32(slot))
-            seq.num_cached = len(seq.prompt)
+                self.params, self.state, jnp.asarray(bt), np.int32(n),
+                jnp.asarray(ids), self._last_tok, np.int32(slot), *more)
+            seq.num_cached = start + n
+        if chunk:
+            _mhooks.counter("serve/prefill_chunks")
+            if self.record_logits and aux and aux.get("rows"):
+                # a chunk's rows, by its start (the last chunk's are kept
+                # with its logits too)
+                self.aux_log.setdefault(seq.seq_id, {})["chunk", start] = {
+                    k: np.asarray(v) for k, v in aux["rows"].items()}
+            if not last:
+                return
         _mhooks.counter("serve/prefills")
         if not resumed:
             self._hold([(seq, slot)], logits, aux, decode=False,

@@ -80,9 +80,11 @@ class LatentServed:
         return self.cfg.max_seq_len
 
     def check(self, *, tp: int, fp8_kv: bool = False,
-              fp8_weights: bool = False, spec_k: int = 0):
+              fp8_weights: bool = False, spec_k: int = 0,
+              prefill_chunk: int = 0):
         for on, what in ((tp > 1, "tp > 1 (a latent leaf has no head dim "
                           "to shard)"),
+                         (prefill_chunk, "chunked prefill over latent pages"),
                          (fp8_kv, "an fp8 latent pool"),
                          (fp8_weights, "fp8 weights"),
                          (spec_k, "speculative decoding")):
@@ -96,7 +98,8 @@ class LatentServed:
                     group=self.cfg.num_heads, dtype=self.cfg.dtype)
 
     def cache_config(self, *, num_pages: int, page_size: int,
-                     fp8: bool = False, fp8_margin: float = 2.0):
+                     fp8: bool = False, fp8_margin: float = 2.0,
+                     max_batch: int = 0):
         cfg = self.cfg
         ccfg = cache_mod.CacheConfig(
             num_layers=cfg.num_layers * self.leaves_per_layer, kv_heads=1,
@@ -108,7 +111,8 @@ class LatentServed:
                         ccfg.bytes_per_page() // (page_size * cfg.num_layers))
         return ccfg
 
-    def prefill(self, ccfg, params, state, block_table, length, ids, **kw):
+    def prefill(self, ccfg, params, state, block_table, length, ids, *,
+                slot=None, **kw):
         return prefill_forward(self, ccfg, params, state, block_table,
                                length, ids, **kw)
 

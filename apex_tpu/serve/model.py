@@ -58,18 +58,28 @@ class GPTServed:
     model that answers. An object with
 
     - ``cfg`` (the model's static sizes), ``max_seq_len``;
-    - ``check(tp=, fp8_kv=, fp8_weights=, spec_k=)``: raises for what the
-      model cannot be served with, at engine construction;
+    - ``check(tp=, fp8_kv=, fp8_weights=, spec_k=, prefill_chunk=)``:
+      raises for what the model cannot be served with, at engine
+      construction;
     - ``page_geometry(tp)``: the per-rank kernel geometry
       ``serve.cache.resolve_page_size`` takes (``kv_heads``, ``head_dim``,
       ``dtype``);
-    - ``cache_config(num_pages=, page_size=, fp8=, fp8_margin=)``: the
-      ``CacheConfig`` of its pool: layers, the geometry of a layer's leaf
-      and of one token's row in it;
-    - ``prefill(ccfg, params, state, block_table, length, ids, **impls)``
-      and ``decode(ccfg, params, state, block_tables, positions, tokens,
-      active, **impls)``, jit-pure, returning ``(logits f32, new state,
-      aux)``. ``aux`` is ``{}`` or ``{"rows": {...}, "round": {...}}``:
+    - ``cache_config(num_pages=, page_size=, fp8=, fp8_margin=,
+      max_batch=)``: the ``CacheConfig`` of its cache: the pool's layers,
+      the geometry of a layer's leaf and of one token's row in it and,
+      where the model keeps them, the recurrent-state leaves (a row a
+      batch row, hence ``max_batch``) and what it derives a page
+      (``serve/cache.py``);
+    - ``prefill(ccfg, params, state, block_table, length, ids, slot=,
+      **impls)`` and ``decode(ccfg, params, state, block_tables,
+      positions, tokens, active, **impls)``, jit-pure, returning ``(logits
+      f32, new state, aux)``. ``slot`` is the sequence's batch row (where
+      its recurrent state lives; a model without one drops it). An engine
+      built with ``prefill_chunk`` passes ``start=`` as well: ``ids`` are
+      then the ``prefill_chunk`` tokens at positions ``start ..``, of
+      which ``length`` are live, ``block_table`` lists the prompt's pages
+      from position 0, and the logits are those of the chunk's last live
+      row. ``aux`` is ``{}`` or ``{"rows": {...}, "round": {...}}``:
       small arrays that leave the program beside the logits: ``rows``,
       kept with the logits under ``record_logits`` (a decode step's with
       one entry a batch row, a prefill's whole), and ``round`` for
@@ -96,7 +106,12 @@ class GPTServed:
     def head_dim(self) -> int:
         return self.cfg.hidden_size // self.cfg.num_heads
 
-    def check(self, *, tp: int, **_):
+    def check(self, *, tp: int, prefill_chunk: int = 0, **_):
+        if prefill_chunk:
+            raise NotImplementedError(
+                "serve/model.py: prefill takes a whole prompt (a chunk "
+                "would need its position offset and attention over the "
+                "pages before it: ROADMAP, queue R)")
         if self.cfg.num_heads % tp:
             raise ValueError(f"num_heads {self.cfg.num_heads} not "
                              f"divisible by tp {tp}")
@@ -110,14 +125,16 @@ class GPTServed:
                     head_dim=self.head_dim, dtype=self.cfg.dtype)
 
     def cache_config(self, *, num_pages: int, page_size: int,
-                     fp8: bool = False, fp8_margin: float = 2.0):
+                     fp8: bool = False, fp8_margin: float = 2.0,
+                     max_batch: int = 0):
         return cache_mod.CacheConfig(
             num_layers=self.cfg.num_layers, kv_heads=self.cfg.num_heads,
             head_dim=self.head_dim, num_pages=num_pages,
             page_size=page_size, dtype=self.cfg.dtype, fp8=fp8,
             fp8_margin=fp8_margin)
 
-    def prefill(self, ccfg, params, state, block_table, length, ids, **kw):
+    def prefill(self, ccfg, params, state, block_table, length, ids, *,
+                slot=None, **kw):
         logits, state = prefill_forward(self.cfg, ccfg, params, state,
                                         block_table, length, ids, **kw)
         return logits, state, {}

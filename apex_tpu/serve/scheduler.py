@@ -9,6 +9,14 @@ stay pure. The policy is the vLLM recompute-preemption shape:
   batch slot is free AND the allocator can cover the sequence's current
   tokens plus the next decode write. Head-of-line blocking is
   deliberate (no starvation).
+- **Chunked prefill** (``prefill_chunk``): a prompt goes in
+  ``prefill_chunk`` tokens a round, at most ONE chunk a round, beside that
+  round's decode batch. The sequence at the head of the queue is admitted
+  (slot and pages for its whole prompt) with its first chunk and stays at
+  the head, ``PREFILLING``, until the round of its last chunk, when it
+  joins the running set; the next one is admitted the round after. Without
+  ``prefill_chunk`` a prompt is one prefill and every waiting sequence that
+  fits is admitted in the same round, as ever.
 - **On-demand growth**: a running sequence takes one page exactly when
   its next decode position crosses a page boundary.
 - **Evict-on-exhaustion**: when growth cannot be served, the LATEST-
@@ -43,6 +51,7 @@ from apex_tpu.monitor import hooks as _mhooks
 from apex_tpu.monitor import spans as _mspans
 
 WAITING = "waiting"
+PREFILLING = "prefilling"      # admitted; its prompt goes in a chunk a round
 RUNNING = "running"
 FINISHED = "finished"
 
@@ -59,7 +68,8 @@ class Sequence:
     tokens: List[int] = dataclasses.field(default_factory=list)
     pages: List[int] = dataclasses.field(default_factory=list)
     slot: Optional[int] = None         # engine batch slot while RUNNING
-    num_cached: int = 0                # positions with K/V in the pool
+    num_cached: int = 0                # positions the cache holds (a
+    #                                    prompt's chunks advance it)
     draft_cached: int = 0              # positions in the DRAFT pool
     n_preemptions: int = 0
     # -- telemetry (host-only; None/0 when monitoring is detached) ----
@@ -134,8 +144,9 @@ class PageAllocator:
 @dataclasses.dataclass
 class StepPlan:
     """What the engine should run this step: prefills first (each is a
-    full-prompt pass + any decode-replay of generated tokens), then one
-    batched decode over every running sequence."""
+    full-prompt pass, or the next chunk of one from ``seq.num_cached``, +
+    any decode-replay of generated tokens), then one batched decode over
+    every running sequence."""
 
     prefill: List[Sequence] = dataclasses.field(default_factory=list)
     decode: List[Sequence] = dataclasses.field(default_factory=list)
@@ -144,10 +155,11 @@ class StepPlan:
 
 class Scheduler:
     def __init__(self, *, num_pages: int, page_size: int, max_batch: int,
-                 lookahead: int = 0):
+                 lookahead: int = 0, prefill_chunk: Optional[int] = None):
         self.allocator = PageAllocator(num_pages)
         self.page_size = page_size
         self.max_batch = max_batch
+        self.prefill_chunk = prefill_chunk
         # speculative decoding writes up to ``lookahead`` positions past
         # the next decode position in one round (the verify window), so
         # growth/admission must cover them up front — a preemption
@@ -189,10 +201,12 @@ class Scheduler:
         return -(-n_tokens // self.page_size)
 
     def _preempt(self, seq: Sequence) -> None:
+        # a sequence caught between two chunks is still in the queue
+        (self.waiting if seq.state == PREFILLING
+         else self.running).remove(seq)
         seq.state = WAITING
         seq.n_preemptions += 1
         freed = len(seq.pages)
-        self.running.remove(seq)
         self.allocator.free(seq.pages)
         seq.pages = []
         seq.slot = None
@@ -242,7 +256,8 @@ class Scheduler:
                 if got is not None:
                     seq.pages.extend(got)
                     break
-                victim = max((s for s in self.running if not s.sent),
+                victim = max((s for s in self.running + self.waiting[:1]
+                              if s.state != WAITING and not s.sent),
                              key=lambda s: s.arrival)
                 self._preempt(victim)
                 plan.preempted.append(victim)
@@ -257,29 +272,42 @@ class Scheduler:
         # recompute) plus the next write.
         while self.waiting and len(self.running) < self.max_batch:
             seq = self.waiting[0]
-            need = self._pages_needed(seq.num_dispatched + 1
-                                      + self.lookahead)
-            if need > self.allocator.num_pages - 1:
-                raise RuntimeError(
-                    f"sequence {seq.seq_id} needs {need} pages; the pool "
-                    f"has {self.allocator.num_pages - 1} usable — it can "
-                    f"never be admitted (grow num_pages or page_size)")
-            got = self.allocator.alloc(need)
-            if got is None:
+            if seq.state != PREFILLING and not self._admit(seq):
                 break                       # head-of-line: no skip-ahead
-            self.waiting.pop(0)
-            seq.pages = got
-            seq.state = RUNNING
-            # admission closes the open queue-wait span; the measured
-            # wait (wall clock, span or not) feeds the streaming
-            # histogram and the request's running total
-            wait_s = time.perf_counter() - seq.queued_t \
-                if seq.queued_t else 0.0
-            seq.queue_wait_s += wait_s
-            _mspans.end(seq.queue_span, seq_id=seq.seq_id)
-            seq.queue_span = None
-            _mhooks.observe("serve/queue_wait_ms", 1e3 * wait_s)
-            _mhooks.counter("serve/admissions")
-            self.running.append(seq)
             plan.prefill.append(seq)
+            chunk = self.prefill_chunk
+            if chunk and seq.num_cached + chunk < len(seq.prompt):
+                # not its last chunk: it keeps the head of the queue, and
+                # nobody else is prefilled this round
+                seq.state = PREFILLING
+                break
+            self.waiting.pop(0)
+            seq.state = RUNNING
+            self.running.append(seq)
+            if chunk:
+                break                       # one chunk a round
         return plan
+
+    def _admit(self, seq: Sequence) -> bool:
+        """Pages for all of a waiting sequence's tokens, or nothing."""
+        need = self._pages_needed(seq.num_dispatched + 1 + self.lookahead)
+        if need > self.allocator.num_pages - 1:
+            raise RuntimeError(
+                f"sequence {seq.seq_id} needs {need} pages; the pool "
+                f"has {self.allocator.num_pages - 1} usable — it can "
+                f"never be admitted (grow num_pages or page_size)")
+        got = self.allocator.alloc(need)
+        if got is None:
+            return False
+        seq.pages = got
+        # admission closes the open queue-wait span; the measured
+        # wait (wall clock, span or not) feeds the streaming
+        # histogram and the request's running total
+        wait_s = time.perf_counter() - seq.queued_t \
+            if seq.queued_t else 0.0
+        seq.queue_wait_s += wait_s
+        _mspans.end(seq.queue_span, seq_id=seq.seq_id)
+        seq.queue_span = None
+        _mhooks.observe("serve/queue_wait_ms", 1e3 * wait_s)
+        _mhooks.counter("serve/admissions")
+        return True

@@ -309,8 +309,11 @@ def _serve_programs(eng, sharding=None):
     decode = eng._decode.lower(
         eng.params, eng.state, i32(B, m), i32(B), i32(B),
         i32(B, dtype=jnp.bool_))
-    prefill = eng._prefill.lower(eng.params, eng.state, i32(m), i32(),
-                                 i32(S), i32(B), i32())
+    # an engine that chunks its prompts: one chunk, from a start offset
+    chunk = (i32(),) if eng.prefill_chunk else ()
+    prefill = eng._prefill.lower(
+        eng.params, eng.state, i32(eng.prefill_pages), i32(),
+        i32(eng.prefill_chunk or S), i32(B), i32(), *chunk)
     return decode, prefill
 
 

@@ -24,12 +24,22 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 FILES = ("test_rehearsal.py", "test_deepseek.py", "test_longcat.py",
          "test_reference.py", "test_manifest.py", "test_flops_bytes.py",
-         "test_span_reduce.py")
+         "test_span_reduce.py", "test_minicpm_sala.py")
+
+#: ``test_longcat.py``'s manifest test also asserts that the benchmark has
+#: SIX cells. PR 37 added the seventh and may not edit a benchmark file, so
+#: that ONE test is deselected there and lives on, every other assertion of
+#: it letter for letter, as ``test_minicpm_sala.py::
+#: test_cell_6_is_in_the_manifest_with_its_traffic_letter_for_letter``. Not a
+#: mechanism: the first thing a ``benchmark`` PR takes back (drop the count
+#: in ``test_longcat.py``, empty this table, delete the copy; ROADMAP R-B)
+DESELECT = {"test_longcat.py": (
+    "test_the_cell_is_in_the_manifest_with_its_traffic_letter_for_letter",)}
 
 
 @pytest.fixture(scope="module")
 def children():
-    """All seven start together: under ``--dist loadfile`` this file is
+    """All eight start together: under ``--dist loadfile`` this file is
     one worker's, and run one after another they are three minutes of
     it, the last of them after every other worker has finished."""
     # tier-1's XLA_FLAGS asks for eight devices; without it
@@ -38,7 +48,9 @@ def children():
     env["JAX_PLATFORMS"] = "cpu"
     procs = {name: subprocess.Popen(
         [sys.executable, "-m", "pytest", f"benchmarks/tests/{name}", "-q",
-         "-p", "no:cacheprovider"],
+         "-p", "no:cacheprovider"]
+        + [f"--deselect=benchmarks/tests/{name}::{t}"
+           for t in DESELECT.get(name, ())],
         cwd=ROOT, env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
         text=True) for name in FILES}
     yield procs

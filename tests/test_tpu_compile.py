@@ -538,6 +538,61 @@ def test_latent_serve_programs_update_the_pool_in_place(chip, chip_smoke,
         assert mem.temp_size_in_bytes < 0.5e9
 
 
+#: cell 7 of the benchmark (MiniCPM-SALA, published layers 9-20) at three of
+#: its twelve layers: one sparse, two lightning
+SALA = dict(vocab_size=73448, hidden_size=4096, intermediate_size=16384,
+            max_seq_len=24576)
+
+
+def _moved_whole(text, shape):
+    """Instructions whose result is a whole cache leaf of ``shape`` and that
+    are not the kernel updating it where it lies."""
+    dims = ",".join(map(str, shape))
+    return [name for name, d, op, _ in _INSTRUCTION.findall(text)
+            if d == dims and op not in ("parameter", "get-tuple-element",
+                                        "bitcast", "custom-call", "tuple")]
+
+
+def test_state_and_selection_leaves_are_updated_in_place(chip, chip_smoke):
+    """The decode program and the CHUNK program of an engine with
+    ``prefill_chunk`` over both kinds of cache: the K|V pool leaf (805 MB),
+    the recurrent-state leaves (67 MB each) and the compressed-key leaf are
+    arguments aliased to results; nothing copies a pool-sized or a
+    state-sized buffer, and the programs need next to nothing beside their
+    arguments. Their kernels are there by name."""
+    from apex_tpu import serve
+    from apex_tpu.models import minicpm_sala as ms
+    from apex_tpu.serve.minicpm_sala import MiniCPMSalaServed
+    cfg = ms.MiniCPMSalaConfig(
+        mixer_types=(ms.SPARSE, ms.LIGHTNING, ms.LIGHTNING), **SALA)
+    params = jax.eval_shape(
+        lambda: ms.init_params(cfg, jax.random.PRNGKey(0)))
+    eng = serve.ServeEngine(
+        MiniCPMSalaServed(cfg), _place(chip, params), num_pages=32 * 384 + 1,
+        max_seq_len=24576, max_prompt_len=16384, page_size=64, max_batch=32,
+        prefill_chunk=2048, paged_impl="kernel", attention_impl="flash",
+        interpret=False)
+    eng.state = _place(chip, eng.state)
+    assert [s.shape for s in eng.state.states] == [(32, 32, 128, 128)] * 2
+    assert [k.shape for k in eng.state.ckeys] == [(12289 * 4, 256)]
+    decode, chunk = (p.compile() for p in chip_smoke._serve_programs(
+        eng, sharding=chip))
+    for program, kernels, room in (
+            (decode, ("apx_lightning_decode", "apx_sparse_decode_attention",
+                      "apx_kv_write"), 0.1e9),
+            (chunk, ("apx_lightning_prefill", "apx_flash_attention_fwd",
+                     "apx_kv_write"), 1.0e9)):       # 2,048 rows x 16,384
+        text = program.as_text()
+        for kernel in kernels:
+            assert re.search(rf"%{kernel}[.\d]* = ", text), kernel
+        assert _pool_traffic(text, eng.state.pools[0].size) == []
+        assert _moved_whole(text, eng.state.pools[0].shape) == []
+        assert _moved_whole(text, eng.state.states[0].shape) == []
+        mem = program.memory_analysis()
+        assert mem.alias_size_in_bytes >= eng.ccfg.pool_bytes()
+        assert mem.temp_size_in_bytes < room
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("shapes,fp8_kv", [("smoke", False), ("smoke", True),
                                            ("cell3", False)])
