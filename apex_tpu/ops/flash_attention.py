@@ -978,12 +978,16 @@ _flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # The two writes (``paged_kv_write_rows`` for a decode step,
 # ``paged_kv_write_pages`` for a prompt) alias the pool to their output
 # and move only the rows they touch: a tile of the pool is copied to
-# VMEM, the new rows are merged in, and the tile is copied back, one
-# tile after another so that two rows of one tile (a speculative verify
-# window, the masked rows on the null page) never race. An XLA scatter
-# or dynamic_update_slice computes the same pool but makes XLA lay the
-# pool out for the update ([kv, 1, 1, 2d]: heads next to the lanes) and
-# copy it there and back.
+# VMEM, the new rows are merged in, and the tile is copied back. A
+# prompt's pages go one after another. A decode step's rows go a GROUP a
+# program, the group's reads in flight together and then its writes (one
+# tile's two DMAs alone are latency: ~0.9 us a row where its bytes need
+# 0.16, PERF.md PR 39); two rows of one tile (a speculative verify
+# window, the masked rows on the null page) are merged into one buffer
+# that is written once, so they never race. An XLA scatter or
+# dynamic_update_slice computes the same pool but makes XLA lay the pool
+# out for the update ([kv, 1, 1, 2d]: heads next to the lanes) and copy
+# it there and back.
 
 
 def _split_pages(kv_pages):
@@ -1287,12 +1291,51 @@ def _merge_rows(tile_ref, buf, sem, src, lo, hi):
 
 
 def _write_rows_kernel(page_ref, slot_ref, rows_ref, _, pool_ref, buf, sem,
-                       *, tile):
-    bi = pl.program_id(0)
-    slot = slot_ref[bi]
-    base = pl.multiple_of(slot // tile * tile, tile)
-    _merge_rows(pool_ref.at[:, page_ref[bi], pl.ds(base, tile), :], buf, sem,
-                rows_ref[0], slot - base, slot - base + 1)
+                       *, tile, n_rows):
+    # one program moves a GROUP of rows: every tile read in flight at
+    # once, the rows merged in, every tile write in flight at once
+    group = buf.shape[0]
+    first = pl.program_id(0) * group
+    # the last group may be short: its missing rows repeat the last real
+    # one (a tile that is read again and neither merged nor written)
+    here = [jnp.minimum(first + i, n_rows - 1) for i in range(group)]
+    page = [page_ref[r] for r in here]
+    slot = [slot_ref[r] for r in here]
+    base = [pl.multiple_of(s // tile * tile, tile) for s in slot]
+
+    def copy(i, read):
+        hbm = pool_ref.at[:, page[i], pl.ds(base[i], tile), :]
+        src, dst = (hbm, buf.at[i]) if read else (buf.at[i], hbm)
+        return pltpu.make_async_copy(src, dst, sem.at[i])
+
+    for i in range(group):
+        copy(i, True).start()
+    # rows of one tile (the masked rows on the null page; two tokens of
+    # one sequence) are merged into ONE buffer, that of the first of them,
+    # and only that one is written: G^2/2 scalar compares, under the reads
+    at = [p * pool_ref.shape[2] + b for p, b in zip(page, base)]
+    owner = []
+    for i in range(group):
+        o = jnp.int32(i)
+        for j in reversed(range(i)):
+            o = jnp.where(at[j] == at[i], j, o)
+        owner.append(o)
+    for i in range(group):
+        copy(i, True).wait()
+    for i in range(group):
+        def merge(i=i):
+            dst = buf.at[owner[i]]
+            row = jax.lax.broadcasted_iota(jnp.int32, dst.shape, 1)
+            dst[...] = jnp.where(row == slot[i] - base[i], rows_ref[i],
+                                 dst[...])
+        if n_rows % group:
+            pl.when(first + i < n_rows)(merge)
+        else:
+            merge()
+    for i in range(group):
+        pl.when(owner[i] == i)(copy(i, False).start)
+    for i in range(group):
+        pl.when(owner[i] == i)(copy(i, False).wait)
 
 
 def _write_pages_kernel(table_ref, len_ref, rows_ref, _, pool_ref, buf, sem,
@@ -1312,16 +1355,13 @@ def _write_pages_kernel(table_ref, len_ref, rows_ref, _, pool_ref, buf, sem,
 
 
 def _kv_write_call(kernel, grid, prefetch, rows, rows_spec, kv_pages,
-                   tile_rows, interpret):
-    kv_heads, _, _, width = kv_pages.shape
+                   scratch_shapes, interpret):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
         in_specs=[rows_spec, pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
-        scratch_shapes=[pltpu.VMEM((kv_heads, tile_rows, width),
-                                   kv_pages.dtype),
-                        pltpu.SemaphoreType.DMA(())],
+        scratch_shapes=scratch_shapes,
     )
     return pl.pallas_call(
         kernel,
@@ -1333,25 +1373,73 @@ def _kv_write_call(kernel, grid, prefetch, rows, rows_spec, kv_pages,
     )(*prefetch, rows, kv_pages)
 
 
+#: rows a program of the token write unrolls over at most: its scalar
+#: compares grow with the square, and so does the time a host takes to
+#: trace and lower it (32 rows: 17.0 against 18.1 us a leaf of 64 rows on
+#: the chip, and seconds more of a serve cell's set-up; PERF.md, PR 39)
+_WRITE_GROUP_ROWS = 16
+
+
+def _write_group(b, kv_heads, tile, width, itemsize):
+    """Rows a program of :func:`paged_kv_write_rows` moves together: as
+    many as ``_DECODE_BUFFER_BYTES`` of VMEM hold (a tile each, and a row
+    each in the two buffers of the rows' pipeline, padded to a tile's
+    sublanes), at most ``_WRITE_GROUP_ROWS``, spread evenly over the
+    groups ``b`` rows then need."""
+    row_bytes = 3 * kv_heads * tile * width * itemsize
+    most = max(1, min(_WRITE_GROUP_ROWS, _DECODE_BUFFER_BYTES // row_bytes))
+    return pl.cdiv(b, pl.cdiv(b, most))
+
+
 def paged_kv_write_rows(kv_pages, page_ids, slots, rows, *,
                         interpret: Optional[bool] = None):
     """One token a batch row into a layer's pool, in place:
     ``kv_pages[:, page_ids[i], slots[i]] = rows[i]`` for every ``i`` in
     order. ``rows``: [b, kv_heads, 2*d] in the pool's dtype; masked
-    rows carry page 0. Returns the pool (aliased to the operand)."""
+    rows carry page 0. Returns the pool (aliased to the operand).
+
+    A program moves a group of ``G`` rows (:func:`_write_group`: from the
+    tile's bytes, a VMEM budget and ``b``; the grid is ``ceil(b / G)``):
+    it starts the ``G`` reads of the rows' tiles (a tile: every kv head's
+    smallest row group the DMA engine addresses), waits for them, merges
+    each row into its tile, starts the writes and waits for them, so a
+    tile's two DMAs wait beside the group's and not alone. Rows of a group
+    that share a tile are found by comparing ``(page, tile)`` with the
+    earlier rows of the group and merged, in order, into the FIRST one's
+    buffer, which alone is written back: a later write never undoes an
+    earlier row. Groups run one after another, so rows of two groups that
+    share a tile do not meet."""
+    return _write_rows_call(kv_pages, page_ids, slots, rows,
+                            interpret=_resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _write_rows_call(kv_pages, page_ids, slots, rows, *, interpret):
+    """The token write's kernel call. Jitted on its own, like the decode
+    kernel's: a decode program makes this call once a layer on the same
+    shapes, and so traces and lowers the kernel (unrolled over the group:
+    ~0.3 s a call at 16 rows) once, not once a layer. A cached trace does
+    not see its caller's scope, so the write's is named here too."""
     kv_heads, _, page_size, width = kv_pages.shape
     # the smallest row group the DMA engine addresses in the pool's
     # dtype: 8 sublanes of 32-bit words, each holding 4/itemsize rows
-    tile = 32 // jnp.dtype(kv_pages.dtype).itemsize
+    itemsize = jnp.dtype(kv_pages.dtype).itemsize
+    tile = 32 // itemsize
     if page_size % tile:
         tile = page_size
     b = rows.shape[0]
-    kernel = functools.partial(_write_rows_kernel, tile=tile)
-    spec = pl.BlockSpec((1, kv_heads, 1, width),
-                        lambda bi, pg, sl: (bi, 0, 0, 0))
-    return _kv_write_call(
-        kernel, (b,), (page_ids.astype(jnp.int32), slots.astype(jnp.int32)),
-        rows[:, :, None, :], spec, kv_pages, tile, interpret)
+    group = _write_group(b, kv_heads, tile, width, itemsize)
+    kernel = functools.partial(_write_rows_kernel, tile=tile, n_rows=b)
+    spec = pl.BlockSpec((group, kv_heads, 1, width),
+                        lambda g, pg, sl: (g, 0, 0, 0))
+    scratch = [pltpu.VMEM((group, kv_heads, tile, width), kv_pages.dtype),
+               pltpu.SemaphoreType.DMA((group,))]
+    from apex_tpu.monitor import profile as _prof
+    with _prof.scope("kv_write"):
+        return _kv_write_call(
+            kernel, (pl.cdiv(b, group),),
+            (page_ids.astype(jnp.int32), slots.astype(jnp.int32)),
+            rows[:, :, None, :], spec, kv_pages, scratch, interpret)
 
 
 def paged_kv_write_pages(kv_pages, block_table, length, rows, *,
@@ -1374,7 +1462,9 @@ def paged_kv_write_pages(kv_pages, block_table, length, rows, *,
         kernel, (n_pages, 2),
         (block_table.astype(jnp.int32),
          jnp.reshape(length, (1,)).astype(jnp.int32)),
-        rows, spec, kv_pages, page_size, interpret)
+        rows, spec, kv_pages,
+        [pltpu.VMEM((kv_heads, page_size, width), kv_pages.dtype),
+         pltpu.SemaphoreType.DMA(())], interpret)
 
 
 from apex_tpu.amp.policy import half_function  # noqa: E402  (amp has no ops imports; placed here to keep kernel code import-light)

@@ -269,6 +269,14 @@ def write_token(cfg: CacheConfig, state: CacheState, layer: int,
     ``page_ids``/``slots``: int32 [b] (masked slots carry page 0);
     ``k_new``/``v_new``: [b, kv_heads, d]. Pure — runs inside the
     donated decode step.
+
+    ``impl="kernel"`` (``ops.flash_attention.paged_kv_write_rows``) moves
+    a group of ``G`` rows a program, their tiles' reads in flight together
+    and then their writes; ``G`` follows from the tile's bytes, the decode
+    kernel's VMEM budget and ``b``, and is nothing a caller sets. Rows of a
+    group that share a tile (the masked rows; two tokens of one sequence)
+    are merged in order into one buffer that is written once, so every
+    row lands, and where two rows name one slot the later one stays.
     """
     _check_impl(impl)
     # NB the scale indexing below mixes the scalar ``layer`` with index

@@ -17,6 +17,8 @@ The acceptance contracts of PR 11, each asserted mechanically:
   apex_tpu.tune.
 """
 
+import importlib
+
 import numpy as np
 import pytest
 
@@ -31,6 +33,9 @@ from apex_tpu.serve import cache as cache_mod
 from apex_tpu.serve.scheduler import (RUNNING, WAITING, PageAllocator,
                                       Scheduler, Sequence)
 from apex_tpu.transformer import parallel_state as ps
+
+# ``apex_tpu.ops.flash_attention`` the attribute is the function
+fa_mod = importlib.import_module("apex_tpu.ops.flash_attention")
 
 
 # ---------------------------------------------------------------------------
@@ -201,11 +206,9 @@ def test_paged_decode_kernel_splits_heads_that_do_not_fit(monkeypatch):
     budget, a program takes a block of the heads and the grid grows a
     second dimension; the walk, and the copy a program starts for the
     next one, go on as before."""
-    import importlib
-    fa = importlib.import_module("apex_tpu.ops.flash_attention")
     q, pool, bt, sl, _, _ = _walk_case(2, False, kv=4)
     page_bytes = pool[0, 0].size * pool.dtype.itemsize
-    monkeypatch.setattr(fa, "_DECODE_BUFFER_BYTES", 2 * 2 * page_bytes)
+    monkeypatch.setattr(fa_mod, "_DECODE_BUFFER_BYTES", 2 * 2 * page_bytes)
     assert _pallas_grids(paged_decode_attention, q, pool, bt, sl) == [
         (q.shape[0], 2)]
     np.testing.assert_allclose(
@@ -369,6 +372,84 @@ def test_pallas_write_equals_xla_scatter_bitwise(pool):
         assert a.dtype == b.dtype
         np.testing.assert_array_equal(np.asarray(a.astype(jnp.float32)),
                                       np.asarray(b.astype(jnp.float32)))
+
+
+#: the token write's cases: pool leaf [kv_heads, 6 pages, page_size, width],
+#: its dtype, the most rows a program may take and the rows it then takes,
+#: and the (page, slot) of every row. A bf16 tile is 16 rows of a page, an
+#: e4m3 tile 32.
+_TOKEN_WRITES = {
+    # 7 rows in groups of 4: the last group is one row short
+    "b_not_a_multiple_of_the_group": (
+        (2, 32, 128), jnp.bfloat16, (4, 4),
+        [(1, 3), (2, 17), (0, 0), (3, 31), (4, 0), (5, 16), (0, 0)]),
+    # 3 rows where a program could take 32: one group of 3
+    "b_under_the_group": (
+        (2, 32, 128), jnp.bfloat16, (32, 3), [(4, 30), (1, 0), (2, 15)]),
+    "every_row_masked": (
+        (2, 32, 128), jnp.bfloat16, (4, 3), [(0, 0)] * 6),
+    # the latent leaf of cells 5 and 6: one head, 640 lanes
+    "one_head_of_640_lanes": (
+        (1, 32, 640), jnp.bfloat16, (4, 3),
+        [(5, 31), (0, 0), (1, 16), (2, 2), (3, 15)]),
+    # cell 7's sparse leaf: two heads, pages of 64
+    "two_heads_pages_of_64": (
+        (2, 64, 256), jnp.bfloat16, (4, 3),
+        [(1, 63), (2, 48), (0, 0), (3, 0), (4, 17), (5, 33)]),
+    # 8-bit pages: a tile is 32 rows, two of them a page of 64
+    "e4m3_tile_of_32": (
+        (2, 64, 128), "e4m3", (4, 3),
+        [(1, 31), (2, 32), (0, 0), (3, 63), (4, 0)]),
+    # two REAL rows of one tile inside one group (slots 3 and 9 of page 2;
+    # 20 is the same page's other tile), between rows of other pages, and
+    # one slot written twice: the later row stays
+    "two_rows_of_one_tile_in_one_group": (
+        (2, 32, 128), jnp.bfloat16, (8, 7),
+        [(1, 5), (2, 3), (4, 8), (2, 9), (2, 20), (3, 1), (4, 8)]),
+    # ... and in two groups: rows 1 and 3 of the case above, 2 rows a group
+    "two_rows_of_one_tile_in_two_groups": (
+        (2, 32, 128), jnp.bfloat16, (2, 2),
+        [(1, 5), (2, 3), (4, 8), (2, 9), (2, 20), (3, 1)]),
+}
+
+
+@pytest.mark.parametrize("case", list(_TOKEN_WRITES))
+def test_grouped_token_write_equals_xla_scatter_bitwise(case, monkeypatch):
+    """``paged_kv_write_rows`` (interpret mode) moves a group of rows a
+    program; whatever the group and however its rows share tiles, every
+    page of the leaf is bit for bit what the XLA scatter leaves."""
+    from apex_tpu.amp import fp8 as f8
+    (kv, page_size, width), dtype, (most, group), where = _TOKEN_WRITES[case]
+    dtype = jnp.dtype(f8.E4M3 if dtype == "e4m3" else dtype)
+    # the group is a function of shapes and of this constant: a trace
+    # cached under another value of it must not answer for this one
+    monkeypatch.setattr(fa_mod, "_WRITE_GROUP_ROWS", most)
+    fa_mod._write_rows_call.clear_cache()
+    rng = np.random.RandomState(len(case))
+    pool = jnp.asarray(rng.randn(kv, 6, page_size, width), jnp.float32
+                       ).astype(dtype)
+    rows = jnp.asarray(rng.randn(len(where), kv, width), jnp.float32
+                       ).astype(dtype)
+    if case == "every_row_masked":     # inactive slots carry one row
+        rows = jnp.broadcast_to(rows[:1], rows.shape)
+    page_ids, slots = (jnp.asarray(x, jnp.int32) for x in zip(*where))
+    assert group == fa_mod._write_group(
+        len(where), kv, 32 // dtype.itemsize, width, dtype.itemsize)
+    ref = pool.at[:, page_ids, slots].set(rows.transpose(1, 0, 2))
+    try:
+        got = fa_mod.paged_kv_write_rows(pool, page_ids, slots, rows,
+                                         interpret=True)
+    finally:
+        fa_mod._write_rows_call.clear_cache()
+    assert got.dtype == ref.dtype
+    np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                  np.asarray(ref.astype(jnp.float32)))
+    # the scatter itself kept the later of two rows of one slot
+    for i, (page, slot) in enumerate(where):
+        if (page, slot) not in where[i + 1:]:
+            np.testing.assert_array_equal(
+                np.asarray(got[:, page, slot].astype(jnp.float32)),
+                np.asarray(rows[i].astype(jnp.float32)))
 
 
 # ---------------------------------------------------------------------------

@@ -30,8 +30,11 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from apex_tpu.amp import fp8 as fp8_mod
-from apex_tpu.ops.flash_attention import (flash_attention,
-                                          paged_decode_attention)
+from apex_tpu.lint.jaxpr_checks import iter_eqns
+from apex_tpu.ops.flash_attention import (_DECODE_BUFFER_BYTES,
+                                          flash_attention,
+                                          paged_decode_attention,
+                                          paged_kv_write_rows)
 from apex_tpu.ops.fp8_matmul import fp8_dequant_matmul
 from apex_tpu.ops.fused_ce import softmax_cross_entropy_with_smoothing
 from apex_tpu.ops.layer_norm import fused_layer_norm_affine
@@ -277,6 +280,52 @@ def test_paged_decode_reads_the_pool_where_it_lies(chip):
     assert "gather" not in text
     q_bytes = 64 * H * 2 * D * 2            # [q | 0] in bf16
     assert compiled.memory_analysis().temp_size_in_bytes <= 4 * q_bytes
+
+
+#: the token write at each serve cell's pool leaf and decode batch: cell 3
+#: (gpt2-medium), cells 5 and 6 (the latent leaves of GigaChat3 and
+#: LongCat), cell 7 (MiniCPM-SALA's sparse layers)
+TOKEN_WRITES = {
+    "cell3_bf16_16x385x128x128_b64": ((16, 385, 128, 128), 64),
+    "cell5_bf16_1x5121x128x640_b256": ((1, 5121, 128, 640), 256),
+    "cell6_bf16_1x3073x128x640_b256": ((1, 3073, 128, 640), 256),
+    "cell7_bf16_2x12289x64x256_b32": ((2, 12289, 64, 256), 32),
+}
+
+
+def _pallas_calls(fn, *args):
+    """The parameters of every ``pallas_call`` in ``fn``'s jaxpr, nested
+    jits included."""
+    return [eqn.params for eqn in iter_eqns(jax.make_jaxpr(fn)(*args),
+                                            skip_kernel_bodies=True)
+            if eqn.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("cell", list(TOKEN_WRITES))
+def test_token_write_moves_a_group_of_rows(chip, cell):
+    """A decode step's write of one token a row takes ``G`` > 1 rows a
+    program at every serve cell's shapes (the form is chosen at trace time
+    from shapes, so this is what says that it engages): the grid is
+    ``ceil(b / G)``, the program holds ``G`` tiles of 16 rows of every kv
+    head and a DMA semaphore each inside the VMEM budget, and the chip's
+    compiler takes it and updates the donated leaf where it lies."""
+    shape, b = TOKEN_WRITES[cell]
+    kv, _, _, width = shape
+    fn = functools.partial(paged_kv_write_rows, interpret=False)
+    args = (_sds(chip, shape, BF16), _sds(chip, (b,), I32),
+            _sds(chip, (b,), I32), _sds(chip, (b, kv, width), BF16))
+    call, = _pallas_calls(fn, *args)
+    tiles, sems = (v.aval for v in call["jaxpr"].invars[-2:])
+    group = tiles.shape[0]
+    assert group > 1 and tuple(call["grid_mapping"].grid) == (-(-b // group),)
+    assert tiles.shape == (group, kv, 16, width) and sems.shape == (group,)
+    assert tiles.size * 2 <= _DECODE_BUFFER_BYTES
+    compiled = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+    assert re.search(r"%apx_kv_write[.\d]* = \S+ custom-call\(",
+                     compiled.as_text())
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == args[0].size * 2
+    assert mem.temp_size_in_bytes < 2 ** 20
 
 
 #: what a device trace of the chip is joined on (benchmarks/harness/
