@@ -76,10 +76,11 @@ def gauge(name: str, value, **extra):
 
 
 def observe(name: str, value, **kw):
-    """Record one sample into the attached recorder's named log-scale
-    histogram (``Recorder.observe`` — O(1) memory streaming
-    percentiles; no per-sample event). The serve engine's token-latency
-    / TTFT / queue-wait SLO numbers flow through here."""
+    """Record one sample (``n=k``: ``k`` samples of one value) into the
+    attached recorder's named log-scale histogram (``Recorder.observe``
+    — O(1) memory streaming percentiles; no per-sample event). The
+    serve engine's token-latency / TTFT / queue-wait SLO numbers flow
+    through here."""
     rec = _state.recorder
     if rec is not None:
         rec.observe(name, value, **kw)

@@ -230,10 +230,11 @@ class Recorder:
                 step["gauges"][name] = value
         self._emit("gauge", name, value, **extra)
 
-    def observe(self, name: str, value, *, lo: float = None,
+    def observe(self, name: str, value, *, n: int = 1, lo: float = None,
                 hi: float = None, buckets_per_decade: int = None):
-        """Record one sample into the named fixed-bucket log-scale
-        histogram (:class:`~apex_tpu.monitor.spans.LogHistogram`).
+        """Record one sample (``n`` of one value: a decode round's rows)
+        into the named fixed-bucket log-scale histogram
+        (:class:`~apex_tpu.monitor.spans.LogHistogram`).
 
         Deliberately NOT one event per sample: the histogram state is
         O(1) memory and the stream stays O(1) traffic under sustained
@@ -256,7 +257,7 @@ class Recorder:
                 if buckets_per_decade is not None:
                     kw["buckets_per_decade"] = buckets_per_decade
                 h = self._histograms[name] = LogHistogram(**kw)
-            h.record(value)
+            h.record(value, n)
 
     def histograms(self) -> dict:
         """Live ``name -> LogHistogram`` map (the objects themselves;

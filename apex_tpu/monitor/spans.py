@@ -198,16 +198,18 @@ class LogHistogram:
         return (self.lo * 10.0 ** (i / self.bpd),
                 self.lo * 10.0 ** ((i + 1) / self.bpd))
 
-    def record(self, value) -> None:
+    def record(self, value, n: int = 1) -> None:
+        """``n`` samples of one value (a decode round's rows share its
+        latency): what ``n`` calls would leave, in one."""
         v = float(value)
-        self.count += 1
-        self.sum += v
+        self.count += n
+        self.sum += n * v
         self.min = v if self.min is None else min(self.min, v)
         self.max = v if self.max is None else max(self.max, v)
         if v < self.lo:                       # incl. v <= 0
-            self.underflow += 1
+            self.underflow += n
         elif v >= self.hi:
-            self.overflow += 1
+            self.overflow += n
         else:
             i = int(math.log10(v / self.lo) * self.bpd)
             # float rounding at an exact bucket edge can land one off
@@ -217,7 +219,7 @@ class LogHistogram:
                 i -= 1
             elif v >= bhi:
                 i += 1
-            self._counts[min(max(i, 0), self.n_buckets - 1)] += 1
+            self._counts[min(max(i, 0), self.n_buckets - 1)] += n
 
     def percentile(self, p: float) -> Optional[float]:
         """Nearest-rank percentile estimate (geometric bucket midpoint,

@@ -468,9 +468,10 @@ class ServeEngine:
             if _mhooks.enabled():
                 # per-TOKEN latency: each row of the round produced one
                 # token — the streaming-percentile source of the serve
-                # SLO numbers (p50/p95/p99)
-                for _ in e.rows:
-                    _mhooks.observe("serve/token_latency_ms", 1e3 * dt)
+                # SLO numbers (p50/p95/p99); one record a dispatch,
+                # weighted by its rows
+                _mhooks.observe("serve/token_latency_ms", 1e3 * dt,
+                                n=len(e.rows))
                 _mhooks.gauge("serve/batch_fill",
                               len(e.rows) / self.max_batch)
         for seq, row in e.rows:
@@ -480,12 +481,22 @@ class ServeEngine:
                              logits[row] if e.decode else logits, aux,
                              row if e.decode else None)
             self._sample(seq, toks[row])
+        # one event a dispatch, not a token
+        _mhooks.counter("serve/tokens_generated", len(e.rows))
 
     def _take(self, n: int) -> list:
-        """Fetch the ``n`` oldest dispatches in flight."""
+        """Fetch the ``n`` oldest dispatches in flight. With something
+        to read, ``serve/token_wait`` covers it: from "the host has
+        nothing else to do" to "the values are here", wherever a step
+        waits for the device (under ``serve/decode_step``, under
+        ``serve/sample`` in a step that sent out no decode, in
+        ``_drain``)."""
         batch = self._in_flight[:n]
         del self._in_flight[:n]
-        return [self._fetch(e) for e in batch]
+        if not batch:
+            return []
+        with _mspans.span("serve/token_wait", n_read=len(batch)):
+            return [self._fetch(e) for e in batch]
 
     def _drain(self, reason: str) -> None:
         """Read everything in flight NOW, because a token's value is
@@ -505,7 +516,6 @@ class ServeEngine:
     def _sample(self, seq: Sequence, token: int) -> None:
         seq.tokens.append(int(token))
         self.tokens_generated += 1
-        _mhooks.counter("serve/tokens_generated")
         if seq.num_generated == 1 and seq.ttft_ms is None \
                 and seq.arrival_t:
             # time-to-first-token, measured ONCE per request (a resumed
@@ -656,10 +666,10 @@ class ServeEngine:
             if logits_np is not None:
                 self._record(seq, n + i, logits_np[i], aux, i)
             self._sample(seq, t)
+        _mhooks.counter("serve/tokens_generated", len(committed))
         if _mhooks.enabled():
-            per_tok = 1e3 * dt / len(committed)
-            for _ in committed:
-                _mhooks.observe("serve/token_latency_ms", per_tok)
+            _mhooks.observe("serve/token_latency_ms",
+                            1e3 * dt / len(committed), n=len(committed))
             _mhooks.gauge("serve/batch_fill",
                           (k + 1) / self.max_batch)
 
@@ -792,8 +802,9 @@ class ServeEngine:
     def _decode_round(self, decodes: List[Sequence]) -> None:
         """Dispatch one batched decode for the running sequences, THEN
         read what earlier steps left in flight. ``serve/decode_step``
-        holds this round's dispatch and the wait for the previous
-        round's tokens; ``serve/sample`` counts those (and, in a step
+        holds this round's dispatch (``serve/decode_dispatch``) and the
+        wait for the previous round's tokens (``serve/token_wait``, in
+        ``_take``); ``serve/sample`` counts those (and, in a step
         without a decode round, holds the wait too)."""
         if not decodes and not self._in_flight:
             return
@@ -817,10 +828,13 @@ class ServeEngine:
             with _mspans.span("serve/decode_step", n_active=len(decodes)):
                 if any(e.decode for e in self._in_flight):
                     _mhooks.counter("serve/rounds_overlapped")
-                logits, self._last_tok, self.state, aux = self._decode(
-                    self.params, self.state, *batch)
-                self._hold([(s, s.slot) for s in decodes], logits, aux,
-                           decode=True, t_dispatch=t0)
+                # the host's cost of sending one decode round out: the
+                # call into the runtime and the start of the async copies
+                with _mspans.span("serve/decode_dispatch"):
+                    logits, self._last_tok, self.state, aux = \
+                        self._decode(self.params, self.state, *batch)
+                    self._hold([(s, s.slot) for s in decodes], logits,
+                               aux, decode=True, t_dispatch=t0)
                 fetched = self._take(self._due(True))
         with _mspans.span("serve/sample"):
             if fetched is None:

@@ -40,6 +40,17 @@ def params():
                          jnp.zeros((1, 8), jnp.int32))["params"]
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _abandoned_requests_leave_no_span_open():
+    """Tests here step an engine a few rounds and drop it with requests
+    unfinished: their ``serve/request`` spans would stay open into the
+    next file of the same worker (``test_spans.py`` counts them)."""
+    yield
+    from apex_tpu.monitor import spans
+    with spans._lock:
+        spans._open.clear()
+
+
 PROMPTS = [[5, 9, 17, 3, 40, 22, 8], [11, 2, 33, 60, 7, 7, 1]]
 N_NEW = 8
 
@@ -174,6 +185,41 @@ def _closed(rec):
             for e in rec.records("span_end")}
 
 
+def _check_dispatch_and_wait(spans_, first, second, steady):
+    """``serve/decode_step`` = ``serve/decode_dispatch`` then
+    ``serve/token_wait``, and the wait's other two places: under
+    ``serve/sample`` in a round that sent out no decode, under nothing
+    in a forced ``preempt``."""
+    eps = 1e-4
+    kids = {}
+    for s in spans_.values():
+        kids.setdefault(s[1], []).append(s)
+    steps = {i: s for i, s in spans_.items() if s[0] == "serve/decode_step"}
+    assert [s[1] for s in steps.values()] == [second] + steady
+    for i, (_, rnd, t0, t1, _) in steps.items():
+        mine = sorted(kids[i], key=lambda k: k[2])
+        # the first decode round of the run finds nothing to read: the
+        # prefill's token went out in this very step
+        want = ["serve/decode_dispatch"] + (
+            ["serve/token_wait"] if rnd != second else [])
+        assert [k[0] for k in mine] == want
+        assert t0 - eps <= mine[0][2] and mine[-1][3] <= t1 + eps
+        for a, b in zip(mine, mine[1:]):
+            assert a[3] <= b[2] + eps                 # not overlapping
+    waits = [s for s in spans_.values() if s[0] == "serve/token_wait"]
+    # dispatches read: the second round's prefill and decode, then a
+    # round's decode a step
+    assert [w[4]["n_read"] for w in waits if w[1] in steps] == \
+        [2] + [1] * (len(steady) - 1)
+    sample = next(i for i, s in spans_.items()
+                  if s[0] == "serve/sample" and s[1] == first)
+    assert [k[0] for k in kids[sample]] == ["serve/token_wait"]
+    assert kids[sample][0][4]["n_read"] == 1          # the prefill's token
+    # the preempt's read is under no span: it ran between two steps
+    assert [w[4]["n_read"] for w in waits if w[1] is None] == [1]
+    assert len(waits) == len(steady) + 2
+
+
 def test_round_span_tree_every_child_inside_its_parent(params):
     """One engine round that admits a prompt and decodes the sequence
     already running: the phase spans of docs/observability.md, each a
@@ -186,11 +232,16 @@ def test_round_span_tree_every_child_inside_its_parent(params):
         eng.step()                       # a round that only prefills
         sid = eng.add_request(PROMPTS[1], N_NEW)
         eng.step()
+        eng.step()                       # steady state: reads the last round
+        eng.step()
+        eng.preempt(sid)                 # reads what is in flight, early
     spans_ = _closed(rec)
-    first, rid = [i for i, s in spans_.items() if s[0] == "serve/round"]
+    first, rid, *steady = [i for i, s in spans_.items()
+                           if s[0] == "serve/round"]
     # no decode went out: the prefill's token is read in the round itself
     assert [s[0] for s in spans_.values() if s[1] == first] == [
         "serve/schedule", "serve/prefill", "serve/sample", "serve/gauges"]
+    _check_dispatch_and_wait(spans_, first, rid, steady)
     _, parent, r0, r1, _ = spans_[rid]
     assert parent is None
     kids = sorted((s for s in spans_.values() if s[1] == rid),
@@ -256,11 +307,54 @@ def test_prefill_span_closes_at_dispatch_and_its_token_counts_when_read(
     assert seq.ttft_ms > 0
     later = [(e["kind"], e["name"]) for e in rec.records()[n:]]
     order = [later.index(k) for k in (
-        ("span_start", "serve/decode_step"), ("span_event", "test/dispatch"),
-        ("span_event", "test/fetch"), ("span_end", "serve/decode_step"),
+        ("span_start", "serve/decode_step"),
+        ("span_start", "serve/decode_dispatch"),
+        ("span_event", "test/dispatch"),
+        ("span_end", "serve/decode_dispatch"),
+        ("span_start", "serve/token_wait"), ("span_event", "test/fetch"),
+        ("span_end", "serve/token_wait"), ("span_end", "serve/decode_step"),
         ("span_start", "serve/sample"), ("counter", "serve/tokens_generated"),
         ("span_end", "serve/sample"))]
     assert order == sorted(order), order
+
+
+def test_one_counter_event_and_one_histogram_record_a_dispatch(
+        params, monkeypatch):
+    """Four rows a round: ``serve/tokens_generated`` goes out once a
+    DISPATCH with ``inc`` = its tokens, ``serve/token_latency_ms`` is
+    recorded once a decode round with its rows as the weight. Totals
+    and counts are what one event and one record a token gave."""
+    from apex_tpu.monitor import spans
+    rec = monitor.Recorder(traced_hooks=False)
+    eng = _engine(params, max_batch=4)
+    records = []
+    record = spans.LogHistogram.record
+
+    def counted(self, value, n=1):
+        records.append(n)
+        return record(self, value, n)
+
+    monkeypatch.setattr(spans.LogHistogram, "record", counted)
+    with monitor.attached(rec):
+        for i in range(4):
+            eng.add_request(PROMPTS[i % 2][i:], N_NEW)
+        eng.run()
+    total = 4 * N_NEW
+    assert eng.tokens_generated == total
+    events = [e for e in rec.records("counter")
+              if e["name"] == "serve/tokens_generated"]
+    # four prefills of one token, then decode rounds of four rows
+    assert [e["value"] for e in events] == [1] * 4 + [4] * (N_NEW - 1)
+    assert events[-1]["total"] == total == \
+        rec.counters()["serve/tokens_generated"]
+    from_decode = total - 4
+    h = rec.histograms()["serve/token_latency_ms"]
+    assert h.count == from_decode
+    assert h.snapshot()["count"] == sum(h.snapshot()["counts"].values())
+    # one record a decode round (the other records are TTFT, queue wait)
+    assert sorted(records)[-(N_NEW - 1):] == [4] * (N_NEW - 1)
+    assert sum(n for n in records if n > 1) == from_decode
+    assert h.mean == pytest.approx(h.sum / from_decode)
 
 
 # ---------------------------------------------------------------------------

@@ -82,6 +82,26 @@ def test_histogram_snapshot_roundtrip():
     assert summ["p50"] == pytest.approx(h.percentile(50))
 
 
+@pytest.mark.parametrize("v, k", [(7.3, 256), (0.0, 3), (5e-4, 64),
+                                  (1e7, 2), (1.0, 1), (20.28, 512)])
+def test_weighted_record_equals_that_many_records(v, k):
+    """``record(v, n=k)`` leaves what ``k`` calls of ``record(v)`` leave:
+    the bucket, the underflow and overflow bins, ``count`` and ``sum``;
+    and so does the recorder's ``observe(.., n=k)`` on top of it."""
+    one, many = LogHistogram(), LogHistogram()
+    for h in (one, many):
+        h.record(3.0)
+    one.record(v, n=k)
+    for _ in range(k):
+        many.record(v)
+    assert one.snapshot() == many.snapshot()
+    assert one.percentile(99) == many.percentile(99)
+    rec = monitor.Recorder()
+    rec.observe("t/h", 3.0)
+    rec.observe("t/h", v, n=k)
+    assert rec.histograms()["t/h"].snapshot() == one.snapshot()
+
+
 def test_histogram_validation():
     with pytest.raises(ValueError):
         LogHistogram(lo=0.0, hi=1.0)
