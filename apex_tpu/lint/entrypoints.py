@@ -136,11 +136,11 @@ def _amp_train_step_monitored():
 
 
 def _tp_overlap_layers():
-    """Sequence-parallel Column→Row pair with ``overlap_comm=True``,
-    forward AND backward: the ring collective-matmul path
-    (``parallel/overlap.py``) whose ppermutes must ride the tensor
-    axis — a wrong axis here would silently exchange shards with the
-    wrong neighbours and trace clean."""
+    """Sequence-parallel Column→Row pair, forward AND backward: the
+    ring collective-matmul path (``parallel/overlap.py``, what
+    ``sequence_parallel=True`` means at tp > 1) whose ppermutes must ride
+    the tensor axis — a wrong axis here would silently exchange shards
+    with the wrong neighbours and trace clean."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -150,11 +150,9 @@ def _tp_overlap_layers():
 
     mesh, _, _ = _mesh_for(tp=2)
     col = ColumnParallelLinear(input_size=8, output_size=16,
-                               gather_output=False, sequence_parallel=True,
-                               overlap_comm=True)
+                               gather_output=False, sequence_parallel=True)
     row = RowParallelLinear(input_size=16, output_size=8,
-                            input_is_parallel=True, sequence_parallel=True,
-                            overlap_comm=True)
+                            input_is_parallel=True, sequence_parallel=True)
 
     def block(x):
         vc = col.init(jax.random.PRNGKey(0), x)

@@ -26,20 +26,25 @@ Dependent Computation via Decomposition", Wang et al.; the Megatron-LM
 sequence-parallel work — PAPERS.md) breaks the dependency by hand: ring-
 decompose the collective into ``tp`` per-shard steps so that step *k*'s
 partial matmul is data-independent of step *k+1*'s ``ppermute``, which
-the scheduler then runs concurrently. This module implements both ring
-directions plus the bucketed gradient-allreduce path that finally gives
-apex's ``message_size`` knob real TPU semantics:
+the scheduler then runs concurrently. This module implements the ring
+where the chip showed it winning (the reduce-scatter) plus the bucketed
+gradient-allreduce path that finally gives apex's ``message_size`` knob
+real TPU semantics:
 
-- :func:`all_gather_matmul`   — ``dot(all_gather(x), w)`` as a ppermute
-  ring, each hop overlapped with the previous shard's partial matmul.
-- :func:`matmul_reduce_scatter` — ``psum_scatter(dot(x, w))`` as the
-  transpose ring: per-destination-block partial matmuls overlapping the
-  travelling accumulator's hops.
-- both carry a ``custom_vjp`` whose backward **uses the conjugate
-  overlapped form** (the cotangent of an all-gather→matmul is exactly a
-  matmul→reduce-scatter, and vice versa), so fwd and bwd each hide their
-  collective. The backward re-rings the *local shard* instead of saving
-  the gathered activation — the Megatron-SP memory property.
+- :func:`matmul_reduce_scatter` — ``psum_scatter(dot(x, w))`` as a
+  ring: per-destination-block partial matmuls overlapping the travelling
+  accumulator's hops. The payload travels in two halves, opposite ways
+  round the ring (a chip's links carry both directions at once).
+- :func:`all_gather_matmul`   — ``dot(all_gather(x), w)`` with the
+  device's own all-gather (on the chip it is several times as fast as a
+  ring's hops: :func:`_gathered`), and a ``custom_vjp`` whose backward is
+  the conjugate ring: the cotangent of an all-gather→matmul is exactly a
+  matmul→reduce-scatter, and vice versa. ONE gather a collective: where a
+  backward needs the gathered array for two products both read the same
+  one, and the gather form keeps forward's gathered activation for the
+  weight gradient. These two are what a sequence-parallel
+  ``ColumnParallelLinear`` / ``RowParallelLinear`` runs at tp > 1
+  (``tensor_parallel/layers.py``).
 - :func:`bucketed_allreduce` / :func:`accumulate_gradients` — partition
   a gradient tree into ``message_size``-byte buckets, one fused ``psum``
   per bucket; in the gradient-accumulation loop each microbatch's bucket
@@ -68,7 +73,7 @@ psum/all_gather/psum_scatter).
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Sequence
+from typing import Any, Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -93,10 +98,11 @@ __all__ = [
 # ---------------------------------------------------------------------------
 
 
-def _ring_perm(tp: int):
-    """The +1 ring: rank j sends to (j+1) % tp, so after each hop rank i
-    holds what rank i-1 held."""
-    return [(j, (j + 1) % tp) for j in range(tp)]
+def _ring_perm(tp: int, step: int = 1):
+    """The ring one way: rank j sends to ``(j + step) % tp``, so after
+    each hop rank i holds what rank ``i - step`` held. ``step=-1`` is
+    the same ring the other way round."""
+    return [(j, (j + step) % tp) for j in range(tp)]
 
 
 def _dot(a, w, out_dtype):
@@ -112,109 +118,119 @@ def _account_ring(axis_name, chunk, hops: int):
                         nbytes=hops * _mon.tree_bytes(chunk), count=hops)
 
 
-def _ring_all_gather_matmul(x, w, axis_name, gather_dim: int):
-    """``dot(all_gather(x, gather_dim), w)`` as tp ring steps.
+class _Lane(NamedTuple):
+    """One travelling piece of a ring's payload: ``size`` entries from
+    ``lo`` along ``dim`` (``dim`` None: the whole payload), hopping
+    ``step`` ranks (+1 or -1) a hop."""
+    dim: int | None
+    lo: int
+    size: int
+    step: int
 
-    Step k matmuls the shard currently held (originally from rank
-    ``idx - k``) into its output row block while the next shard is in
-    flight on the ring — the two are data-independent, so XLA overlaps
-    them. Each block is a complete contraction, so the result is bitwise
-    equal to the blocking gather-then-matmul form.
+    def of(self, a):
+        if self.dim is None:
+            return a
+        return jax.lax.slice_in_dim(a, self.lo, self.lo + self.size,
+                                    axis=self.dim)
+
+
+def _lanes(shape, ring_dim: int) -> tuple:
+    """How a ring's payload of ``shape`` travels. A chip's links carry
+    both directions at once, so the payload is cut in two halves along
+    the first even dimension that is neither the ring's nor the
+    contraction's (the last), and the halves go round opposite ways:
+    each direction moves half the bytes a hop. A payload with no such
+    dimension (2-D ``[s, h]``, a batch of 1) travels whole, one way."""
+    for d, n in enumerate(shape[:-1]):
+        if d != ring_dim and n >= 2 and n % 2 == 0:
+            return (_Lane(d, 0, n // 2, 1), _Lane(d, n // 2, n // 2, -1))
+    return (_Lane(None, 0, 0, 1),)
+
+
+def _block(a, lane: _Lane, ring_dim: int, rank, s_local: int):
+    """``lane``'s entries of rank ``rank``'s row block of the
+    full-length ``a``."""
+    return jax.lax.dynamic_slice_in_dim(
+        lane.of(a), rank * s_local, s_local, axis=ring_dim)
+
+
+def _gathered(x, w, axis_name, gather_dim: int):
+    """``g = all_gather(x, gather_dim)`` and its product: ``(dot(g, w), g)``.
+
+    The gather is the device's own collective, not a ring. Measured on a
+    v5e 2x2 (PR 41, PERF.md section 6): XLA's all-gather moves a shard in
+    58 us inside cell 4's step where a ring's three ``ppermute`` hops take
+    150-210 us (a hop is 42 GB/s a direction), so the gather ring lost at
+    every width the chip was shown (960, 1,280) and went. The scatter form
+    is the other way round: the device's reduce-scatter holds the core for
+    0.22-0.26 ms, longer than the ring's whole wire.
     """
-    tp = _axis_size(axis_name)
-    if tp == 1:
-        return _dot(x, w, x.dtype)
-    gather_dim = gather_dim % x.ndim
-    idx = jax.lax.axis_index(axis_name)
-    s_local = x.shape[gather_dim]
-    out_shape = list(x.shape[:-1]) + [w.shape[-1]]
-    out_shape[gather_dim] = s_local * tp
-    y = jnp.zeros(tuple(out_shape), x.dtype)
-    perm = _ring_perm(tp)
-    _account_ring(axis_name, x, tp - 1)
-    chunk = x
-    for k in range(tp):
-        part = _dot(chunk, w, x.dtype)
-        src = (idx - k) % tp
-        y = jax.lax.dynamic_update_slice_in_dim(
-            y, part, src * s_local, axis=gather_dim)
-        if k < tp - 1:
-            chunk = jax.lax.ppermute(chunk, axis_name, perm)
-    return y
+    if _axis_size(axis_name) > 1:
+        _mon.collective("all_gather", axis_name, x)
+        x = jax.lax.all_gather(x, axis_name, axis=gather_dim, tiled=True)
+    return _dot(x, w, x.dtype), x
 
 
 def _ring_matmul_reduce_scatter(x, w, axis_name, scatter_dim: int):
     """``psum_scatter(dot(x, w), scatter_dim)`` as tp ring steps.
 
-    A partial-sum accumulator travels the ring; at step t rank i slices
-    the row block destined for rank ``i - t - 1``, matmuls it, and adds
-    it to the arriving accumulator. The slice+matmul for step t is
-    independent of step t-1's hop, so compute hides the permute. After
-    tp-1 hops each rank holds its own fully-reduced output block.
+    A partial-sum accumulator travels each way round the ring; at step t
+    rank i slices the row block destined for rank ``i - t - 1`` (``i + t
+    + 1`` the other way), matmuls it, and adds it to the arriving
+    accumulator. The slice+matmul for step t is independent of step
+    t-1's hop, so compute hides the permute. After tp-1 hops each rank
+    holds its own fully-reduced output block. The wire carries the
+    activation dtype, as the blocking form's does: every hop adds in
+    float32 and rounds once.
     """
     tp = _axis_size(axis_name)
     if tp == 1:
         return _dot(x, w, x.dtype)
     scatter_dim = scatter_dim % x.ndim
-    idx = jax.lax.axis_index(axis_name)
     s_full = x.shape[scatter_dim]
     if s_full % tp != 0:
         raise ValueError(
             f"matmul_reduce_scatter: dim {scatter_dim} of size {s_full} is "
             f"not divisible by axis '{axis_name}' size {tp}")
-    s_local = s_full // tp
-    perm = _ring_perm(tp)
-    acc = None
-    for t in range(tp):
-        b = (idx - t - 1) % tp
-        blk = jax.lax.dynamic_slice_in_dim(
-            x, b * s_local, s_local, axis=scatter_dim)
-        part = _dot(blk, w, x.dtype)
-        if acc is None:
-            acc = part
-        else:
-            acc = jax.lax.ppermute(acc, axis_name, perm) + part
-    _account_ring(axis_name, acc, tp - 1)
-    return acc
+    for lane in _lanes(x.shape, scatter_dim):
+        piece = list(lane.of(x).shape[:-1]) + [w.shape[-1]]
+        piece[scatter_dim] = s_full // tp
+        _account_ring(axis_name, jax.ShapeDtypeStruct(piece, x.dtype), tp - 1)
+    return _scatter_ring(x, w, axis_name, scatter_dim)
 
 
-def _ring_weight_grad(travelling, resident, axis_name, block_dim: int,
-                      *, resident_on_left: bool):
-    """The shared dw-accumulation ring of both backwards: ``travelling``
-    (a per-rank shard — ``x`` in the gather backward, the cotangent in
-    the scatter backward) circulates on the ring while each arriving
-    chunk is contracted over all non-feature dims with its origin rank's
-    row block of the resident full-length array. ``resident_on_left``
-    picks the contraction order (``dw = resident_blk^T @ chunk`` vs
-    ``chunk^T @ resident_blk``). Accumulates in fp32 (the MXU
-    convention) and returns fp32 — the caller casts."""
-    nd = travelling.ndim
-    axes = (tuple(range(nd - 1)),) * 2
-
-    def term(chunk, blk):
-        a, b = (blk, chunk) if resident_on_left else (chunk, blk)
-        return jnp.tensordot(a, b, axes=axes,
-                             preferred_element_type=jnp.float32)
-
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _scatter_ring(x, w, axis_name, scatter_dim: int):
+    """The ring of :func:`_ring_matmul_reduce_scatter`, jitted: a model's
+    layers of one shape (cell 4: 36 each of four) share ONE trace and ONE
+    function in the lowered module, which XLA inlines; unrolled into the
+    caller they cost the step's lowering 4-5 s of every warm start."""
     tp = _axis_size(axis_name)
-    if tp == 1:
-        return term(travelling, resident)
-    block_dim = block_dim % nd
     idx = jax.lax.axis_index(axis_name)
-    s_local = travelling.shape[block_dim]
-    perm = _ring_perm(tp)
-    _account_ring(axis_name, travelling, tp - 1)
-    chunk = travelling
-    dw = None
-    for k in range(tp):
-        src = (idx - k) % tp
-        blk = jax.lax.dynamic_slice_in_dim(
-            resident, src * s_local, s_local, axis=block_dim)
-        part = term(chunk, blk)
-        dw = part if dw is None else dw + part
-        if k < tp - 1:
-            chunk = jax.lax.ppermute(chunk, axis_name, perm)
-    return dw
+    s_local = x.shape[scatter_dim] // tp
+    lanes = _lanes(x.shape, scatter_dim)
+    accs = [None] * len(lanes)
+    for t in range(tp):
+        for n, lane in enumerate(lanes):
+            dst = (idx - lane.step * (t + 1)) % tp
+            part = jnp.dot(_block(x, lane, scatter_dim, dst, s_local), w,
+                           preferred_element_type=jnp.float32)
+            if accs[n] is not None:
+                part = part + jax.lax.ppermute(
+                    accs[n], axis_name,
+                    _ring_perm(tp, lane.step)).astype(jnp.float32)
+            accs[n] = part.astype(x.dtype)
+    if lanes[0].dim is None:
+        return accs[0]
+    return jnp.concatenate(accs, axis=lanes[0].dim)
+
+
+def _weight_grad(a, b):
+    """``a^T @ b`` over every dimension but the last, in fp32 (the MXU
+    convention): a weight gradient as ONE matmul over the whole sequence
+    (a product a ring piece leaves the MXU waiting on the fp32 sums)."""
+    axes = (tuple(range(a.ndim - 1)),) * 2
+    return jnp.tensordot(a, b, axes=axes, preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -309,35 +325,38 @@ def _check_operands(x, w, dim: int, what: str):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def all_gather_matmul(x, w, axis_name, gather_dim: int = 0):
-    """``dot(all_gather(x, axis=gather_dim, tiled=True), w)`` with the
-    gather ring-decomposed so each hop overlaps a per-shard matmul.
+    """``dot(all_gather(x, axis=gather_dim, tiled=True), w)``: the
+    device's all-gather, then one matmul (:func:`_gathered`).
 
     ``x``: the local sequence shard ``[..., s/tp at gather_dim, ..., h]``;
     ``w``: the local weight shard ``[h, n_local]``. Returns
     ``[..., s, ..., n_local]``. Bitwise-equal to the blocking form.
 
     Backward: ``dx`` is the conjugate :func:`matmul_reduce_scatter` of
-    ``dy @ w^T`` (overlapped), ``dw`` re-rings the saved *local* shard
-    (no gathered activation is stored — the Megatron-SP memory property).
+    ``dy @ w^T`` (the ring), ``dw`` one matmul over forward's gathered
+    ``x``, which is kept for it (as the blocking layer kept it).
     """
-    _check_operands(x, w, gather_dim, "all_gather_matmul")
-    return _ring_all_gather_matmul(x, w, axis_name, gather_dim)
+    return _agm_fwd(x, w, axis_name, gather_dim)[0]
 
 
 def _agm_fwd(x, w, axis_name, gather_dim):
     _check_operands(x, w, gather_dim, "all_gather_matmul")
-    return _ring_all_gather_matmul(x, w, axis_name, gather_dim), (x, w)
+    y, g = _gathered(x, w, axis_name, gather_dim % x.ndim)
+    return y, (g, w)
 
 
 def _agm_bwd(axis_name, gather_dim, res, dy):
-    x, w = res
+    g, w = res
     # d(gathered x) = dy @ w^T, and the gather's transpose re-shards while
     # summing cross-rank partials: exactly matmul→reduce-scatter.
     dx = _ring_matmul_reduce_scatter(
-        dy, jnp.swapaxes(w, 0, 1).astype(dy.dtype), axis_name, gather_dim)
-    dw = _ring_weight_grad(x, dy, axis_name, gather_dim,
-                           resident_on_left=False).astype(w.dtype)
-    return dx.astype(x.dtype), dw
+        dy, jnp.swapaxes(w, 0, 1).astype(dy.dtype), axis_name,
+        gather_dim % g.ndim)
+    # dw = gathered(x)^T @ dy in ONE matmul over forward's gathered x,
+    # kept: gathering the local shard again costs cell 4 a second
+    # all-gather a column layer on the links the scatter ring is using,
+    # and the memory it saves (1.1 GB of 8.2) the step does not need
+    return dx, _weight_grad(g, dy).astype(w.dtype)
 
 
 all_gather_matmul.defvjp(_agm_fwd, _agm_bwd)
@@ -355,12 +374,11 @@ def matmul_reduce_scatter(x, w, axis_name, scatter_dim: int = 0):
     Matches the fused form to dtype tolerance (the cross-rank additions
     are reassociated).
 
-    Backward: ``dx`` is the conjugate :func:`all_gather_matmul` of the
-    scattered cotangent (overlapped); ``dw`` rings the cotangent shard
-    against the saved local activation.
+    Backward: ONE gather of the scattered cotangent (the device's
+    all-gather) feeds both ``dx = g @ w^T`` and
+    ``dw = x^T @ g``.
     """
-    _check_operands(x, w, scatter_dim, "matmul_reduce_scatter")
-    return _ring_matmul_reduce_scatter(x, w, axis_name, scatter_dim)
+    return _mrs_fwd(x, w, axis_name, scatter_dim)[0]
 
 
 def _mrs_fwd(x, w, axis_name, scatter_dim):
@@ -370,13 +388,11 @@ def _mrs_fwd(x, w, axis_name, scatter_dim):
 
 def _mrs_bwd(axis_name, scatter_dim, res, dy):
     x, w = res
-    # d(x @ w) = all_gather(dy) — and folding the following @ w^T into the
-    # gather ring is exactly the conjugate collective matmul.
-    dx = _ring_all_gather_matmul(
-        dy, jnp.swapaxes(w, 0, 1).astype(dy.dtype), axis_name, scatter_dim)
-    dw = _ring_weight_grad(dy, x, axis_name, scatter_dim,
-                           resident_on_left=True).astype(w.dtype)
-    return dx.astype(x.dtype), dw
+    # d(x @ w) = all_gather(dy) @ w^T and dw = x^T @ all_gather(dy): ONE
+    # gather of the cotangent shard feeds both products
+    dx, g = _gathered(dy, jnp.swapaxes(w, 0, 1).astype(dy.dtype), axis_name,
+                      scatter_dim % x.ndim)
+    return dx.astype(x.dtype), _weight_grad(x, g).astype(w.dtype)
 
 
 matmul_reduce_scatter.defvjp(_mrs_fwd, _mrs_bwd)

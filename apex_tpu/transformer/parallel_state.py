@@ -94,9 +94,55 @@ def initialize_model_parallel(
     if ep > 1:
         shape.insert(1, ep)
         names.insert(1, EXPERT_AXIS)
-    arr = np.array(devs).reshape(shape)
+    arr = np.array(_ring_ordered(devs, tp)).reshape(shape)
     _MESH = Mesh(arr, tuple(names))
     return _MESH
+
+
+def _ici_neighbours(a, b) -> bool:
+    """Two chips one ICI hop apart: their ``coords`` differ by one in
+    exactly one place."""
+    diff = [abs(p - q) for p, q in zip(a.coords, b.coords)]
+    return sum(diff) == 1
+
+
+def _ring_ordered(devs: list, tp: int) -> list:
+    """``devs`` with each tensor group — ``tp`` consecutive devices, as
+    the reshape above cuts them — reordered so that consecutive tensor
+    ranks, and the last with the first, are ICI neighbours: the
+    sequence-parallel rings (``parallel/overlap.py``) hop ``j -> j +- 1``,
+    and a hop between chips that are not neighbours crosses two links
+    that another pair is using. A v5e 2x2 enumerates row-major over
+    ``coords``, (0,0) (1,0) (0,1) (1,1), so ranks 1 -> 2 and 3 -> 0 of
+    the enumeration order are diagonal; the cycle is 0, 1, 3, 2.
+
+    The order follows from the devices: a group keeps its members and its
+    first device, and takes the first cycle (else the first path) through
+    its members' neighbour graph in enumeration order; devices without
+    ``coords`` (CPU), a group of one or two, a group with no such path and
+    a group too large to search (the walk below is exhaustive) keep the
+    order they came in."""
+    if not 3 <= tp <= 16 or not all(hasattr(d, "coords") for d in devs):
+        return devs
+
+    def walk(path, rest, closed):
+        if not rest:
+            return path if not closed or _ici_neighbours(
+                path[-1], path[0]) else None
+        for d in rest:
+            if _ici_neighbours(path[-1], d):
+                found = walk(path + [d], [r for r in rest if r is not d],
+                             closed)
+                if found:
+                    return found
+        return None
+
+    out = []
+    for g in range(0, len(devs), tp):
+        group = devs[g:g + tp]
+        out += (walk(group[:1], group[1:], True)
+                or walk(group[:1], group[1:], False) or group)
+    return out
 
 
 def model_parallel_is_initialized() -> bool:

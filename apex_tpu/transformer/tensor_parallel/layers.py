@@ -14,11 +14,13 @@ under ``shard_map`` over the ``tensor`` mesh axis — and degrade to plain
 dense/embedding at tp=1. The reference's async-allreduce-overlapped-
 with-weight-grad trick (:221-234) needs no code here: XLA's latency-hiding
 scheduler overlaps the backward ``psum`` with the weight-gradient matmul
-automatically. The *blocking* sequence-parallel collectives, though —
+automatically. The sequence-parallel collectives, though —
 all-gather→matmul and matmul→reduce-scatter, where the dependency chain
-defeats any scheduler — get explicit overlap via ``overlap_comm=True``:
-the ring collective-matmul forms from ``apex_tpu/parallel/overlap.py``
-(off by default; the default jaxpr is byte-identical to the fused form).
+defeats any scheduler — ARE the collective-matmul forms of
+``apex_tpu/parallel/overlap.py``: ``sequence_parallel=True`` at tp > 1
+means the reduce-scatter rides the ring beside its own matmul, forward
+(row layer) and backward (column layer), from what the call sees (tp,
+the shapes); there is no switch.
 
 Per-partition init matches the reference's ``_initialize_affine_weight``
 strategy (:59-124): the full weight is materialized deterministically from
@@ -33,26 +35,26 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from apex_tpu.monitor import hooks as _mon
 from apex_tpu.monitor import profile as _prof
 from apex_tpu.parallel import overlap
 from apex_tpu.transformer import parallel_state as ps
 from apex_tpu.transformer.tensor_parallel import mappings
 from apex_tpu.transformer.tensor_parallel.utils import divide, VocabUtility
-from apex_tpu.utils.parity import warn_inert_once as _warn_inert_once
 
-# One-time notice (the inert-knob convention, ``utils/parity``):
-# ``overlap_comm=True`` only has an overlapped form on the
-# sequence-parallel paths — the non-SP copy/psum mappings are already
-# overlapped by XLA's scheduler (no blocking collective→matmul chain to
-# decompose), so the flag would be silently a no-op there without this.
-# Warned inline from ``__call__`` (no helper frame) so the stacklevel
-# points as close to the caller as flax's apply machinery allows.
-_OVERLAP_WITHOUT_SP_MSG = (
-    "{cls}: overlap_comm=True has no effect without "
-    "sequence_parallel=True — only the blocking sequence-parallel "
-    "all-gather→matmul / matmul→reduce-scatter patterns have ring-"
-    "overlapped forms (parallel/overlap.py); the non-SP mappings "
-    "already overlap under XLA's scheduler")
+
+def _count_sp_linear():
+    """Each sequence-parallel linear call at ``world > 1`` counts its two
+    collectives at trace time, forward's and its backward's conjugate, by
+    the form they take: ``tp/sp_linear_ring`` for the reduce-scatter, which
+    travels the ring beside the pieces of its own matmul
+    (``overlap.matmul_reduce_scatter``), ``tp/sp_linear_blocking`` for the
+    all-gather, which the device runs whole (``overlap._gathered``: a gather
+    ring lost on the chip). ``benchmarks/layer_metrics/sp_ring_share.py``
+    reads the two: 50 today; a tree that sends a collective the other way
+    counts it there, and the share moves."""
+    _mon.counter("tp/sp_linear_ring")
+    _mon.counter("tp/sp_linear_blocking")
 
 
 def set_tensor_model_parallel_attributes(param, is_parallel: bool, dim: int, stride: int = 1):
@@ -142,17 +144,12 @@ class ColumnParallelLinear(nn.Module):
     skip_bias_add: bool = False
     sequence_parallel: bool = False
     sequence_dim: int = 0          # 0 = [s, b, h] (Megatron), 1 = [b, s, h]
-    overlap_comm: bool = False     # SP only: ring collective-matmul fwd+bwd
     axis_name: str = ps.TENSOR_AXIS
     init_method: Callable = nn.initializers.lecun_normal()
     param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x):
-        if self.overlap_comm and not self.sequence_parallel:
-            _warn_inert_once(
-                _OVERLAP_WITHOUT_SP_MSG.format(cls="ColumnParallelLinear"),
-                key="ColumnParallelLinear.overlap_comm_without_sp")
         world = ps._axis_size(self.axis_name)
         out_per = divide(self.output_size, world)
         kernel = self.param(
@@ -162,24 +159,16 @@ class ColumnParallelLinear(nn.Module):
         # profile scope (monitor.profile): the per-module attribution
         # tag — metadata only, the jaxpr is byte-identical without it
         with _prof.scope(self.name or "column_linear"):
-            y = None
             if self.sequence_parallel and world > 1:
-                if self.overlap_comm:
-                    # explicit comms/compute overlap (parallel/overlap.py):
-                    # the sequence all-gather is ring-decomposed so each
-                    # ppermute hop hides behind the previous shard's partial
-                    # matmul; the custom_vjp backward uses the conjugate
-                    # matmul→reduce-scatter ring. Off (default) this layer's
-                    # jaxpr is byte-identical to the blocking form.
-                    y = overlap.all_gather_matmul(
-                        x, kernel.astype(x.dtype), self.axis_name,
-                        self.sequence_dim)
-                else:
-                    x = mappings.gather_from_sequence_parallel_region(
-                        x, self.axis_name, self.sequence_dim)
-            elif world > 1:
-                x = mappings.copy_to_tensor_model_parallel_region(x, self.axis_name)
-            if y is None:
+                # the device's all-gather -> matmul, with the conjugate
+                # matmul -> reduce-scatter ring in its custom_vjp backward
+                _count_sp_linear()
+                y = overlap.all_gather_matmul(
+                    x, kernel.astype(x.dtype), self.axis_name,
+                    self.sequence_dim)
+            else:
+                if world > 1:
+                    x = mappings.copy_to_tensor_model_parallel_region(x, self.axis_name)
                 y = jnp.dot(x, kernel.astype(x.dtype),
                             preferred_element_type=jnp.float32).astype(x.dtype)
             bias = None
@@ -213,17 +202,12 @@ class RowParallelLinear(nn.Module):
     skip_bias_add: bool = False
     sequence_parallel: bool = False
     sequence_dim: int = 0          # 0 = [s, b, h] (Megatron), 1 = [b, s, h]
-    overlap_comm: bool = False     # SP only: ring collective-matmul fwd+bwd
     axis_name: str = ps.TENSOR_AXIS
     init_method: Callable = nn.initializers.lecun_normal()
     param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x):
-        if self.overlap_comm and not self.sequence_parallel:
-            _warn_inert_once(
-                _OVERLAP_WITHOUT_SP_MSG.format(cls="RowParallelLinear"),
-                key="RowParallelLinear.overlap_comm_without_sp")
         world = ps._axis_size(self.axis_name)
         in_per = divide(self.input_size, world)
         kernel = self.param(
@@ -234,12 +218,15 @@ class RowParallelLinear(nn.Module):
         with _prof.scope(self.name or "row_linear"):
             if not self.input_is_parallel and world > 1:
                 x = mappings.scatter_to_tensor_model_parallel_region(x, self.axis_name)
-            if self.sequence_parallel and world > 1 and self.overlap_comm:
-                # transpose pattern of the column layer's overlap: the
-                # sequence reduce-scatter is ring-decomposed, each partial
-                # matmul hiding the travelling accumulator's ppermute hop.
-                # Reassociates the cross-rank sum — dtype-tolerance parity
-                # with the fused psum_scatter, not bitwise.
+            if self.sequence_parallel and world > 1:
+                # transpose pattern of the column layer: the sequence
+                # reduce-scatter is ring-decomposed, each partial matmul
+                # hiding the travelling accumulator's ppermute hop (the
+                # sequence must split evenly over the axis, as for the
+                # blocking psum_scatter). Reassociates the cross-rank sum —
+                # dtype-tolerance parity with the fused psum_scatter, not
+                # bitwise. Backward gathers the cotangent once.
+                _count_sp_linear()
                 y = overlap.matmul_reduce_scatter(
                     x, kernel.astype(x.dtype), self.axis_name,
                     self.sequence_dim)
@@ -247,11 +234,7 @@ class RowParallelLinear(nn.Module):
                 y = jnp.dot(x, kernel.astype(x.dtype),
                             preferred_element_type=jnp.float32).astype(x.dtype)
                 if world > 1:
-                    if self.sequence_parallel:
-                        y = mappings.reduce_scatter_to_sequence_parallel_region(
-                            y, self.axis_name, self.sequence_dim)
-                    else:
-                        y = mappings.reduce_from_tensor_model_parallel_region(y, self.axis_name)
+                    y = mappings.reduce_from_tensor_model_parallel_region(y, self.axis_name)
             bias = None
             if self.use_bias:
                 bias = self.param("bias", nn.initializers.zeros,

@@ -20,12 +20,12 @@ The sequence-parallel pairs here are *blocking*: the consumer matmul
 cannot start until ``gather_from_sequence_parallel_region`` lands, and
 ``reduce_scatter_to_sequence_parallel_region`` cannot start until the
 producer matmul finishes. When the collective is immediately adjacent to
-a matmul, prefer the fused ring forms —
+a matmul, prefer the collective-matmul forms —
 :func:`apex_tpu.parallel.overlap.all_gather_matmul` /
 :func:`apex_tpu.parallel.overlap.matmul_reduce_scatter` (re-exported
 below) — which decompose the collective into ppermute hops overlapped
-with per-shard partial matmuls; ``ColumnParallelLinear`` /
-``RowParallelLinear`` select them via ``overlap_comm=True``.
+with per-shard partial matmuls wherever that wins; a sequence-parallel
+``ColumnParallelLinear`` / ``RowParallelLinear`` at tp > 1 runs them.
 """
 
 from __future__ import annotations

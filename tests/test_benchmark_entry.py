@@ -65,3 +65,36 @@ def children():
 def test_benchmark_tests_pass(children, name):
     out, _ = children[name].communicate(timeout=900)
     assert children[name].returncode == 0, out[-4000:]
+
+
+# -- the per-layer metric PR 41 added: a reader of the program's counters -----
+
+@pytest.mark.parametrize("counters,trace,want", [
+    # cell 4: 3 x 144 calls, each a scatter that rings and a blocking gather
+    ({"tp/sp_linear_ring": 432, "tp/sp_linear_blocking": 432}, {}, 50.0),
+    ({"tp/sp_linear_ring": 108, "tp/sp_linear_blocking": 36}, {}, 75.0),
+    ({"tp/sp_linear_blocking": 288}, {}, 0.0),  # a tree that fell back
+    ({"tune/cache_miss": 3}, {}, None),       # world == 1, or the parent
+    ({"tp/sp_linear_ring": 144}, None, None),  # no device trace: no share
+])
+def test_sp_ring_share_reads_the_layers_counters(counters, trace, want):
+    """``sp_ring_share`` = ring / (ring + blocking) of the collectives of
+    the SP linear calls the process traced, in cell 4 only, and nothing
+    where the program has no such counter (the driver lays this PR's
+    benchmark files over the parent's checkout too)."""
+    import json
+    sys.path.insert(0, ROOT)
+    try:
+        from benchmarks.layer_metrics import sp_ring_share
+    finally:
+        sys.path.remove(ROOT)
+    assert sp_ring_share.compute({"counters": counters,
+                                  "trace": trace}) == want
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = [m for m in json.load(f)["per_layer"]
+                 if m["name"] == "sp_ring_share"]
+    assert entry == [{
+        "name": "sp_ring_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "multi-chip",
+        "moves": "train4_tokens_per_s",
+        "workloads": ["gpt2l-train-4chip"]}]

@@ -399,20 +399,14 @@ CELL4 = dict(vocab=50304, hidden=1280, heads=20, seq=1024, batch=8,
              mc_layers=2)
 
 
-def test_example_train_step_updates_its_state_in_place(topo, no_interpret,
-                                                       chip_smoke):
+def _compile_example_step(topo, smoke):
     """``examples/gpt/main_gpt.py:make_step_fns`` on the four described
-    chips, as cell 4 and ``chip_smoke.py --chips 4`` run it: the step
-    donates its variables, optimizer state and scaler state, and the
-    chip's compiler aliases every leaf of them onto the output. Before
-    PR 33 nothing was donated: the state was resident twice and each step
-    allocated an output buffer for every leaf on every chip while the
-    chips waited (~1,750 leaves a chip, ~170 ms a step, at full depth)."""
+    chips at ``CELL4``: the mesh, the state's shapes and the compiled
+    step."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from apex_tpu.models import GPT
     from apex_tpu.optimizers import FusedAdam
     from apex_tpu.transformer import parallel_state as ps
-    smoke = chip_smoke
     sz = dataclasses.replace(smoke.FULL, **CELL4)
     ps.destroy_model_parallel()
     try:
@@ -433,6 +427,20 @@ def test_example_train_step_updates_its_state_in_place(topo, no_interpret,
         compiled = step_f.lower(*state, ids, ids).compile()
     finally:
         ps.destroy_model_parallel()
+    return mesh, state, compiled
+
+
+def test_example_train_step_updates_its_state_in_place(topo, no_interpret,
+                                                       chip_smoke):
+    """``examples/gpt/main_gpt.py:make_step_fns`` on the four described
+    chips, as cell 4 and ``chip_smoke.py --chips 4`` run it: the step
+    donates its variables, optimizer state and scaler state, and the
+    chip's compiler aliases every leaf of them onto the output. Before
+    PR 33 nothing was donated: the state was resident twice and each step
+    allocated an output buffer for every leaf on every chip while the
+    chips waited (~1,750 leaves a chip, ~170 ms a step, at full depth)."""
+    smoke = chip_smoke
+    _, state, compiled = _compile_example_step(topo, smoke)
     text = compiled.as_text()
     smoke._require_kernels(smoke._kernel_calls(text), flash_attention=2)
     leaves = jax.tree.leaves(state)
@@ -443,6 +451,41 @@ def test_example_train_step_updates_its_state_in_place(topo, no_interpret,
     assert sorted(map(int, aliased)) == list(range(len(leaves)))
     assert compiled.memory_analysis().alias_size_in_bytes >= sum(
         x.size * x.dtype.itemsize for x in leaves)
+
+
+#: what the step of the commit before the layers took the ring (2d6d991)
+#: compiles to at ``CELL4`` on the described v5e:2x2, arguments + outputs +
+#: temporaries - aliased (deviceless compile, PR 41)
+CELL4_PARENT_BYTES = 986_164_736
+
+
+def test_example_train_step_rings_its_reduce_scatters(topo, no_interpret,
+                                                      chip_smoke):
+    """The same step: tensor ranks follow the 2x2's links (0, 1, 3, 2:
+    the enumeration order's 1 -> 2 and 3 -> 0 are diagonal), every
+    reduce-scatter of a block's activation travels as asynchronous
+    ``collective-permute-start/done`` pairs beside the pieces of its own
+    matmul (row forward and column backward of two layers x two linears,
+    3 hops x 2 directions each) and none is left blocking; the gathers
+    are the device's (a gather ring lost on the chip, ``overlap._gathered``).
+    The step holds no more memory than the parent's."""
+    mesh, _, compiled = _compile_example_step(topo, chip_smoke)
+    assert [d.id for d in mesh.devices.flat] == [0, 1, 3, 2]
+    text = compiled.as_text()
+    layers, linears, hops, ways = CELL4["mc_layers"], 2, 3, 2
+    assert text.count(" collective-permute-start(") \
+        == 2 * layers * linears * hops * ways
+    assert text.count(" collective-permute-done(") \
+        == text.count(" collective-permute-start(")
+    blocking = [line for line in text.splitlines()
+                if re.search(r" reduce-scatter\(|%reduce_scatter[\w.]* = ",
+                             line) and "/block_" in line]
+    assert blocking == []
+    assert re.search(r" all-gather\(.*/block_\d+/", text)
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total <= 1.05 * CELL4_PARENT_BYTES
 
 
 def _compile_serve(chip, smoke, sz, fp8_kv=False):
