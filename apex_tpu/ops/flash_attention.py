@@ -33,12 +33,16 @@ and backward get INDEPENDENT defaults (r5 retune — the r3 single
 default conflated the two phases). Forward: 1024 everywhere (256-blocks
 are ~1.9x slower — per-program overhead; 2048-blocks exceed VMEM) —
 even causal, where one [1024, 1024] block per s=1024 sequence beats two
-512-blocks (1.33 vs 1.72 ms fwd-only) despite computing the fully-masked
-half: per-program overhead outweighs the live-block skip. Backward:
-causal s=1024 keeps two 512-aligned k blocks — measured 1.17 ms vs
-1.29 ms fused-at-1024 and 1.66 ms two-kernel (the fused single-pass
-kernel runs at any n_kb since r5; the 512 choice is purely the faster
-measurement); s >= 2048 uses 1024-blocks. When bias AND
+512-blocks (1.33 vs 1.72 ms fwd-only): a grid step costs more than the
+live-block skip saves. A PLAIN causal call (no segments, bias, dropout
+or padding) skips the fully-masked half inside the program instead, in
+strips of query rows that each stop at their own diagonal (``Strips``
+below), forward and fused backward, and its backward then takes
+1024-blocks too. Backward otherwise: causal s=1024 keeps two
+512-aligned k blocks — measured 1.17 ms vs 1.29 ms fused-at-1024 and
+1.66 ms two-kernel (the fused single-pass kernel runs at any n_kb since
+r5; the 512 choice is purely the faster measurement); s >= 2048 uses
+1024-blocks. When bias AND
 dropout are both active both defaults drop to (512, 512): the extra
 [block_q, block_k] fp32 bias block plus the keep mask push the 1024
 config over VMEM on hardware (verified at d=128 s=2048: bias-only ok,
@@ -52,6 +56,7 @@ not exp-bound (exp costs the same as mul on the v5e VPU).
 
 from __future__ import annotations
 
+import collections
 import functools
 from typing import Optional
 
@@ -211,7 +216,7 @@ def _causal_block_full(qi, kb, block_q, block_k, causal_offset):
 
 
 def _dispatch_causal(compute, causal, use_segments, qi, kb, block_q,
-                     block_k, causal_offset, skip_dead=True):
+                     block_k, causal_offset, skip_dead=True, strips=None):
     """Run ``compute(masked: bool)`` under the right predication — shared
     by all four kernels. Causal without segments splits live blocks into
     fully-live (mask-free, see ``_causal_block_full``; bit-identical
@@ -224,8 +229,22 @@ def _dispatch_causal(compute, causal, use_segments, qi, kb, block_q,
     writes o/lse inside ``compute``, so a skipped block would leave its
     output block uninitialized (VMEM garbage on hardware). The mask +
     dead-row guard turn those rows into zeros/-1e30 lse, matching the
-    carry path's initialized-scratch behavior."""
-    if causal and not use_segments:
+    carry path's initialized-scratch behavior.
+
+    ``strips`` (a :class:`_StripPlan`; plain causal only): the block the
+    diagonal crosses runs ``compute(masked, strip)`` once a strip of
+    query rows, each against the keys up to its own diagonal."""
+    if strips is not None:
+        rel = qi * block_q + causal_offset - kb * block_k
+        if strips.any_full:
+            pl.when(rel >= block_k - 1)(lambda: compute(False))
+
+        @pl.when(rel == strips.rel)
+        def _diagonal():
+            for strip in _strips_of(strips, block_q, block_k,
+                                    keep_dead=not skip_dead):
+                compute(strip.masked, strip)
+    elif causal and not use_segments:
         full = _causal_block_full(qi, kb, block_q, block_k, causal_offset)
         pl.when(full)(lambda: compute(False))
         rest = jnp.logical_not(full)
@@ -245,11 +264,146 @@ def _dispatch_causal(compute, causal, use_segments, qi, kb, block_q,
 
 
 # ---------------------------------------------------------------------------
+# Strips: the causal triangle inside a program
+# ---------------------------------------------------------------------------
+#
+# A program that holds the block the diagonal crosses (at s = 1,024 THE
+# block: one [1024, 1024] program a (batch, head)) used to multiply the
+# whole square and mask half of it away. It now walks strips of ``r``
+# query rows; strip ``i`` multiplies against the keys up to its own
+# diagonal only (static slices of the refs), so the dead rectangle above
+# it is never touched: no more VMEM, no more grid steps. Measured on a v5e
+# (b8 h16 s1024 d64 bf16, ms a call; PERF.md, PR 44 and PR 45): forward
+# 0.533 -> 0.444 at r = 512 (0.469 at 256); fused backward 1.219 (two
+# 512-blocks) -> 0.915 at block 1,024, r = 256 (1.040 at 512). Tiling
+# the KEYS as well inside a program lost at every tile shape, and
+# 512-blocks with strips gain nothing (the grid step is what costs). The
+# two matmuls are 0.39 of the 0.53 ms and removing the exp changes
+# nothing: the MXU, half filled at d = 64, binds, so products not
+# computed are the lever.
+#
+# ``r`` follows from the direction, the block and ``d``; no argument sets
+# it. A call that is not causal, or carries segments (padding installs
+# them), a bias or dropout, or whose diagonal crosses its blocks at more
+# than one place, has no plan and runs the whole-block program.
+
+_Strip = collections.namedtuple("_Strip", "rows cols shift masked")
+_StripPlan = collections.namedtuple("_StripPlan", "rows rel any_full")
+
+
+def _strip_rows(direction, block_q, d):
+    """Query rows a strip: 512 forward and 256 backward (half that past
+    d = 128, where a strip's products are as long again), halved until
+    it divides the block into two strips or more; ``block_q`` = none."""
+    r = (512 if d <= 128 else 256) // (1 if direction == "fwd" else 2)
+    while r >= 128 and (block_q % r or r >= block_q):
+        r //= 2
+    return r if r >= 128 else block_q
+
+
+def _block_rels(sq, sk, block_q, block_k):
+    """``rel`` of every block of the grid: row ``j`` of block (qi, kb)
+    sees the block's columns ``<= j + rel`` under causal."""
+    return [qi * block_q + (sk - sq) - kb * block_k
+            for qi in range(sq // block_q) for kb in range(sk // block_k)]
+
+
+def _strip_plan(direction, causal, plain, sq, sk, block_q, block_k, d):
+    """The :class:`_StripPlan` of a call, or None where it runs whole
+    blocks. ``plain``: no segments, no bias, no dropout. ``sq``, ``sk``
+    unpadded: a length its block does not divide is padded, and padding
+    installs segments."""
+    block_q, block_k = min(block_q, sq), min(block_k, sk)
+    if not (causal and plain) or sq % block_q or sk % block_k \
+            or block_k % 128:
+        return None
+    r = _strip_rows(direction, block_q, d)
+    if r >= block_q:
+        return None
+    rels = _block_rels(sq, sk, block_q, block_k)
+    # the forward's single k block writes its outputs inside the compute,
+    # dead rows too, so its dead blocks are the diagonal's business
+    keep_dead = direction == "fwd" and sk == block_k
+    diagonal = {rel for rel in rels if rel < block_k - 1
+                and (keep_dead or rel > -block_q)}
+    if len(diagonal) != 1:
+        return None
+    return _StripPlan(r, diagonal.pop(),
+                      any(rel >= block_k - 1 for rel in rels))
+
+
+def _strips_of(plan, block_q, block_k, keep_dead=False):
+    """The strips of the diagonal's block, top to bottom. Row ``j`` of a
+    strip sees columns ``<= j + shift``; ``cols`` ends at the strip's
+    last row's diagonal. A strip that sees nothing is left out, or kept
+    on one lane tile of masked columns (``keep_dead``: the caller has to
+    write its rows)."""
+    r = plan.rows
+    out = []
+    for i in range(block_q // r):
+        shift = plan.rel + i * r
+        n = min(max(shift + r, 0), block_k)
+        if n == 0:
+            if not keep_dead:
+                continue
+            n = 128
+        out.append(_Strip(pl.ds(i * r, r), pl.ds(0, n), shift,
+                          masked=shift < n - 1))
+    return out
+
+
+def _tile_mask(masked, strip, *block_mask_args):
+    """The validity mask of what a ``compute(masked, strip)`` multiplies:
+    None, the strip's own diagonal, or ``_block_mask`` of the whole
+    block."""
+    if not masked:
+        return None
+    if strip is None:
+        return _block_mask(*block_mask_args)
+    r, n = strip.rows.size, strip.cols.size
+    row = jax.lax.broadcasted_iota(jnp.int32, (r, n), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (r, n), 1)
+    return col <= row + strip.shift
+
+
+def _tiles(plan, causal, sq, sk, block_q, block_k, skip_dead=True):
+    """(computed, square): the 128 x 128 tiles of scores a (batch, head)
+    of the call multiplies, and those of its whole ``sq`` x ``sk`` sheet.
+    Whole blocks but for the dead ones a causal grid skips and, under a
+    plan, the diagonal's strips."""
+    unit = 128.0 * 128.0
+    computed = 0
+    for rel in _block_rels(sq, sk, block_q, block_k):
+        if plan is not None and rel == plan.rel:
+            computed += sum(s.rows.size * s.cols.size for s in _strips_of(
+                plan, block_q, block_k, keep_dead=not skip_dead))
+        elif not (causal and skip_dead and rel <= -block_q):
+            computed += block_q * block_k
+    return computed / unit, sq * sk / unit
+
+
+def _count_tiles(direction, batch_heads, plan, causal, sq, sk, block_q,
+                 block_k, skip_dead=True):
+    """``flash/tiles_computed`` and ``flash/tiles_square`` by direction,
+    once a trace of the kernel's call: their ratio says how much of the
+    square a call multiplies (0.75 forward and 0.625 backward at s =
+    1,024; 1.0 where nothing can be skipped)."""
+    from apex_tpu.monitor import hooks as _mon
+    computed, square = _tiles(plan, causal, sq, sk, block_q, block_k,
+                              skip_dead)
+    _mon.counter("flash/tiles_computed", batch_heads * computed,
+                 direction=direction)
+    _mon.counter("flash/tiles_square", batch_heads * square,
+                 direction=direction)
+
+
+# ---------------------------------------------------------------------------
 # Pallas forward kernel
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(*refs, scale, causal, block_q, block_k, use_segments,
-                use_bias, dropout_rate, causal_offset, single_kb=False):
+                use_bias, dropout_rate, causal_offset, single_kb=False,
+                strips=None):
     it = iter(refs)
     sq_ref = next(it) if use_segments else None
     skv_ref = next(it) if use_segments else None
@@ -268,13 +422,18 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, use_segments,
             l_scr[:] = jnp.zeros_like(l_scr)
             acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _compute(masked):
+    def _compute(masked, strip=None):
+        # the whole block, or one strip of its query rows against the keys
+        # up to that strip's diagonal (``_dispatch_causal``)
+        rows = slice(None) if strip is None else strip.rows
+        cols = slice(None) if strip is None else strip.cols
         # operands stay in their native dtype: the MXU multiplies bf16
         # pairs exactly and accumulates fp32 (preferred_element_type), so
         # upcasting first changes nothing numerically but forces Mosaic's
         # multi-pass fp32 matmul (~3x slower)
-        q = q_ref[0, 0]                                  # [block_q, d]
-        k = k_ref[0, 0]                                  # [block_k, d]
+        q = q_ref[0, 0, rows]                            # [block_q, d]
+        k = k_ref[0, 0, cols]                            # [block_k, d]
+        v = v_ref[0, 0, cols]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if scale != 1.0:
@@ -282,8 +441,8 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, use_segments,
         if use_bias:
             s = s + bias_ref[0, 0].astype(jnp.float32)
 
-        mask = (_block_mask(qi, kb, block_q, block_k, causal, causal_offset,
-                            sq_ref, skv_ref) if masked else None)
+        mask = _tile_mask(masked, strip, qi, kb, block_q, block_k, causal,
+                          causal_offset, sq_ref, skv_ref)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
 
@@ -307,14 +466,15 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, use_segments,
                                      block_k, dropout_rate)
                 p = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - dropout_rate))
             acc = jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             safe_l = jnp.where(l > 0, l, 1.0)
-            o_ref[0, 0] = (acc / safe_l).astype(o_ref.dtype)
-            lse_ref[0, 0, 0] = jnp.reshape(m + jnp.log(safe_l), (block_q,))
+            o_ref[0, 0, rows] = (acc / safe_l).astype(o_ref.dtype)
+            lse_ref[0, 0, 0, rows] = jnp.reshape(m + jnp.log(safe_l),
+                                                 (q.shape[0],))
             return
 
-        m_prev = m_scr[:]                                 # [block_q, 1]
+        m_prev = m_scr[rows]                              # [block_q, 1]
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)
@@ -330,7 +490,7 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, use_segments,
             # underflow to an exact 0 without the where() pass
             p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
+        l_new = alpha * l_scr[rows] + jnp.sum(p, axis=1, keepdims=True)
         if dropout_rate > 0.0:
             keep = _dropout_keep(seed_ref, bi, hi, qi, kb, block_q, block_k,
                                  dropout_rate)
@@ -339,14 +499,15 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, use_segments,
             p = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - dropout_rate))
         # p rounds to the v dtype for the MXU (flash-attention-2 practice;
         # fp32 v inputs keep an exact fp32 product)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, 0], (((1,), (0,)), ((), ())),
+        acc_scr[rows] = acc_scr[rows] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
-        l_scr[:] = l_new
+        m_scr[rows] = m_new
+        l_scr[rows] = l_new
 
     _dispatch_causal(_compute, causal, use_segments, qi, kb, block_q,
-                     block_k, causal_offset, skip_dead=not single_kb)
+                     block_k, causal_offset, skip_dead=not single_kb,
+                     strips=strips)
 
     if not single_kb:
         @pl.when(kb == n_kb - 1)
@@ -423,11 +584,36 @@ def _bias_spec(bias, block_q, block_k, qdim, kdim):
 # keep the straightforward `s * scale` (guarded for callers passing 1.0).
 
 
+# The forward's and the backward's kernel calls are jitted on their own,
+# like ``_paged_decode_call`` and ``_write_rows_call``: a model makes them
+# once a LAYER on the same shapes, and a Pallas call is traced (the kernel
+# body, unrolled over its strips) and lowered (its Mosaic module) once a
+# call site at every lowering of the program around it, compile cache hit
+# or not. Behind ``jax.jit`` the N layers of a program share one trace and
+# one lowered function (PERF.md, PR 44: 36 layers x three programs cost
+# cell 4 twenty seconds of set-up where the compiler's time had not moved).
+# A cached trace does not see its caller's scope, so the scopes that name
+# the compiled instructions (``apx_flash_attention_fwd`` / ``_bwd``: a
+# device trace tells the directions apart by them) are inside.
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12))
 def _flash_fwd_impl(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
                     scale, causal, dropout_rate, block_q, block_k, interpret):
+    from apex_tpu.monitor import profile as _prof
+    with _prof.scope("flash_attention_fwd"):
+        return _flash_fwd(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
+                          scale, causal, dropout_rate, block_q, block_k,
+                          interpret)
+
+
+def _flash_fwd(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
+               scale, causal, dropout_rate, block_q, block_k, interpret):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     causal_offset = sk - sq   # aligns the original sequence ends
+    strips = _strip_plan(
+        "fwd", causal, segment_ids_q is None and bias is None
+        and dropout_rate == 0.0, sq, sk, block_q, block_k, d)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     (q, k, v, segment_ids_q, segment_ids_kv, bias, _, pad_q, pad_k
@@ -438,11 +624,14 @@ def _flash_fwd_impl(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
     use_bias = bias is not None
 
     grid = (b, h, sq_p // block_q, sk_p // block_k)
+    single_kb = sk_p // block_k == 1
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, use_segments=use_segments, use_bias=use_bias,
         dropout_rate=dropout_rate, causal_offset=causal_offset,
-        single_kb=(sk_p // block_k == 1))
+        single_kb=single_kb, strips=strips)
+    _count_tiles("fwd", b * h, strips, causal, sq_p, sk_p, block_q, block_k,
+                 skip_dead=not single_kb)
 
     # Mosaic requires the last two block dims to be (8k, 128k) or equal to
     # the array dims — trailing-singleton layouts (b, sq, 1) / (b, 1, sk)
@@ -470,8 +659,10 @@ def _flash_fwd_impl(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec((1, 1, 1, block_q), lambda b_, h_, qi, ki: (b_, h_, 0, qi)),
+            pl.BlockSpec((1, 1, block_q, d),
+                         lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
+            pl.BlockSpec((1, 1, 1, block_q),
+                         lambda b_, h_, qi, ki: (b_, h_, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
@@ -483,7 +674,7 @@ def _flash_fwd_impl(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
             # would waste ~256 KB of the VMEM the block defaults are
             # budgeted against (measured perf-neutral)
             [pltpu.VMEM((8, 128), jnp.float32)] * 3
-            if sk_p // block_k == 1 else [
+            if single_kb else [
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, d), jnp.float32),
@@ -497,23 +688,25 @@ def _flash_fwd_impl(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
 # Pallas backward kernels (flash-attention-2 decomposition)
 # ---------------------------------------------------------------------------
 
-def _recompute_p(q_ref, k_ref, lse_ref, bias_ref, mask, scale, guard):
-    """p = exp(s - lse), zeroed where masked. [block_q, block_k].
+def _recompute_p(q_ref, k_ref, lse_ref, bias_ref, mask, scale, guard,
+                 rows=slice(None), cols=slice(None)):
+    """p = exp(s - lse), zeroed where masked. [block_q, block_k], or the
+    strip ``rows`` x ``cols`` of it.
     ``mask=None`` = fully live (a non-masking shape, or a fully-live
     causal block — see ``_causal_block_full``), so the where() passes are
     skipped. ``guard``: whether rows with lse == -1e30 (segment padding)
     or +inf blowups (sq > sk fully-masked rows) can exist — when False
     (plain causal, sq <= sk) the post-exp where() is skipped too: masked
     entries have s = -1e30 and finite lse, so exp underflows to exact 0."""
-    q = q_ref[0, 0]                # native dtype: bf16 MXU path (see fwd)
-    k = k_ref[0, 0]
+    q = q_ref[0, 0, rows]          # native dtype: bf16 MXU path (see fwd)
+    k = k_ref[0, 0, cols]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if scale != 1.0:
         s = s * scale
     if bias_ref is not None:
         s = s + bias_ref[0, 0].astype(jnp.float32)
-    lse_col = lse_ref[0, 0, 0][:, None]          # [block_q, 1] (relayout)
+    lse_col = lse_ref[0, 0, 0, rows][:, None]    # [block_q, 1] (relayout)
     if mask is None:
         return jnp.exp(s - lse_col)
     s = jnp.where(mask, s, _NEG_INF)
@@ -525,8 +718,10 @@ def _recompute_p(q_ref, k_ref, lse_ref, bias_ref, mask, scale, guard):
 
 def _p_dp_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
              seed_ref, mask, scale, dropout_rate,
-             bi, hi, qi, kb, block_q, block_k, guard):
-    """Shared backward-block math: recompute p, form dp and ds.
+             bi, hi, qi, kb, block_q, block_k, guard,
+             rows=slice(None), cols=slice(None)):
+    """Shared backward-block math: recompute p, form dp and ds, of the
+    block or of its strip ``rows`` x ``cols``.
 
     Returns ``(p_drop, do, ds)``. The dropout-backward rule lives ONLY
     here: ``ds`` multiplies the UNdropped ``p`` while ``dp`` is
@@ -538,10 +733,11 @@ def _p_dp_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
     (block_k/d = 8x fewer elements, and the fp32 post-dot multiply is
     numerically at least as good as scaling ds before its bf16 cast).
     """
-    p = _recompute_p(q_ref, k_ref, lse_ref, bias_ref, mask, scale, guard)
-    do = do_ref[0, 0]                                     # [block_q, d]
+    p = _recompute_p(q_ref, k_ref, lse_ref, bias_ref, mask, scale, guard,
+                     rows, cols)
+    do = do_ref[0, 0, rows]                               # [block_q, d]
     dp = jax.lax.dot_general(
-        do, v_ref[0, 0], (((1,), (1,)), ((), ())),
+        do, v_ref[0, 0, cols], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     if dropout_rate > 0.0:
         keep = _dropout_keep(seed_ref, bi, hi, qi, kb, block_q, block_k,
@@ -551,7 +747,7 @@ def _p_dp_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
         dp = jnp.where(keep, dp, 0.0) * inv
     else:
         p_drop = p
-    ds = p * (dp - delta_ref[0, 0, 0][:, None])
+    ds = p * (dp - delta_ref[0, 0, 0, rows][:, None])
     return p_drop, do, ds
 
 
@@ -602,7 +798,7 @@ def _dkdv_kernel(*refs, scale, causal, block_q, block_k, use_segments,
 
 
 def _bwd_fused_kernel(*refs, scale, causal, block_q, block_k, use_segments,
-                      use_bias, dropout_rate, causal_offset):
+                      use_bias, dropout_rate, causal_offset, strips=None):
     """Single-pass backward: dq accumulated per q-block (resident across
     the inner k loop) while dk/dv accumulate into full-[sk, d] fp32 VMEM
     scratch for the whole (b, h) cell. Recomputes p = exp(s - lse) ONCE
@@ -631,14 +827,19 @@ def _bwd_fused_kernel(*refs, scale, causal, block_q, block_k, use_segments,
     def _init_q():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _compute(masked):
-        mask = (_block_mask(qi, kb, block_q, block_k, causal, causal_offset,
-                            sq_ref, skv_ref) if masked else None)
+    def _compute(masked, strip=None):
+        # the whole block, or one strip of its query rows against the keys
+        # up to that strip's diagonal (``_dispatch_causal``)
+        rows = slice(None) if strip is None else strip.rows
+        cols = slice(None) if strip is None else strip.cols
+        mask = _tile_mask(masked, strip, qi, kb, block_q, block_k, causal,
+                          causal_offset, sq_ref, skv_ref)
         p_drop, do, ds = _p_dp_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
             seed_ref, mask, scale, dropout_rate, bi, hi, qi, kb,
-            block_q, block_k, guard)
-        kv = pl.ds(kb * block_k, block_k)
+            block_q, block_k, guard, rows, cols)
+        kv = pl.ds(kb * block_k,
+                   block_k if strip is None else strip.cols.size)
         dv_scr[kv, :] += jax.lax.dot_general(
             p_drop.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -647,14 +848,14 @@ def _bwd_fused_kernel(*refs, scale, causal, block_q, block_k, use_segments,
         # applies at the [*, d] finish, not per [block_q, block_k] block
         dsc = ds.astype(q_ref.dtype)
         dk_scr[kv, :] += jax.lax.dot_general(
-            dsc, q_ref[0, 0], (((0,), (0,)), ((), ())),
+            dsc, q_ref[0, 0, rows], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dq_scr[...] += jax.lax.dot_general(
-            dsc.astype(k_ref.dtype), k_ref[0, 0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dq_scr[rows] += jax.lax.dot_general(
+            dsc.astype(k_ref.dtype), k_ref[0, 0, cols],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     _dispatch_causal(_compute, causal, use_segments, qi, kb, block_q,
-                     block_k, causal_offset)
+                     block_k, causal_offset, strips=strips)
 
     @pl.when(kb == n_kb - 1)
     def _finish_q():
@@ -707,12 +908,61 @@ def _dq_kernel(*refs, scale, causal, block_q, block_k, use_segments,
         dq_ref[0, 0] = dq.astype(dq_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "causal", "dropout_rate", "block_q", "block_k", "interpret"))
 def _flash_bwd_impl(res, do, *, scale, causal, dropout_rate, block_q,
                     block_k, interpret):
+    from apex_tpu.monitor import profile as _prof
+    with _prof.scope("flash_attention_bwd"):
+        return _flash_bwd(res, do, scale=scale, causal=causal,
+                          dropout_rate=dropout_rate, block_q=block_q,
+                          block_k=block_k, interpret=interpret)
+
+
+def _bwd_fused(sk_p, d, k_dtype, v_dtype, use_bias, dropout_rate, block_q,
+               block_k):
+    """Whether the backward runs as the fused single-pass kernel: its
+    [sk, d] dk/dv accumulators (fp32 scratch pair + the output blocks in
+    their own dtype) fit the scoped-VMEM budget. r5 re-measure: the old
+    n_kb >= 2 gate (single-block fused had measured slightly slower in
+    r3) no longer holds with the deferred-scale/ds-reuse kernel — fused
+    wins at every single-k-block shape tried (b32 h12 s512 d64: 3.43 ->
+    3.16 ms; b8 h16 s512 d64: 1.61 -> 1.25; b4 h16 s512 d128: 0.93 ->
+    0.91)."""
+    kv_bytes = sk_p * d * (8 + jnp.dtype(k_dtype).itemsize
+                           + jnp.dtype(v_dtype).itemsize)
+    # bias rides as an extra [block_q, block_k] fp32 operand block and
+    # dropout regenerates a same-shape keep mask in VMEM; the 2 MB cap
+    # was measured without either, so count them against the same gate
+    # (at the default 1024 blocks this routes bias/dropout shapes to the
+    # two-kernel path, which keeps O(block) VMEM)
+    if use_bias:
+        kv_bytes += 4 * block_q * block_k
+    if dropout_rate > 0.0:
+        kv_bytes += 4 * block_q * block_k
+    return kv_bytes <= _FUSED_BWD_MAX_KV_BYTES
+
+
+def _bwd_strip_plan(causal, plain, sq, sk, block_q, block_k, d, k_dtype,
+                    v_dtype):
+    """The backward's :class:`_StripPlan`: the fused kernel's alone (the
+    two kernels of a longer sequence run whole blocks)."""
+    plan = _strip_plan("bwd", causal, plain, sq, sk, block_q, block_k, d)
+    if plan is not None and not _bwd_fused(sk, d, k_dtype, v_dtype, False,
+                                           0.0, block_q, block_k):
+        return None
+    return plan
+
+
+def _flash_bwd(res, do, *, scale, causal, dropout_rate, block_q, block_k,
+               interpret):
     q, k, v, out, lse, sid_q, sid_kv, bias, seed = res
     b, h, sq, d = q.shape
     sk = k.shape[2]
     causal_offset = sk - sq
+    strips = _bwd_strip_plan(
+        causal, sid_q is None and bias is None and dropout_rate == 0.0,
+        sq, sk, block_q, block_k, d, k.dtype, v.dtype)
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
 
@@ -764,28 +1014,15 @@ def _flash_bwd_impl(res, do, *, scale, causal, dropout_rate, block_q,
         return pl.BlockSpec((1, 1, 1, block_q),
                             lambda *g, _q=qdim: (g[0], g[1], 0, g[_q]))
 
+    _count_tiles("bwd", b * h, strips, causal, sq_p, sk_p, block_q, block_k)
     # --- fused single-pass backward when the [sk, d] dk/dv accumulators
-    # fit the scoped-VMEM budget (fp32 scratch pair + the dk/dv output
-    # blocks in their own dtype). r5 re-measure: the old n_kb >= 2 gate
-    # (single-block fused had measured slightly slower in r3) no longer
-    # holds with the deferred-scale/ds-reuse kernel — fused wins at every
-    # single-k-block shape tried (b32 h12 s512 d64: 3.43 -> 3.16 ms;
-    # b8 h16 s512 d64: 1.61 -> 1.25; b4 h16 s512 d128: 0.93 -> 0.91)
-    kv_bytes = sk_p * d * (8 + k.dtype.itemsize + v.dtype.itemsize)
-    # bias rides as an extra [block_q, block_k] fp32 operand block and
-    # dropout regenerates a same-shape keep mask in VMEM; the 2 MB cap
-    # was measured without either, so count them against the same gate
-    # (at the default 1024 blocks this routes bias/dropout shapes to the
-    # two-kernel path, which keeps O(block) VMEM)
-    if use_bias:
-        kv_bytes += 4 * block_q * block_k
-    if dropout_rate > 0.0:
-        kv_bytes += 4 * block_q * block_k
-    if kv_bytes <= _FUSED_BWD_MAX_KV_BYTES:
+    # fit the scoped-VMEM budget
+    if _bwd_fused(sk_p, d, k.dtype, v.dtype, use_bias, dropout_rate,
+                  block_q, block_k):
         especs, eops = extra(qdim=2, kdim=3)
         kvspec = pl.BlockSpec((1, 1, sk_p, d), lambda *g: (g[0], g[1], 0, 0))
         dq, dk, dv = pl.pallas_call(
-            functools.partial(_bwd_fused_kernel, **common),
+            functools.partial(_bwd_fused_kernel, strips=strips, **common),
             grid=(b, h, n_qb, n_kb),
             in_specs=especs + [qspec(2), kspec(3), kspec(3), qspec(2),
                                rowspec(2), rowspec(2)],
@@ -894,14 +1131,9 @@ def _resolve_interpret(interpret):
 def _fa_fwd(q, k, v, sid_q, sid_kv, bias, seed, causal, scale, dropout_rate,
             block_q, block_k, block_q_bwd, block_k_bwd, interpret):
     scale_v = q.shape[-1] ** -0.5 if scale is None else scale
-    # a Pallas custom call is named by the innermost scope around it:
-    # the compiled instruction is ``apx_flash_attention_fwd`` (``_bwd``
-    # below), so a device trace tells the two directions apart
-    from apex_tpu.monitor import profile as _prof
-    with _prof.scope("flash_attention_fwd"):
-        out, lse = _flash_fwd_impl(q, k, v, sid_q, sid_kv, bias, seed,
-                                   scale_v, causal, dropout_rate, block_q,
-                                   block_k, _resolve_interpret(interpret))
+    out, lse = _flash_fwd_impl(q, k, v, sid_q, sid_kv, bias, seed,
+                               float(scale_v), causal, dropout_rate, block_q,
+                               block_k, _resolve_interpret(interpret))
     return out, (q, k, v, out, lse, sid_q, sid_kv, bias, seed)
 
 
@@ -910,12 +1142,10 @@ def _fa_bwd(causal, scale, dropout_rate, block_q, block_k,
     q = res[0]
     bias = res[7]
     scale_v = q.shape[-1] ** -0.5 if scale is None else scale
-    from apex_tpu.monitor import profile as _prof
-    with _prof.scope("flash_attention_bwd"):
-        dq, dk, dv = _flash_bwd_impl(
-            res, do, scale=scale_v, causal=causal,
-            dropout_rate=dropout_rate, block_q=block_q_bwd,
-            block_k=block_k_bwd, interpret=_resolve_interpret(interpret))
+    dq, dk, dv = _flash_bwd_impl(
+        res, do, scale=float(scale_v), causal=causal,
+        dropout_rate=dropout_rate, block_q=block_q_bwd,
+        block_k=block_k_bwd, interpret=_resolve_interpret(interpret))
     # bias is an additive attention mask — non-differentiable by contract
     # (matches apex, where masks are inputs, never parameters); a real dbias
     # would require materializing [sq, sk] and is deliberately not offered.
@@ -1517,8 +1747,9 @@ def flash_attention(q, k, v, segment_ids_q=None, segment_ids_kv=None,
        ``block_q_bwd``/``block_k_bwd``, the backward inherits your
        forward tiling verbatim (back-compat: callers tuned before the
        phases split expect one consistent tiling) and the phase-tuned
-       backward defaults — measurably faster on causal shapes, e.g.
-       1.17 ms vs 1.29 ms at b8 h16 s1024 d64 — are NOT applied. To get
+       backward defaults — measurably faster on causal shapes that
+       carry segments or dropout, e.g. 1.17 ms vs 1.29 ms at b8 h16
+       s1024 d64 — are NOT applied. To get
        the tuned backward while pinning the forward, pass
        ``block_q_bwd=None``-equivalent explicitly:
        ``flash_attention(..., block_q=1024, block_k=1024,
@@ -1568,11 +1799,12 @@ def flash_attention(q, k, v, segment_ids_q=None, segment_ids_kv=None,
     if block_q is None or block_k is None:
         # bias + dropout together exceed VMEM at 1024 blocks (see module
         # docstring); everything else is fastest at 1024 in the FORWARD,
-        # including causal shapes: per-program overhead dominates the
-        # wasted fully-masked half of a [1024, 1024] diagonal block
+        # including causal shapes: a grid step costs more than it skips
         # (measured b8 h16 s1024 d64 fwd-only: 1.33 ms @ (1024,1024) vs
-        # 1.72 ms @ (512,512) — the r3 two-block tuning conflated the
-        # forward with the backward, which has its own default below)
+        # 1.72 ms @ (512,512)), and the fully-masked half of a
+        # [1024, 1024] diagonal block is skipped INSIDE the program, a
+        # strip of query rows at a time, where the call is plain causal
+        # (``_strip_plan``: 0.533 -> 0.444 ms a call)
         default = 512 if (bias is not None and dropout_rate > 0.0) else 1024
         block_q = block_q or default
         block_k = block_k or default
@@ -1601,12 +1833,19 @@ def flash_attention(q, k, v, segment_ids_q=None, segment_ids_kv=None,
         else:
             bq_d = bk_d = 512 if (bias is not None and dropout_rate > 0.0) \
                 else 1024
-            if causal:
-                # the BACKWARD wants two 512-aligned k blocks per
-                # sequence at s=1024: measured 1.17 ms vs 1.29 ms fused
-                # @ (1024,1024) and 1.66 ms two-kernel (b8 h16 d64) —
-                # the fused kernel runs at any n_kb (r5), this is purely
-                # the faster tiling; s >= 2048 keeps 1024 blocks
+            if causal and _bwd_strip_plan(
+                    True, segment_ids_q is None and bias is None
+                    and dropout_rate == 0.0, q.shape[2], k.shape[2], bq_d,
+                    bk_d, q.shape[3], k.dtype, v.dtype) is None:
+                # a causal BACKWARD that cannot walk strips inside a
+                # 1024-block (segments, dropout, a padded length, the
+                # two-kernel form) wants two 512-aligned k blocks per
+                # sequence at s=1024, one dead block of four skipped:
+                # measured 1.17 ms vs 1.29 ms fused @ (1024,1024) whole
+                # and 1.66 ms two-kernel (b8 h16 d64) — the fused kernel
+                # runs at any n_kb (r5), this is purely the faster tiling;
+                # s >= 2048 keeps 1024 blocks. With strips the 1024-block
+                # wins: 0.915 ms (PERF.md, PR 44)
                 bq_d = bk_d = min(bq_d, max(512, (q.shape[2] // 2)
                                             // 512 * 512))
         block_q_bwd = block_q_bwd or bq_d

@@ -357,10 +357,6 @@ def test_compiled_text_names_direction_and_scope(chip, case):
         assert re.search(pattern, text), pattern
 
 
-# ---------------------------------------------------------------------------
-# whole programs (slow): the train step and the serve programs of chip_smoke
-# ---------------------------------------------------------------------------
-
 @pytest.fixture
 def no_interpret(monkeypatch):
     """The kernels' backend rule, steered for a described chip."""
@@ -372,6 +368,38 @@ def no_interpret(monkeypatch):
     monkeypatch.setattr(importlib.import_module("apex_tpu.ops.lm_head_ce"),
                         "_resolve_interpret", rule)
 
+
+def test_layers_call_one_lowered_flash_kernel_a_direction(chip, no_interpret):
+    """A four-layer GPT's forward + backward for the described chip: the
+    kernel calls are jitted on their own (``_flash_fwd_impl``,
+    ``_flash_bwd_impl``), so the program holds ONE lowered function a
+    direction, one Mosaic module in it, that the four layers call; the chip's
+    compiler still names four instructions a direction by the scopes inside
+    (what the benchmark's rooflines find). Without the jit each layer's call
+    site is traced and lowered again at every lowering, cache hit or not:
+    PR 44 lost 21 s of ``gpt2l-train-4chip``'s warm set-up to that."""
+    from apex_tpu.models.gpt import GPT, GPTConfig
+    model = GPT(GPTConfig(vocab_size=512, max_seq_len=256, hidden_size=128,
+                          num_layers=4, num_heads=2, fused_lm_head=False))
+    ids = _sds(chip, (2, 256), I32)
+    params = _place(chip, jax.eval_shape(
+        functools.partial(model.init, jax.random.PRNGKey(0)), ids))
+    lowered = jax.jit(jax.grad(
+        lambda p, ids: model.loss(p, ids, ids))).lower(params, ids)
+    text = lowered.as_text()
+    for fn in ("_flash_fwd_impl", "_flash_bwd_impl"):
+        assert text.count(f"func.func private @{fn}(") == 1
+        assert text.count(f"call @{fn}(") == 4
+    assert text.count("tpu_custom_call") == 2
+    hlo = lowered.compile().as_text()
+    for direction in ("fwd", "bwd"):
+        assert len(re.findall(
+            rf"%apx_flash_attention_{direction}[.\d]* = ", hlo)) == 4
+
+
+# ---------------------------------------------------------------------------
+# whole programs (slow): the train step and the serve programs of chip_smoke
+# ---------------------------------------------------------------------------
 
 @pytest.mark.slow
 def test_train_step_compiles_for_v5e(chip, no_interpret, chip_smoke):
