@@ -117,7 +117,7 @@ def mha_reference(q, k, v, *, causal=False, segment_ids_q=None,
 # ---------------------------------------------------------------------------
 
 def _block_mask(qi, kb, block_q, block_k, causal, causal_offset,
-                sq_ref, skv_ref):
+                sq_ref, skv_ref, window=None):
     """[block_q, block_k] validity mask for block (qi, kb), or None when
     nothing masks (not causal, no segments) — skipping the two where()
     passes and the iota/compare construction saves real VPU time in the
@@ -134,6 +134,9 @@ def _block_mask(qi, kb, block_q, block_k, causal, causal_offset,
     if causal:
         # offset aligns the (original, pre-padding) sequence ends
         mask &= k_pos <= q_pos + causal_offset
+    if window is not None:
+        # the band's lower edge: a query sees its last ``window`` keys
+        mask &= k_pos > q_pos + causal_offset - window
     if sq_ref is not None:
         sid_q = sq_ref[0]                             # [block_q, 1]
         sid_k = skv_ref[0]                            # [1, block_k]
@@ -215,8 +218,96 @@ def _causal_block_full(qi, kb, block_q, block_k, causal_offset):
     return (kb + 1) * block_k - 1 <= qi * block_q + causal_offset
 
 
+def _window_block_live(qi, kb, block_q, block_k, causal_offset, window):
+    """Whether block (qi, kb) reaches into the band from below: its last
+    key is seen by the block's first query row."""
+    return (kb + 1) * block_k - 1 > qi * block_q + causal_offset - window
+
+
+def _window_block_full(qi, kb, block_q, block_k, causal_offset, window):
+    """Whether no entry of block (qi, kb) lies below the band: its first
+    key is seen by the block's last query row."""
+    return kb * block_k > qi * block_q + block_q - 1 + causal_offset - window
+
+
+# ---------------------------------------------------------------------------
+# The band: a sliding window, and key/value heads shared by a group
+# ---------------------------------------------------------------------------
+#
+# A call with ``window`` (a query sees its last ``window`` keys) or with
+# fewer key/value heads than query heads walks a grid of its own: the inner
+# dimension counts only the blocks a q block (forward, dq) or a k block
+# (dk/dv) can see, ``_Band.inner`` of them, and the block's real index is
+# computed from the outer one (``_band_kb``, ``_band_qi``), clamped in the
+# index maps, so that a block outside the band or above the diagonal is
+# neither a step that multiplies nor a DMA (the clamped index repeats the
+# last block's). Blocks the band's edges cross are masked
+# (``_block_mask``), blocks inside run mask-free. The key/value BlockSpecs
+# index ``h // group``: K and V are read as they are, never repeated. dK
+# and dV of a key/value head are summed over its group INSIDE the dk/dv
+# kernel's grid: its inner dimension walks the group's query heads one
+# after another over the same resident K/V block and one float32
+# accumulator. These calls always take the two-kernel backward. A grouped
+# call that is not causal walks every block (its band is the square). A
+# call without either runs the grids above, unchanged.
+
+#: ``inner``: the inner grid extent; ``blocks``: how many blocks the inner
+#: index ranges over (k blocks for the q-outer grids, q blocks for dk/dv's)
+_Band = collections.namedtuple("_Band", "inner blocks causal")
+
+
+def _band_kb(qi, t, band, block_q, block_k, causal_offset):
+    """The k block of inner step ``t`` for q block ``qi``: the band's
+    blocks end at the one the q block's diagonal crosses. Negative: none."""
+    hi = band.blocks - 1
+    if band.causal:
+        hi = jnp.minimum(
+            (qi * block_q + block_q - 1 + causal_offset) // block_k, hi)
+    return hi - (band.inner - 1) + t
+
+
+def _band_qi(kb, u, band, block_q, block_k, causal_offset):
+    """The q block of inner step ``u`` for k block ``kb``: the band's
+    blocks start at the first q block that sees the k block. Past the last
+    q block: none."""
+    if not band.causal:
+        return u
+    return jnp.maximum((kb * block_k - causal_offset) // block_q, 0) + u
+
+
+def _band_plan(causal, sq_p, sk_p, block_q, block_k, causal_offset, window):
+    """``(_Band of the q-outer grids, _Band of the k-outer grid, live
+    blocks)`` for padded lengths: the inner extents are the most blocks any
+    outer block sees; causal with ``window=None`` is the triangle."""
+    n_qb, n_kb = sq_p // block_q, sk_p // block_k
+    if not causal:
+        return (_Band(n_kb, n_kb, False), _Band(n_qb, n_qb, False),
+                n_qb * n_kb)
+
+    def rows_of(qi):            # visible key range of a q block
+        lo = 0 if window is None else \
+            qi * block_q + causal_offset - window + 1
+        hi = qi * block_q + block_q - 1 + causal_offset
+        return max(lo, 0) // block_k, min(hi // block_k, n_kb - 1)
+
+    def cols_of(kb):            # q blocks that see a k block
+        lo = max((kb * block_k - causal_offset) // block_q, 0)
+        hi = n_qb - 1 if window is None else min(
+            (kb * block_k + block_k - 1 - causal_offset + window - 1)
+            // block_q, n_qb - 1)
+        return lo, hi
+
+    spans = [rows_of(qi) for qi in range(n_qb)]
+    live = sum(hi - lo + 1 for lo, hi in spans if hi >= lo)
+    k_inner = max(max(hi - lo + 1 for lo, hi in spans), 1)
+    q_inner = max(max(hi - lo + 1 for lo, hi in map(cols_of, range(n_kb))),
+                  1)
+    return _Band(k_inner, n_kb, True), _Band(q_inner, n_qb, True), live
+
+
 def _dispatch_causal(compute, causal, use_segments, qi, kb, block_q,
-                     block_k, causal_offset, skip_dead=True, strips=None):
+                     block_k, causal_offset, skip_dead=True, strips=None,
+                     window=None, alive=None):
     """Run ``compute(masked: bool)`` under the right predication — shared
     by all four kernels. Causal without segments splits live blocks into
     fully-live (mask-free, see ``_causal_block_full``; bit-identical
@@ -233,8 +324,26 @@ def _dispatch_causal(compute, causal, use_segments, qi, kb, block_q,
 
     ``strips`` (a :class:`_StripPlan`; plain causal only): the block the
     diagonal crosses runs ``compute(masked, strip)`` once a strip of
-    query rows, each against the keys up to its own diagonal."""
-    if strips is not None:
+    query rows, each against the keys up to its own diagonal.
+
+    ``alive`` (the banded grids): whether this inner step is a block at
+    all; ``window`` adds the band's lower edge to what is live and to what
+    is full."""
+    if alive is not None:
+        args = (qi, kb, block_q, block_k, causal_offset)
+        live, full = alive, True
+        if causal:
+            live &= _causal_block_live(*args)
+            full = _causal_block_full(*args)
+        if window is not None:
+            live &= _window_block_live(*args, window)
+            full &= _window_block_full(*args, window)
+        if use_segments or not causal:
+            pl.when(live)(lambda: compute(use_segments))
+        else:
+            pl.when(live & full)(lambda: compute(False))
+            pl.when(live & jnp.logical_not(full))(lambda: compute(True))
+    elif strips is not None:
         rel = qi * block_q + causal_offset - kb * block_k
         if strips.any_full:
             pl.when(rel >= block_k - 1)(lambda: compute(False))
@@ -403,7 +512,7 @@ def _count_tiles(direction, batch_heads, plan, causal, sq, sk, block_q,
 
 def _fwd_kernel(*refs, scale, causal, block_q, block_k, use_segments,
                 use_bias, dropout_rate, causal_offset, single_kb=False,
-                strips=None):
+                strips=None, window=None, band=None):
     it = iter(refs)
     sq_ref = next(it) if use_segments else None
     skv_ref = next(it) if use_segments else None
@@ -414,9 +523,13 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, use_segments,
     bi, hi, qi, kb = (pl.program_id(0), pl.program_id(1),
                       pl.program_id(2), pl.program_id(3))
     n_kb = pl.num_programs(3)
+    step, alive = kb, None          # the inner step IS the k block, or:
+    if band is not None:
+        kb = _band_kb(qi, step, band, block_q, block_k, causal_offset)
+        alive = kb >= 0
 
     if not single_kb:
-        @pl.when(kb == 0)
+        @pl.when(step == 0)
         def _init():
             m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
             l_scr[:] = jnp.zeros_like(l_scr)
@@ -442,7 +555,7 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, use_segments,
             s = s + bias_ref[0, 0].astype(jnp.float32)
 
         mask = _tile_mask(masked, strip, qi, kb, block_q, block_k, causal,
-                          causal_offset, sq_ref, skv_ref)
+                          causal_offset, sq_ref, skv_ref, window)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
 
@@ -507,10 +620,10 @@ def _fwd_kernel(*refs, scale, causal, block_q, block_k, use_segments,
 
     _dispatch_causal(_compute, causal, use_segments, qi, kb, block_q,
                      block_k, causal_offset, skip_dead=not single_kb,
-                     strips=strips)
+                     strips=strips, window=window, alive=alive)
 
     if not single_kb:
-        @pl.when(kb == n_kb - 1)
+        @pl.when(step == n_kb - 1)
         def _finish():
             l = l_scr[:]
             safe_l = jnp.where(l > 0, l, 1.0)
@@ -552,27 +665,32 @@ def _pad_operands(q, k, v, segment_ids_q, segment_ids_kv, bias, do,
     return q, k, v, segment_ids_q, segment_ids_kv, bias, do, pad_q, pad_k
 
 
+def _grid_block(g, dim):
+    return dim(*g) if callable(dim) else g[dim]
+
+
 def _seg_specs(block_q, block_k, qdim, kdim):
     """BlockSpecs for the [b, sq, 1] / [b, 1, sk] segment-id layouts.
 
-    ``qdim``/``kdim``: which grid dim indexes q-blocks / k-blocks.
+    ``qdim``/``kdim``: which grid dim indexes q-blocks / k-blocks, or (the
+    banded grids) a function of the grid indices that gives the block.
     """
     def qmap(*g):
-        return (g[0], g[qdim], 0)
+        return (g[0], _grid_block(g, qdim), 0)
 
     def kmap(*g):
-        return (g[0], 0, g[kdim])
+        return (g[0], 0, _grid_block(g, kdim))
 
     return [pl.BlockSpec((1, block_q, 1), qmap),
             pl.BlockSpec((1, 1, block_k), kmap)]
 
 
-def _bias_spec(bias, block_q, block_k, qdim, kdim):
+def _bias_spec(bias, block_q, block_k, qdim, kdim, hdim=1):
     bb, bh = bias.shape[0], bias.shape[1]
 
     def bmap(*g):
-        return (g[0] if bb > 1 else 0, g[1] if bh > 1 else 0,
-                g[qdim], g[kdim])
+        return (g[0] if bb > 1 else 0, _grid_block(g, hdim) if bh > 1 else 0,
+                _grid_block(g, qdim), _grid_block(g, kdim))
 
     return pl.BlockSpec((1, 1, block_q, block_k), bmap)
 
@@ -596,22 +714,69 @@ def _bias_spec(bias, block_q, block_k, qdim, kdim):
 # the compiled instructions (``apx_flash_attention_fwd`` / ``_bwd``: a
 # device trace tells the directions apart by them) are inside.
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12))
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10, 11, 12, 13))
 def _flash_fwd_impl(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
-                    scale, causal, dropout_rate, block_q, block_k, interpret):
+                    scale, causal, dropout_rate, block_q, block_k, interpret,
+                    window=None):
     from apex_tpu.monitor import profile as _prof
-    with _prof.scope("flash_attention_fwd"):
+    # a window call's instructions carry a name of their own, so that a
+    # device trace tells a model's window layers from its full ones
+    with _prof.scope("flash_attention_window_fwd" if window
+                     else "flash_attention_fwd"):
         return _flash_fwd(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
                           scale, causal, dropout_rate, block_q, block_k,
-                          interpret)
+                          interpret, window)
+
+
+def _banded(q, k, window):
+    """Whether a call walks the banded grids (``_Band``)."""
+    return window is not None or k.shape[1] != q.shape[1]
+
+
+#: the banded kernels hold [block_q, block_k] float32 scores and their
+#: gradient at d = 128 and 1,024-blocks: over Mosaic's 16 MB default scope
+_BAND_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+#: a banded call's default blocks: 1,024 but for the BACKWARD under a window,
+#: 512. Measured on a v5e at b2 h32/4 s8192 d128 bf16 (PERF.md, PR 46), ms a
+#: call, blocks 256 / 512 / 1,024: window 1,024 forward 11.3 / 6.3 / 4.8,
+#: its backward 14.8 / 9.2 / 11.2; no window forward 41.4 / 17.8 / 10.1, its
+#: backward 58.7 / 28.6 / 25.6. A grid step costs more than the band's
+#: tighter fit saves, except where the two-kernel backward multiplies a
+#: window's edge blocks twice
+_BAND_BLOCK, _BAND_BLOCK_BWD_WINDOW = 1024, 512
+
+
+def _band_params():
+    from apex_tpu._compat import tpu_compiler_params
+    return tpu_compiler_params(vmem_limit_bytes=_BAND_VMEM_LIMIT)
+
+
+def _count_band_tiles(direction, batch_heads, window, live, sq_p, sk_p,
+                      block_q, block_k):
+    """``flash/tiles_*`` of a banded call (``_count_tiles``), with
+    ``attention=window|full`` beside the direction (``kind`` is the
+    recorder's own field): the blocks its grid multiplies, whole, over the
+    square's."""
+    from apex_tpu.monitor import hooks as _mon
+    kind = "window" if window is not None else "full"
+    unit = 128.0 * 128.0
+    _mon.counter("flash/tiles_computed",
+                 batch_heads * live * block_q * block_k / unit,
+                 direction=direction, attention=kind)
+    _mon.counter("flash/tiles_square", batch_heads * sq_p * sk_p / unit,
+                 direction=direction, attention=kind)
 
 
 def _flash_fwd(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
-               scale, causal, dropout_rate, block_q, block_k, interpret):
+               scale, causal, dropout_rate, block_q, block_k, interpret,
+               window=None):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     causal_offset = sk - sq   # aligns the original sequence ends
-    strips = _strip_plan(
+    banded = _banded(q, k, window)
+    strips = None if banded else _strip_plan(
         "fwd", causal, segment_ids_q is None and bias is None
         and dropout_rate == 0.0, sq, sk, block_q, block_k, d)
     block_q = min(block_q, sq)
@@ -624,14 +789,32 @@ def _flash_fwd(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
     use_bias = bias is not None
 
     grid = (b, h, sq_p // block_q, sk_p // block_k)
-    single_kb = sk_p // block_k == 1
+    single_kb = sk_p // block_k == 1 and not banded
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k, use_segments=use_segments, use_bias=use_bias,
         dropout_rate=dropout_rate, causal_offset=causal_offset,
         single_kb=single_kb, strips=strips)
-    _count_tiles("fwd", b * h, strips, causal, sq_p, sk_p, block_q, block_k,
-                 skip_dead=not single_kb)
+    kdim, kv_map, params = 3, (lambda b_, h_, qi, ki: (b_, h_, ki, 0)), {}
+    if banded:
+        band, _, live = _band_plan(causal, sq_p, sk_p, block_q, block_k,
+                                   causal_offset, window)
+        kernel = functools.partial(kernel, window=window, band=band)
+        grid = grid[:3] + (band.inner,)
+        params = dict(compiler_params=_band_params())
+        _count_band_tiles("fwd", b * h, window, live, sq_p, sk_p, block_q,
+                          block_k)
+        group = h // k.shape[1]
+
+        def kdim(*g):       # the k block of a (q block, inner step)
+            return jnp.maximum(_band_kb(g[2], g[3], band, block_q, block_k,
+                                        causal_offset), 0)
+
+        def kv_map(*g):
+            return g[0], g[1] // group, kdim(*g), 0
+    else:
+        _count_tiles("fwd", b * h, strips, causal, sq_p, sk_p, block_q,
+                     block_k, skip_dead=not single_kb)
 
     # Mosaic requires the last two block dims to be (8k, 128k) or equal to
     # the array dims — trailing-singleton layouts (b, sq, 1) / (b, 1, sk)
@@ -639,18 +822,18 @@ def _flash_fwd(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
     in_specs = []
     operands = []
     if use_segments:
-        in_specs += _seg_specs(block_q, block_k, qdim=2, kdim=3)
+        in_specs += _seg_specs(block_q, block_k, qdim=2, kdim=kdim)
         operands += [segment_ids_q[:, :, None], segment_ids_kv[:, None, :]]
     if use_bias:
-        in_specs += [_bias_spec(bias, block_q, block_k, qdim=2, kdim=3)]
+        in_specs += [_bias_spec(bias, block_q, block_k, qdim=2, kdim=kdim)]
         operands += [bias]
     if dropout_rate > 0.0:
         in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM)]
         operands += [seed]
     in_specs += [
         pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-        pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, qi, ki: (b_, h_, ki, 0)),
-        pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, qi, ki: (b_, h_, ki, 0)),
+        pl.BlockSpec((1, 1, block_k, d), kv_map),
+        pl.BlockSpec((1, 1, block_k, d), kv_map),
     ]
     operands += [q, k, v]
 
@@ -680,6 +863,7 @@ def _flash_fwd(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
                 pltpu.VMEM((block_q, d), jnp.float32),
             ]),
         interpret=interpret,
+        **params,
     )(*operands)
     return out[:, :, :sq], lse[:, :, 0, :sq]
 
@@ -752,7 +936,8 @@ def _p_dp_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
 
 
 def _dkdv_kernel(*refs, scale, causal, block_q, block_k, use_segments,
-                 use_bias, dropout_rate, causal_offset):
+                 use_bias, dropout_rate, causal_offset, window=None,
+                 band=None, group=1):
     it = iter(refs)
     sq_ref = next(it) if use_segments else None
     skv_ref = next(it) if use_segments else None
@@ -764,16 +949,24 @@ def _dkdv_kernel(*refs, scale, causal, block_q, block_k, use_segments,
     bi, hi, kb, qi = (pl.program_id(0), pl.program_id(1),
                       pl.program_id(2), pl.program_id(3))
     n_qb = pl.num_programs(3)
+    step, alive = qi, None          # the inner step IS the q block, or:
+    if band is not None:
+        # the group's query heads one after another, each over the band's
+        # q blocks; ``hi`` the query head (the dropout mask is keyed by it)
+        hi = hi * group + step // band.inner
+        qi = _band_qi(kb, step % band.inner, band, block_q, block_k,
+                      causal_offset)
+        alive = qi < band.blocks
     guard = use_segments or use_bias or causal_offset < 0
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     def _compute(masked):
         mask = (_block_mask(qi, kb, block_q, block_k, causal, causal_offset,
-                            sq_ref, skv_ref) if masked else None)
+                            sq_ref, skv_ref, window) if masked else None)
         p_drop, do, ds = _p_dp_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
             seed_ref, mask, scale, dropout_rate, bi, hi, qi, kb,
@@ -788,9 +981,9 @@ def _dkdv_kernel(*refs, scale, causal, block_q, block_k, use_segments,
             preferred_element_type=jnp.float32)
 
     _dispatch_causal(_compute, causal, use_segments, qi, kb, block_q,
-                     block_k, causal_offset)
+                     block_k, causal_offset, window=window, alive=alive)
 
-    @pl.when(qi == n_qb - 1)
+    @pl.when(step == n_qb - 1)
     def _finish():
         dk = dk_scr[:] * scale if scale != 1.0 else dk_scr[:]
         dk_ref[0, 0] = dk.astype(dk_ref.dtype)
@@ -870,7 +1063,7 @@ def _bwd_fused_kernel(*refs, scale, causal, block_q, block_k, use_segments,
 
 
 def _dq_kernel(*refs, scale, causal, block_q, block_k, use_segments,
-               use_bias, dropout_rate, causal_offset):
+               use_bias, dropout_rate, causal_offset, window=None, band=None):
     it = iter(refs)
     sq_ref = next(it) if use_segments else None
     skv_ref = next(it) if use_segments else None
@@ -881,15 +1074,19 @@ def _dq_kernel(*refs, scale, causal, block_q, block_k, use_segments,
     bi, hi, qi, kb = (pl.program_id(0), pl.program_id(1),
                       pl.program_id(2), pl.program_id(3))
     n_kb = pl.num_programs(3)
+    step, alive = kb, None          # as in ``_fwd_kernel``
+    if band is not None:
+        kb = _band_kb(qi, step, band, block_q, block_k, causal_offset)
+        alive = kb >= 0
     guard = use_segments or use_bias or causal_offset < 0
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     def _compute(masked):
         mask = (_block_mask(qi, kb, block_q, block_k, causal, causal_offset,
-                            sq_ref, skv_ref) if masked else None)
+                            sq_ref, skv_ref, window) if masked else None)
         _, _, ds = _p_dp_ds(
             q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, bias_ref,
             seed_ref, mask, scale, dropout_rate, bi, hi, qi, kb,
@@ -900,23 +1097,26 @@ def _dq_kernel(*refs, scale, causal, block_q, block_k, use_segments,
             preferred_element_type=jnp.float32)
 
     _dispatch_causal(_compute, causal, use_segments, qi, kb, block_q,
-                     block_k, causal_offset)
+                     block_k, causal_offset, window=window, alive=alive)
 
-    @pl.when(kb == n_kb - 1)
+    @pl.when(step == n_kb - 1)
     def _finish():
         dq = dq_scr[:] * scale if scale != 1.0 else dq_scr[:]
         dq_ref[0, 0] = dq.astype(dq_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "scale", "causal", "dropout_rate", "block_q", "block_k", "interpret"))
+    "scale", "causal", "dropout_rate", "block_q", "block_k", "interpret",
+    "window"))
 def _flash_bwd_impl(res, do, *, scale, causal, dropout_rate, block_q,
-                    block_k, interpret):
+                    block_k, interpret, window=None):
     from apex_tpu.monitor import profile as _prof
-    with _prof.scope("flash_attention_bwd"):
+    with _prof.scope("flash_attention_window_bwd" if window
+                     else "flash_attention_bwd"):
         return _flash_bwd(res, do, scale=scale, causal=causal,
                           dropout_rate=dropout_rate, block_q=block_q,
-                          block_k=block_k, interpret=interpret)
+                          block_k=block_k, interpret=interpret,
+                          window=window)
 
 
 def _bwd_fused(sk_p, d, k_dtype, v_dtype, use_bias, dropout_rate, block_q,
@@ -955,12 +1155,13 @@ def _bwd_strip_plan(causal, plain, sq, sk, block_q, block_k, d, k_dtype,
 
 
 def _flash_bwd(res, do, *, scale, causal, dropout_rate, block_q, block_k,
-               interpret):
+               interpret, window=None):
     q, k, v, out, lse, sid_q, sid_kv, bias, seed = res
     b, h, sq, d = q.shape
     sk = k.shape[2]
     causal_offset = sk - sq
-    strips = _bwd_strip_plan(
+    banded = _banded(q, k, window)
+    strips = None if banded else _bwd_strip_plan(
         causal, sid_q is None and bias is None and dropout_rate == 0.0,
         sq, sk, block_q, block_k, d, k.dtype, v.dtype)
     block_q = min(block_q, sq)
@@ -989,13 +1190,14 @@ def _flash_bwd(res, do, *, scale, causal, dropout_rate, block_q, block_k,
                   use_bias=use_bias, dropout_rate=dropout_rate,
                   causal_offset=causal_offset)
 
-    def extra(qdim, kdim):
+    def extra(qdim, kdim, hdim=1):
         specs, ops = [], []
         if use_segments:
             specs += _seg_specs(block_q, block_k, qdim=qdim, kdim=kdim)
             ops += [sid_q[:, :, None], sid_kv[:, None, :]]
         if use_bias:
-            specs += [_bias_spec(bias, block_q, block_k, qdim=qdim, kdim=kdim)]
+            specs += [_bias_spec(bias, block_q, block_k, qdim=qdim, kdim=kdim,
+                                 hdim=hdim)]
             ops += [bias]
         if dropout_rate > 0.0:
             specs += [pl.BlockSpec(memory_space=pltpu.SMEM)]
@@ -1014,6 +1216,10 @@ def _flash_bwd(res, do, *, scale, causal, dropout_rate, block_q, block_k,
         return pl.BlockSpec((1, 1, 1, block_q),
                             lambda *g, _q=qdim: (g[0], g[1], 0, g[_q]))
 
+    if banded:
+        return _flash_bwd_banded(
+            (q_p, k_p, v_p, do_p, lse4, delta), extra, common, window,
+            (sq, sk), interp)
     _count_tiles("bwd", b * h, strips, causal, sq_p, sk_p, block_q, block_k)
     # --- fused single-pass backward when the [sk, d] dk/dv accumulators
     # fit the scoped-VMEM budget
@@ -1068,6 +1274,78 @@ def _flash_bwd(res, do, *, scale, causal, dropout_rate, block_q, block_k,
     return dq[:, :, :sq], dk[:, :, :sk], dv[:, :, :sk]
 
 
+def _flash_bwd_banded(operands, extra, common, window, lengths, interpret):
+    """The two-kernel backward over the banded grids (``_Band``): dk/dv a
+    KEY/VALUE head, its inner dimension the group's query heads times the
+    q blocks that see the k block; dq a query head over the k blocks its q
+    block sees. ``operands``: padded q, k, v, do and the lse and delta
+    rows; ``extra`` and ``common`` as in ``_flash_bwd``."""
+    q_p, k_p, v_p, do_p, lse4, delta = operands
+    b, h, sq_p, d = q_p.shape
+    hk, sk_p = k_p.shape[1], k_p.shape[2]
+    group = h // hk
+    block_q, block_k = common["block_q"], common["block_k"]
+    off = common["causal_offset"]
+    kband, qband, live = _band_plan(common["causal"], sq_p, sk_p, block_q,
+                                    block_k, off, window)
+    _count_band_tiles("bwd", b * h, window, live, sq_p, sk_p, block_q,
+                      block_k)
+    common = dict(common, window=window)
+
+    # --- dk/dv: grid (b, kv head, kb, group x band), k block resident
+    def head_of(*g):
+        return g[1] * group + g[3] // qband.inner
+
+    def qi_of(*g):
+        return jnp.minimum(_band_qi(g[2], g[3] % qband.inner, qband, block_q,
+                                    block_k, off), qband.blocks - 1)
+
+    q_spec = pl.BlockSpec((1, 1, block_q, d),
+                          lambda *g: (g[0], head_of(*g), qi_of(*g), 0))
+    row_spec = pl.BlockSpec((1, 1, 1, block_q),
+                            lambda *g: (g[0], head_of(*g), 0, qi_of(*g)))
+    kv_spec = pl.BlockSpec((1, 1, block_k, d),
+                           lambda *g: (g[0], g[1], g[2], 0))
+    especs, eops = extra(qdim=qi_of, kdim=2, hdim=head_of)
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkdv_kernel, band=qband, group=group, **common),
+        grid=(b, hk, sk_p // block_k, group * qband.inner),
+        in_specs=especs + [q_spec, kv_spec, kv_spec, q_spec, row_spec,
+                           row_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(k_p.shape, k_p.dtype),
+                   jax.ShapeDtypeStruct(v_p.shape, v_p.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        interpret=interpret, compiler_params=_band_params(),
+    )(*eops, q_p, k_p, v_p, do_p, lse4, delta)
+
+    # --- dq: grid (b, h, qi, band), q block resident
+    def kb_of(*g):
+        return jnp.maximum(_band_kb(g[2], g[3], kband, block_q, block_k,
+                                    off), 0)
+
+    q_spec = pl.BlockSpec((1, 1, block_q, d),
+                          lambda *g: (g[0], g[1], g[2], 0))
+    row_spec = pl.BlockSpec((1, 1, 1, block_q),
+                            lambda *g: (g[0], g[1], 0, g[2]))
+    kv_spec = pl.BlockSpec((1, 1, block_k, d),
+                           lambda *g: (g[0], g[1] // group, kb_of(*g), 0))
+    especs, eops = extra(qdim=2, kdim=kb_of)
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, band=kband, **common),
+        grid=(b, h, sq_p // block_q, kband.inner),
+        in_specs=especs + [q_spec, kv_spec, kv_spec, q_spec, row_spec,
+                           row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q_p.shape, q_p.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        interpret=interpret, compiler_params=_band_params(),
+    )(*eops, q_p, k_p, v_p, do_p, lse4, delta)
+    sq, sk = lengths
+    return dq[:, :, :sq], dk[:, :, :sk], dv[:, :, :sk]
+
+
 # ---------------------------------------------------------------------------
 # Reference backward math (parity baseline for the Pallas kernels; O(s^2)
 # memory — debug/test only)
@@ -1112,13 +1390,13 @@ def _bwd_math(res, do, *, scale, causal, dropout_rate=0.0):
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14))
+                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15))
 def _flash_attention(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
                      causal, scale, dropout_rate, block_q, block_k,
-                     block_q_bwd, block_k_bwd, interpret):
+                     block_q_bwd, block_k_bwd, interpret, window=None):
     out, _ = _fa_fwd(q, k, v, segment_ids_q, segment_ids_kv, bias, seed,
                      causal, scale, dropout_rate, block_q, block_k,
-                     block_q_bwd, block_k_bwd, interpret)
+                     block_q_bwd, block_k_bwd, interpret, window)
     return out
 
 
@@ -1129,23 +1407,25 @@ def _resolve_interpret(interpret):
 
 
 def _fa_fwd(q, k, v, sid_q, sid_kv, bias, seed, causal, scale, dropout_rate,
-            block_q, block_k, block_q_bwd, block_k_bwd, interpret):
+            block_q, block_k, block_q_bwd, block_k_bwd, interpret,
+            window=None):
     scale_v = q.shape[-1] ** -0.5 if scale is None else scale
     out, lse = _flash_fwd_impl(q, k, v, sid_q, sid_kv, bias, seed,
                                float(scale_v), causal, dropout_rate, block_q,
-                               block_k, _resolve_interpret(interpret))
+                               block_k, _resolve_interpret(interpret), window)
     return out, (q, k, v, out, lse, sid_q, sid_kv, bias, seed)
 
 
 def _fa_bwd(causal, scale, dropout_rate, block_q, block_k,
-            block_q_bwd, block_k_bwd, interpret, res, do):
+            block_q_bwd, block_k_bwd, interpret, window, res, do):
     q = res[0]
     bias = res[7]
     scale_v = q.shape[-1] ** -0.5 if scale is None else scale
     dq, dk, dv = _flash_bwd_impl(
         res, do, scale=float(scale_v), causal=causal,
         dropout_rate=dropout_rate, block_q=block_q_bwd,
-        block_k=block_k_bwd, interpret=_resolve_interpret(interpret))
+        block_k=block_k_bwd, interpret=_resolve_interpret(interpret),
+        window=window)
     # bias is an additive attention mask — non-differentiable by contract
     # (matches apex, where masks are inputs, never parameters); a real dbias
     # would require materializing [sq, sk] and is deliberately not offered.
@@ -1710,8 +1990,26 @@ def flash_attention(q, k, v, segment_ids_q=None, segment_ids_kv=None,
                     block_q_bwd: Optional[int] = None,
                     block_k_bwd: Optional[int] = None,
                     interpret: Optional[bool] = None,
-                    autotune: Optional[str] = None):
+                    autotune: Optional[str] = None,
+                    window: Optional[int] = None):
     """Fused attention. Returns [b, h, sq, d].
+
+    ``k``, ``v`` ``[b, hk, sk, d]`` with ``hk`` = ``h`` or a divisor of it
+    (grouped-query attention: query head ``i`` reads key/value head ``i //
+    (h // hk)``; the kernels index it, K and V are never repeated, and dK
+    and dV come back ``[b, hk, sk, d]``, summed over each group in float32
+    inside the backward kernel). ``h`` not a multiple of ``hk`` is a
+    ``ValueError``.
+
+    ``window``: a sliding window (``causal=True`` only, ``sq <= sk``): a
+    query sees its last ``window`` keys, itself included (``i - window < j
+    <= i`` at equal lengths). Blocks wholly outside the band are neither
+    computed nor fetched, blocks its edges cross are masked. ``window >=
+    sk`` is plain causal attention, bit for bit. A window or a grouped call
+    walks grids of its own (``_Band``), takes the two-kernel backward and
+    blocks of 1,024 (512 in a window call's backward) where none are passed
+    (the tuned-block cache is not consulted); its instructions are named
+    ``apx_flash_attention_window_fwd`` / ``_bwd`` under a window.
 
     ``segment_ids_*``: packed-varlen support (FMHA cu_seqlens analog) —
     tokens attend only within equal *non-negative* segment ids; negative
@@ -1761,6 +2059,28 @@ def flash_attention(q, k, v, segment_ids_q=None, segment_ids_kv=None,
     """
     if dropout_rate >= 1.0 or dropout_rate < 0.0:
         raise ValueError(f"dropout_rate must be in [0, 1), got {dropout_rate}")
+    if q.shape[1] % k.shape[1] or k.shape[1] != v.shape[1]:
+        raise ValueError(
+            f"{q.shape[1]} query heads are not a multiple of {k.shape[1]} "
+            f"key / {v.shape[1]} value heads")
+    if window is not None:
+        if not causal or window < 1 or q.shape[2] > k.shape[2]:
+            raise ValueError(
+                f"window={window} needs causal=True, window >= 1 and "
+                f"sq <= sk (got causal={causal}, sq={q.shape[2]}, "
+                f"sk={k.shape[2]})")
+        if window >= k.shape[2]:
+            window = None                   # every key is inside the band
+    if _banded(q, k, window):
+        # the banded grids: the blocks passed, or their defaults; no lookup
+        # (explicit forward blocks govern the backward too, as below)
+        both = bias is not None and dropout_rate > 0.0
+        bwd = block_q or block_k or (
+            _BAND_BLOCK_BWD_WINDOW if window is not None or both
+            else _BAND_BLOCK)
+        fwd = 512 if both else _BAND_BLOCK
+        block_q, block_k = block_q or fwd, block_k or fwd
+        block_q_bwd, block_k_bwd = block_q_bwd or bwd, block_k_bwd or bwd
     explicit_fwd_blocks = block_q is not None or block_k is not None
     if (block_q is None and block_k is None) or \
             (block_q_bwd is None and block_k_bwd is None):
@@ -1871,4 +2191,4 @@ def flash_attention(q, k, v, segment_ids_q=None, segment_ids_kv=None,
         return _flash_attention(q, k, v, segment_ids_q, segment_ids_kv,
                                 bias, seed, causal, scale,
                                 float(dropout_rate), block_q, block_k,
-                                block_q_bwd, block_k_bwd, interpret)
+                                block_q_bwd, block_k_bwd, interpret, window)

@@ -18,6 +18,25 @@ worst case of the counts); tiles past ``tiles_used`` map every operand to
 the block of the last used step, so they move nothing, and compute
 nothing: their output rows are never read.
 
+**Backward** (the kernel path is a ``jax.custom_vjp``; a program that never
+differentiates it traces the forward kernel alone). Both cotangents walk
+the forward's tile layout, nothing is sorted again:
+
+- ``dx = dy @ w[group]^T``: the forward's kernel with the weight block
+  contracted over its columns (``transpose_w``: ``[block_m, N] x [block_k,
+  N]^T``, no transposed copy of the weights), under the same scope
+  ``apx:moe_grouped_matmul``. Its rows past ``tiles_used`` are undefined,
+  as the forward's are: whoever laid the rows out reads back the rows it
+  wrote (``moe_dropless._take_rows`` gathers its cotangent).
+- ``dw[g] = sum over the tiles of g of x_tile^T @ dy_tile``: a kernel of its
+  own (``apx:moe_grouped_matmul_dw``), grid ``(n blocks, m tiles)`` with the
+  tiles innermost: a float32 ``[K, block_n]`` accumulator is zeroed at a
+  group's first tile and written to the group's block of ``dw`` at its last
+  (a group's tiles are consecutive), tiles past ``tiles_used`` are skipped
+  as in the forward, and a group without a row keeps the zeros ``dw``
+  starts from (the output aliases a zero array that is never read).
+  ``tile_group`` and ``tiles_used`` carry no gradient.
+
 ``impl="reference"`` is a plain einsum over a one-hot of each row's group:
 the off-TPU path and the tests' baseline. ``jax.lax.ragged_dot`` over the
 same layout was tried on the chip and not kept: 1.7-2.4x slower at decode's
@@ -27,6 +46,7 @@ the kernel skips unused tiles (PERF.md, PR 26).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -79,21 +99,56 @@ def num_tiles(groups: int, block_m: int, max_rows: int) -> int:
     return -(-max_rows // block_m) + min(groups, max_rows)
 
 
-def _kernel(tg_ref, used_ref, x_ref, w_ref, o_ref):
+def _kernel(tg_ref, used_ref, x_ref, w_ref, o_ref, *, transpose_w=False):
     del tg_ref
 
     @pl.when(pl.program_id(0) < used_ref[0])
     def _():
         o_ref[...] = jax.lax.dot_general(
-            x_ref[...], w_ref[0], (((1,), (0,)), ((), ())),
+            x_ref[...], w_ref[0],
+            (((1,), (1 if transpose_w else 0,)), ((), ())),
             preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def _dw_kernel(tg_ref, used_ref, x_ref, dy_ref, _, o_ref, acc):
+    i, used = pl.program_id(1), used_ref[0]
+    last_tile = pl.num_programs(1) - 1
+
+    @pl.when(i < used)
+    def _():
+        g = tg_ref[i]
+        first = (i == 0) | (tg_ref[jnp.maximum(i - 1, 0)] != g)
+        last = (i == used - 1) | (tg_ref[jnp.minimum(i + 1, last_tile)] != g)
+        prod = jax.lax.dot_general(
+            x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)             # [K, block_n]
+
+        @pl.when(first)
+        def _():
+            acc[...] = prod
+
+        @pl.when(jnp.logical_not(first))
+        def _():
+            acc[...] += prod
+
+        @pl.when(last)
+        def _():
+            o_ref[0] = acc[...].astype(o_ref.dtype)
+
+    # no tile holds a row: the block the clamped index names is written
+    # back all the same, so it has to hold the zeros it stands for
+    @pl.when((used == 0) & (i == 0))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
 
 def grouped_matmul(x, w, tile_group, tiles_used, *, block_m: int,
                    impl: str = "kernel", interpret: Optional[bool] = None):
     """``x`` ``[tiles * block_m, K]`` in the tile layout, ``w`` ``[g, K,
     N]``: ``[tiles * block_m, N]`` in ``x.dtype``. Rows of tiles past
-    ``tiles_used`` are undefined."""
+    ``tiles_used`` are undefined. Differentiable in ``x`` and ``w`` (module
+    doc: the cotangent of ``x`` is undefined in those rows too, a group
+    without a row gets a zero ``dw``)."""
     if impl not in IMPLS:
         raise ValueError(f"impl must be one of {IMPLS}, got {impl!r}")
     m, k = x.shape
@@ -108,38 +163,116 @@ def grouped_matmul(x, w, tile_group, tiles_used, *, block_m: int,
         return jnp.einsum("mk,mg,gkn->mn", x, onehot, w,
                           preferred_element_type=jnp.float32
                           ).astype(x.dtype)
-    bn = _block_n(k, n)
+    used = jnp.reshape(tiles_used, (1,)).astype(jnp.int32)
+    return _grouped_matmul(x, w, tile_group, used, block_m,
+                           _resolve_interpret(interpret))
 
-    def step(i, used):
-        # past the used tiles: stay on the last used step's blocks
-        return jnp.minimum(i, jnp.maximum(used[0] - 1, 0))
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _grouped_matmul(x, w, tile_group, used, block_m, interpret):
+    return _call(x, w, tile_group, used, block_m, interpret)
+
+
+def _vjp_fwd(x, w, tile_group, used, block_m, interpret):
+    return (_call(x, w, tile_group, used, block_m, interpret),
+            (x, w, tile_group, used))
+
+
+def _vjp_bwd(block_m, interpret, res, dy):
+    x, w, tile_group, used = res
+    dx = _call(dy, w, tile_group, used, block_m, interpret,
+               transpose_w=True)
+    dw = _call_dw(x, dy, w, tile_group, used, block_m, interpret)
+    return dx.astype(x.dtype), dw, None, None
+
+
+_grouped_matmul.defvjp(_vjp_fwd, _vjp_bwd)
+
+
+def _last_used(i, used):
+    """Tile ``i``, or past the used tiles the last used one: a step there
+    stays on the blocks it had, and moves nothing."""
+    return jnp.minimum(i, jnp.maximum(used[0] - 1, 0))
+
+
+def _call(x, w, tile_group, used, block_m, interpret, transpose_w=False):
+    """``x @ w[group]`` (``[m, K] -> [m, N]``), or with ``transpose_w``
+    ``x @ w[group]^T`` (``[m, N] -> [m, K]``)."""
+    m = x.shape[0]
+    g, k, n = w.shape
+    tiles = tile_group.shape[0]
+    if transpose_w:
+        bo = _block_n(n, k)                 # a block of w's ROWS, whole N
+        n_out, w_block = k, (1, bo, n)
+    else:
+        bo = _block_n(k, n)                 # a block of w's columns, whole K
+        n_out, w_block = n, (1, k, bo)
+    last = n_out // bo - 1
+    step = _last_used
+
+    def col(i, j, used):
+        return jnp.where(i < used[0], j, last)
+
+    def w_map(i, j, tg, used):
+        c = col(i, j, used)
+        return (tg[i], c, 0) if transpose_w else (tg[i], 0, c)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(tiles, n // bn),
+        grid=(tiles, n_out // bo),
         in_specs=[
-            pl.BlockSpec((block_m, k),
+            pl.BlockSpec((block_m, x.shape[1]),
                          lambda i, j, tg, used: (step(i, used), 0)),
-            pl.BlockSpec((1, k, bn),
-                         lambda i, j, tg, used: (
-                             tg[i], 0, jnp.where(i < used[0], j,
-                                                 n // bn - 1))),
+            pl.BlockSpec(w_block, w_map),
         ],
         out_specs=pl.BlockSpec(
-            (block_m, bn),
-            lambda i, j, tg, used: (step(i, used),
-                                    jnp.where(i < used[0], j, n // bn - 1))),
+            (block_m, bo),
+            lambda i, j, tg, used: (step(i, used), col(i, j, used))),
     )
     with _prof.scope("moe_grouped_matmul"):
         return pl.pallas_call(
-            _kernel,
+            functools.partial(_kernel, transpose_w=transpose_w),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+            out_shape=jax.ShapeDtypeStruct((m, n_out), x.dtype),
             compiler_params=tpu_compiler_params(
                 vmem_limit_bytes=_VMEM_LIMIT,
                 dimension_semantics=("arbitrary", "arbitrary")),
-            interpret=_resolve_interpret(interpret),
-        )(tile_group, jnp.reshape(tiles_used, (1,)).astype(jnp.int32), x, w)
+            interpret=interpret,
+        )(tile_group, used, x, w)
+
+
+def _call_dw(x, dy, w, tile_group, used, block_m, interpret):
+    """``dw [g, K, N]`` in ``w.dtype`` (module doc)."""
+    g, k, n = w.shape
+    tiles = tile_group.shape[0]
+    bn = _block_n(k, n, itemsize=4)         # the float32 accumulator's
+    step = _last_used
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(n // bn, tiles),
+        in_specs=[
+            pl.BlockSpec((block_m, k),
+                         lambda j, i, tg, used: (step(i, used), 0)),
+            pl.BlockSpec((block_m, bn),
+                         lambda j, i, tg, used: (step(i, used), j)),
+            pl.BlockSpec(memory_space=pl.ANY),      # the zeros dw starts as
+        ],
+        out_specs=pl.BlockSpec(
+            (1, k, bn), lambda j, i, tg, used: (tg[step(i, used)], 0, j)),
+        scratch_shapes=[pltpu.VMEM((k, bn), jnp.float32)],
+    )
+    with _prof.scope("moe_grouped_matmul_dw"):
+        return pl.pallas_call(
+            _dw_kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((g, k, n), w.dtype),
+            input_output_aliases={4: 0},
+            compiler_params=tpu_compiler_params(
+                vmem_limit_bytes=_VMEM_LIMIT,
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=interpret,
+        )(tile_group, used, x, dy, jnp.zeros((g, k, n), w.dtype))
 
 
 def _block_n(k: int, n: int, budget: int = 8 * 1024 * 1024,

@@ -24,7 +24,7 @@ What a model's description has to answer: ``routing`` (a key of
 :data:`ROUTING`) with the sizes that rule reads, ``n_routed_experts``,
 ``zero_expert_num``, ``first_expert``, ``local_experts``.
 
-Routing (float32, as published), two rules:
+Routing (float32, as published), three rules:
 
 - ``sigmoid_group_limited`` (DeepSeek-V3): ``sc = sigmoid(x W_g)``; the
   correction bias moves the CHOICE only (``sc + b``); a group's score is
@@ -37,6 +37,19 @@ Routing (float32, as published), two rules:
   the choice only; the top ``moe_topk`` of ``p + b``, no groups; the
   weights are ``p`` of the chosen times ``routed_scaling_factor``, NOT
   renormalised.
+- ``softmax_topk_renorm`` (Mellum 2, ``norm_topk_prob: true``): ``p =
+  softmax(x W_r)`` over all ``n_routed_experts``; no bias, no groups, no
+  scaling factor; the top ``num_experts_per_tok`` of ``p``; the weights
+  are ``p`` of the chosen divided by their sum.
+
+**Training.** The layer differentiates: to ``x`` (through the gather into
+the tile layout, the router's logits and the combine), to the experts
+(``ops.grouped_matmul``'s backward) and to the router (through the weights
+``w``; the choice ``idx`` carries none). The two gathers' cotangents are
+gathers as well (:func:`_take_rows`: the layout is a known permutation with
+holes, so nothing is scattered), and the static row bound stays the worst
+case, ``t x min(k, n_local)`` rows: nothing is dropped in a step whose
+tokens all choose experts held here.
 
 No operation mixes tokens: a token's output row depends on its own input
 alone (its position among an expert's rows changes which tile row computes
@@ -57,8 +70,17 @@ from apex_tpu.ops import grouped_matmul as gmm
 
 #: rows of a tile of the grouped matmul: a decode step brings a handful of
 #: rows an expert (weight-streaming-bound whatever the tile: 16 / 32 / 64
-#: read 1.39 / 1.35 / 1.34 ms on the chip), a prompt some dozens
-BLOCK_M_DECODE, BLOCK_M_PREFILL = 32, 128
+#: read 1.39 / 1.35 / 1.34 ms on the chip), a prompt some dozens, a training
+#: step's 16,384 tokens some thousands (compute-bound; ``_block_m``)
+BLOCK_M_DECODE, BLOCK_M_PREFILL, BLOCK_M_TRAIN = 32, 128, 256
+
+
+def _block_m(assignments: int) -> int:
+    """Rows of a tile for a call of ``assignments`` (token, choice) pairs:
+    a decode step's, a prompt's, or a training step's."""
+    if assignments <= 4096:
+        return BLOCK_M_DECODE
+    return BLOCK_M_PREFILL if assignments < 65536 else BLOCK_M_TRAIN
 
 
 def _router_logits(router, x):
@@ -89,15 +111,51 @@ def _route_softmax_topk(cfg, router, bias, x):
     return idx.astype(jnp.int32), w * cfg.routed_scaling_factor
 
 
+def _route_softmax_topk_renorm(cfg, router, bias, x):
+    del bias                                    # the rule has none
+    p = jax.nn.softmax(_router_logits(router, x), axis=-1)
+    idx = jax.lax.top_k(jax.lax.stop_gradient(p),
+                        cfg.num_experts_per_tok)[1]
+    w = jnp.take_along_axis(p, idx, axis=1)
+    return idx.astype(jnp.int32), w / jnp.sum(w, axis=-1, keepdims=True)
+
+
 #: the rules a description can name as its ``routing``
 ROUTING = {"sigmoid_group_limited": _route_sigmoid_group_limited,
-           "softmax_topk": _route_softmax_topk}
+           "softmax_topk": _route_softmax_topk,
+           "softmax_topk_renorm": _route_softmax_topk_renorm}
 
 
 def route(cfg, router, bias, x):
     """``(idx [t, k] int32, w [t, k] f32)`` over all of the router's slots,
     by the rule ``cfg.routing`` names."""
     return ROUTING[cfg.routing](cfg, router, bias, x)
+
+
+@jax.custom_vjp
+def _take_rows(x, idx, back_idx, back_ok):
+    """``x[idx]`` (rows) whose cotangent is GATHERED: row ``j`` of ``x``
+    gets the sum over ``c`` of ``dy[back_idx[j, c]]`` where ``back_ok[j,
+    c]``. The caller knows where each row of ``x`` went (the tile layout is
+    a permutation with holes), which a scatter-add would have to find out
+    row by row. Not differentiated, it is ``jnp.take``."""
+    del back_idx, back_ok
+    return jnp.take(x, idx, axis=0)
+
+
+def _take_rows_fwd(x, idx, back_idx, back_ok):
+    return jnp.take(x, idx, axis=0), (back_idx, back_ok)
+
+
+def _take_rows_bwd(res, dy):
+    back_idx, back_ok = res
+    g = jnp.take(dy, back_idx.reshape(-1), axis=0).reshape(
+        back_idx.shape + dy.shape[1:])
+    g = jnp.where(back_ok[..., None], g.astype(jnp.float32), 0.0).sum(1)
+    return g.astype(dy.dtype), None, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
 def expert_layer(cfg, p, x, *, active=None, impl: str = "kernel",
@@ -120,9 +178,9 @@ def expert_layer(cfg, p, x, *, active=None, impl: str = "kernel",
     nl = cfg.local_experts
     with _prof.scope("moe"):
         with _prof.scope("moe_route"):
-            idx, w = route(cfg, p["router"], p["bias"], x)
+            idx, w = route(cfg, p["router"], p.get("bias"), x)
             k = idx.shape[1]
-            bm = BLOCK_M_DECODE if t * k <= 4096 else BLOCK_M_PREFILL
+            bm = _block_m(t * k)
             max_rows = t * min(k, nl)
             rows_padded = gmm.num_tiles(nl, bm, max_rows) * bm
             local = idx - cfg.first_expert
@@ -144,7 +202,8 @@ def expert_layer(cfg, p, x, *, active=None, impl: str = "kernel",
             # results are never read)
             src = jnp.zeros((rows_padded,), jnp.int32).at[dest].set(
                 jnp.arange(t * k, dtype=jnp.int32) // k, mode="drop")
-            xs = jnp.take(x, src, axis=0)
+            taken = jnp.minimum(dest, rows_padded - 1)           # [t*k]
+            xs = _take_rows(x, src, taken.reshape(t, k), here)
         with _prof.scope("moe_experts"):
             ex = p["experts"]
             kw = dict(block_m=bm, impl=impl, interpret=interpret)
@@ -154,8 +213,11 @@ def expert_layer(cfg, p, x, *, active=None, impl: str = "kernel",
             act = (jax.nn.silu(gu[:, :im]) * gu[:, im:]).astype(x.dtype)
             ys = gmm.grouped_matmul(act, ex["down"], tile_group, used, **kw)
         with _prof.scope("moe_combine"):
-            rows = jnp.take(ys, jnp.minimum(dest, rows_padded - 1),
-                            axis=0).reshape(t, k, h)
+            # the assignment each padded row holds, for the way back
+            held = jnp.full((rows_padded,), t * k, jnp.int32).at[dest].set(
+                jnp.arange(t * k, dtype=jnp.int32), mode="drop")
+            rows = _take_rows(ys, taken, jnp.minimum(held, t * k - 1)[:, None],
+                              (held < t * k)[:, None]).reshape(t, k, h)
             # an absent expert's row index points at a row nobody wrote
             rows = jnp.where(here[:, :, None], rows.astype(jnp.float32), 0.0)
             y = jnp.einsum("tk,tkh->th", jnp.where(here, w, 0.0), rows)

@@ -247,6 +247,98 @@ def test_grouped_matmul_uneven_and_empty_groups(impl, counts):
         assert all(tg[t] == e for t in range(s // bm, (s + c + bm - 1) // bm))
 
 
+@pytest.mark.parametrize("counts", [[5, 0, 17, 1, 0, 9], [0, 0, 0, 0, 0, 3],
+                                    [0, 0, 0, 0, 0, 0], [8, 8, 8, 8, 8, 8],
+                                    [1, 1, 1, 1, 1, 1]])
+def test_grouped_matmul_differentiates_like_the_einsum(counts):
+    """The kernel path's ``custom_vjp`` (dx: the forward's kernel against
+    the transposed weight block; dw: a kernel of its own) against
+    ``jax.grad`` of the ``impl="reference"`` einsum, for uneven, empty and
+    single-row groups. The cotangent is zero outside the rows that hold a
+    token (what the expert layer's gathers hand back); dx is compared in the
+    used tiles (past them it is undefined, as the forward's rows are)."""
+    bm, k, n, g = 8, 128, 256, len(counts)
+    max_rows = 48
+    starts, tile_group, used = gmm.tile_layout(
+        jnp.asarray(counts, jnp.int32), bm, max_rows)
+    rows = gmm.num_tiles(g, bm, max_rows) * bm
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(rows, k), jnp.float32)
+    w = jnp.asarray(rng.randn(g, k, n) * 0.1, jnp.float32)
+    real = np.zeros((rows, 1), np.float32)
+    for c, s in zip(counts, np.asarray(starts)):
+        real[s:s + c] = 1.0
+    ct = jnp.asarray(rng.randn(rows, n).astype(np.float32) * real)
+
+    def grads(impl):
+        def f(x, w):
+            y = gmm.grouped_matmul(x, w, tile_group, used, block_m=bm,
+                                   impl=impl, interpret=True)
+            return jnp.sum(jnp.where(real > 0, y, 0.0) * ct)
+        return jax.grad(f, (0, 1))(x, w)
+
+    (dx, dw), (dx_ref, dw_ref) = grads("kernel"), grads("reference")
+    live = np.arange(rows) // bm < int(used)
+    np.testing.assert_allclose(np.asarray(dx)[live], np.asarray(dx_ref)[live],
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_ref),
+                               rtol=1e-4, atol=1e-4)
+    for e, c in enumerate(counts):      # a group without a row: exact zeros
+        if c == 0:
+            assert not np.asarray(dw[e]).any()
+
+
+def test_tiles_past_the_used_ones_give_dw_nothing():
+    """Rows that lie in tiles past ``tiles_used`` hold whatever the caller
+    left there (here: large numbers, in ``x`` and in the cotangent): the dw
+    kernel skips those tiles as the forward does."""
+    bm, k, n = 8, 128, 128
+    counts = jnp.asarray([3, 9], jnp.int32)
+    starts, tile_group, used = gmm.tile_layout(counts, bm, 40)
+    rows = tile_group.shape[0] * bm
+    live = (np.arange(rows) // bm < int(used))[:, None]
+    rng = np.random.RandomState(1)
+    x = np.where(live, rng.randn(rows, k), 1e6).astype(np.float32)
+    ct = np.where(live, rng.randn(rows, n), 1e6).astype(np.float32)
+    w = jnp.asarray(rng.randn(2, k, n), jnp.float32)
+
+    def dw_of(x, ct):
+        return jax.grad(lambda w: jnp.sum(gmm.grouped_matmul(
+            jnp.asarray(x), w, tile_group, used, block_m=bm,
+            interpret=True) * jnp.asarray(ct)))(w)
+
+    np.testing.assert_array_equal(
+        np.asarray(dw_of(x, ct)),
+        np.asarray(dw_of(np.where(live, x, 0.0), np.where(live, ct, 0.0))))
+
+
+def test_a_forward_that_is_not_differentiated_traces_the_forward_kernel_alone(
+        monkeypatch):
+    """The ``custom_vjp`` costs a serving program nothing: no backward
+    kernel is traced where nobody takes a derivative."""
+    seen = []
+    real = gmm.pl.pallas_call
+
+    def spy(kernel, **kw):
+        seen.append(getattr(kernel, "func", kernel).__name__)
+        return real(kernel, **kw)
+
+    monkeypatch.setattr(gmm.pl, "pallas_call", spy)
+    starts, tile_group, used = gmm.tile_layout(
+        jnp.asarray([3, 9], jnp.int32), 8, 40)
+    x = jnp.zeros((tile_group.shape[0] * 8, 128), jnp.float32)
+    w = jnp.zeros((2, 128, 128), jnp.float32)
+
+    def f(x, w):
+        return gmm.grouped_matmul(x, w, tile_group, used, block_m=8,
+                                  interpret=True)
+
+    jax.eval_shape(f, x, w)
+    assert seen == ["_kernel"]
+    jax.eval_shape(jax.grad(lambda x, w: jnp.sum(f(x, w)), (0, 1)), x, w)
+    assert seen == ["_kernel", "_kernel", "_kernel", "_dw_kernel"]
+
+
 # -- the expert layer -------------------------------------------------------------------
 
 def _layer_inputs(seed=1, t=24):

@@ -274,3 +274,206 @@ def test_layers_share_one_trace_of_each_kernel(monkeypatch, remat):
     hlo = lowered.compile().as_text()
     assert "apx:flash_attention_fwd" in hlo
     assert "apx:flash_attention_bwd" in hlo
+
+
+# -- a sliding window and grouped key/value heads: the banded grids -----------------
+
+def _band_reference(q, k, v, window):
+    """``mha_reference`` with the band as a bias and K/V repeated a group."""
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(a, group, axis=1) for a in (k, v))
+    bias = None
+    if window is not None:
+        sq, sk = q.shape[2], k.shape[2]
+        i = jnp.arange(sq)[:, None] + (sk - sq)
+        bias = jnp.where(jnp.arange(sk)[None, :] > i - window, 0.0,
+                         fa._NEG_INF)[None, None]
+    return fa.mha_reference(q, k, v, causal=True, bias=bias)
+
+
+def _band_operands(h, hk, sq, sk, d=32, seed=0):
+    rng = np.random.RandomState(seed)
+    return [jnp.asarray(rng.randn(1, n, s, d), F32)
+            for n, s in ((h, sq), (hk, sk), (hk, sk), (h, sq))]
+
+
+# (query heads, key/value heads, sq, sk, window, block): the window under,
+# at and over a block, whole blocks below the band, grouped heads with and
+# without a window, a length no block divides (padding installs segments)
+BAND = {
+    "window-under-a-block": (2, 2, 256, 256, 48, 64),
+    "window-is-a-block": (2, 2, 256, 256, 64, 64),
+    "window-over-a-block": (2, 2, 256, 256, 100, 64),
+    "window-gqa": (4, 2, 256, 256, 48, 64),
+    "window-gqa-one-kv-head": (4, 1, 256, 256, 96, 128),
+    "gqa-no-window": (4, 2, 256, 256, None, 64),
+    "window-padded-length": (2, 1, 200, 200, 70, 64),
+    "window-sq-under-sk": (2, 2, 128, 256, 80, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BAND))
+def test_window_and_grouped_heads_match_the_reference(case):
+    """Forward and backward of the banded grids against ``mha_reference``
+    with the band as a bias; dK and dV of a key/value head equal the
+    repeat-K/V formulation's, summed over the group (``jax.grad`` through
+    ``jnp.repeat`` sums them)."""
+    h, hk, sq, sk, window, blk = BAND[case]
+    q, k, v, do = _band_operands(h, hk, sq, sk)
+
+    def mine(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=window,
+                                  block_q=blk, block_k=blk, interpret=True)
+
+    np.testing.assert_allclose(
+        np.asarray(mine(q, k, v)),
+        np.asarray(_band_reference(q, k, v, window)), rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda *a: jnp.sum(mine(*a) * do), (0, 1, 2))(q, k, v)
+    want = jax.grad(lambda *a: jnp.sum(_band_reference(*a, window) * do),
+                    (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_grouped_heads_without_a_causal_mask_walk_every_block():
+    q, k, v, do = _band_operands(4, 2, 128, 256)
+
+    def mine(q, k, v):
+        return fa.flash_attention(q, k, v, block_q=64, block_k=64,
+                                  interpret=True)
+
+    def want(q, k, v):
+        return fa.mha_reference(q, *(jnp.repeat(a, 2, axis=1)
+                                     for a in (k, v)))
+
+    np.testing.assert_allclose(np.asarray(mine(q, k, v)),
+                               np.asarray(want(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    for g, w in zip(
+            jax.grad(lambda *a: jnp.sum(mine(*a) * do), (0, 1, 2))(q, k, v),
+            jax.grad(lambda *a: jnp.sum(want(*a) * do), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_a_window_over_the_sequence_is_plain_causal_bit_for_bit():
+    q, k, v, do = _band_operands(2, 2, 256, 256)
+
+    def run(**kw):
+        f = lambda *a: fa.flash_attention(     # noqa: E731
+            *a, causal=True, block_q=128, block_k=128, interpret=True, **kw)
+        return (f(q, k, v),) + jax.grad(
+            lambda *a: jnp.sum(f(*a) * do), (0, 1, 2))(q, k, v)
+
+    for a, b in zip(run(window=256), run()):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_band_skips_blocks_and_counts_what_it_multiplies(monkeypatch):
+    """s = 512 in 64-blocks under a window of 100: a q block sees three k
+    blocks at most, so the grids' inner extent is 3 (of 8), the grouped
+    dk/dv grid walks its two query heads in one (kv head, k block) program
+    sequence, and the counters say ``attention=window``."""
+    calls = _band_calls(monkeypatch, window=100)
+    assert calls == [("_fwd_kernel", (1, 4, 8, 3)),
+                     ("_dkdv_kernel", (1, 2, 8, 2 * 3)),
+                     ("_dq_kernel", (1, 4, 8, 3))]
+    calls = _band_calls(monkeypatch, window=None)     # grouped, no window
+    assert calls == [("_fwd_kernel", (1, 4, 8, 8)),
+                     ("_dkdv_kernel", (1, 2, 8, 2 * 8)),
+                     ("_dq_kernel", (1, 4, 8, 8))]
+
+    rec = monitor.Recorder(name="t", traced_hooks=False)
+    monitor.attach(rec)
+    try:
+        _band_calls(monkeypatch, window=100)
+    finally:
+        monitor.detach()
+    events = [e for e in rec.records() if e["kind"] == "counter"
+              and e["name"].startswith("flash/tiles")]
+    assert {e["attention"] for e in events} == {"window"}
+    by = {(e["name"], e["direction"]): e["value"] for e in events}
+    # live 64-blocks: 1 + 2 + 6 x 3 = 21 of 64, four heads, in 128-tiles
+    assert by["flash/tiles_computed", "fwd"] == 4 * 21 / 4
+    assert by["flash/tiles_square", "fwd"] == 4 * 64 / 4
+    assert by["flash/tiles_computed", "bwd"] == 4 * 21 / 4
+
+
+def _band_calls(monkeypatch, window):
+    seen = []
+    real = fa.pl.pallas_call
+
+    def spy(kernel, **kw):
+        seen.append((kernel.func.__name__, kw["grid"]))
+        return real(kernel, **kw)
+
+    monkeypatch.setattr(fa.pl, "pallas_call", spy)
+    fa._flash_fwd_impl.clear_cache()
+    fa._flash_bwd_impl.clear_cache()
+    q = jnp.zeros((1, 4, 512, 32), F32)
+    k = jnp.zeros((1, 2, 512, 32), F32)
+    jax.eval_shape(jax.grad(lambda q, k: jnp.sum(fa.flash_attention(
+        q, k, k, causal=True, window=window, block_q=64, block_k=64,
+        interpret=True))), q, k)
+    fa._flash_fwd_impl.clear_cache()
+    fa._flash_bwd_impl.clear_cache()
+    monkeypatch.setattr(fa.pl, "pallas_call", real)
+    return seen
+
+
+@pytest.mark.parametrize("bad", [
+    dict(h=3, hk=2, kw=dict(causal=True)),              # 3 is no multiple of 2
+    dict(h=2, hk=2, kw=dict(causal=False, window=8)),   # a window is causal
+    dict(h=2, hk=2, kw=dict(causal=True, window=0)),
+])
+def test_flash_attention_refuses_what_it_cannot_mean(bad):
+    q = jnp.zeros((1, bad["h"], 64, 32), F32)
+    k = jnp.zeros((1, bad["hk"], 64, 32), F32)
+    with pytest.raises(ValueError):
+        fa.flash_attention(q, k, k, interpret=True, **bad["kw"])
+
+
+def test_window_and_full_layers_each_trace_their_kernels_once(monkeypatch):
+    """A model of six window layers and two full ones enters the forward
+    kernel's body twice a kind (its blocks are rematerialised: once more
+    where the derivative is taken, as the GPT case above), not eight times,
+    and each of the two backward kernels once a kind: ``window`` is a static
+    argument of the jitted kernel calls, the layer count is not."""
+    from apex_tpu.models import mellum as ml
+
+    entered = {"_fwd_kernel": 0, "_dkdv_kernel": 0, "_dq_kernel": 0}
+    for name in entered:
+        def counting(*refs, _name=name, _body=getattr(fa, name), **kw):
+            entered[_name] += 1
+            return _body(*refs, **kw)
+        monkeypatch.setattr(fa, name, counting)
+    fa._flash_fwd_impl.clear_cache()
+    fa._flash_bwd_impl.clear_cache()
+    cfg = ml.MellumConfig(
+        vocab_size=128, hidden_size=64, num_heads=4, num_kv_heads=2,
+        head_dim=32, moe_intermediate_size=64, n_routed_experts=4,
+        num_experts_per_tok=2, sliding_window=48,
+        layer_types=(ml.SLIDING,) * 3 + (ml.FULL,) + (ml.SLIDING,) * 3
+        + (ml.FULL,), dtype=F32)
+    params = jax.eval_shape(functools.partial(ml.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    ids = jnp.zeros((1, 128), jnp.int32)
+    lowered = jax.jit(jax.grad(lambda p: ml.loss(
+        cfg, p, ids, ids, impl="reference", interpret=True)[0])).lower(params)
+    fa._flash_fwd_impl.clear_cache()
+    fa._flash_bwd_impl.clear_cache()
+    assert entered == {"_fwd_kernel": 4, "_dkdv_kernel": 2, "_dq_kernel": 2}
+    text = lowered.as_text()
+    # lowered functions a kind: the forward's and the rematerialised one's,
+    # and one backward; eight layers call them
+    assert text.count("func.func private @_flash_fwd_impl") == 4
+    assert text.count("func.func private @_flash_bwd_impl") == 2
+    assert text.count("call @_flash_fwd_impl") == 16
+    assert text.count("call @_flash_bwd_impl") == 8
+    hlo = lowered.compile().as_text()
+    for name in ("apx:flash_attention_window_fwd", "apx:flash_attention_fwd",
+                 "apx:flash_attention_window_bwd", "apx:flash_attention_bwd",
+                 "apx:attn_window", "apx:attn_full"):
+        assert name in hlo, name

@@ -236,6 +236,40 @@ def _grouped_matmul(block_m, rows):
     return build
 
 
+def _flash_banded(window):
+    """Cell 8's attention: 32 query heads over 4 key/value heads of 128 at
+    8,192 tokens, forward and the two-kernel backward over the banded
+    grids, under a window of 1,024 or without one."""
+    def build(chip):
+        q = _sds(chip, (2, 32, 8192, 128), BF16)
+        k = _sds(chip, (2, 4, 8192, 128), BF16)
+        fn = _sum_grad(functools.partial(
+            flash_attention, causal=True, window=window, scale=128 ** -0.5,
+            interpret=False), (0, 1, 2))
+        return fn, (q, k, k)
+    return build
+
+
+def _grouped_matmul_train(chip):
+    """Cell 8's expert layer: 16 held experts of 2,304 x 2 x 896 over the
+    worst-case rows of 16,384 tokens x top 8, tiles of 256: forward, dx and
+    dw of both grouped matmuls."""
+    from apex_tpu.ops import grouped_matmul as gmm
+    from apex_tpu.transformer import moe_dropless
+    bm = moe_dropless._block_m(16384 * 8)
+    tiles = gmm.num_tiles(16, bm, 16384 * 8)
+
+    def fn(x, gate_up, down, tile_group, used):
+        h = gmm.grouped_matmul(x, gate_up, tile_group, used, block_m=bm,
+                               interpret=False)
+        return gmm.grouped_matmul(h[:, :896], down, tile_group, used,
+                                  block_m=bm, interpret=False)
+    return _sum_grad(fn, (0, 1, 2)), (
+        _sds(chip, (tiles * bm, 2304), BF16),
+        _sds(chip, (16, 2304, 1792), BF16), _sds(chip, (16, 896, 2304), BF16),
+        _sds(chip, (tiles,), I32), _sds(chip, (), I32))
+
+
 CASES = {
     "flash_fwd_bwd_b8_s1024": _flash(1024, 8),
     "flash_fwd_bwd_b2_s4096": _flash(4096, 2),
@@ -256,6 +290,9 @@ CASES = {
     "mla_decode_b256_h64_w640": _mla_decode,
     "moe_grouped_matmul_decode_16x7168x4096": _grouped_matmul(32, 2048),
     "moe_grouped_matmul_prefill_16x7168x4096": _grouped_matmul(128, 8192),
+    "flash_window1024_gqa_fwd_bwd_b2_s8192": _flash_banded(1024),
+    "flash_gqa_fwd_bwd_b2_s8192": _flash_banded(None),
+    "moe_grouped_matmul_train_16x2304x1792": _grouped_matmul_train,
 }
 
 
@@ -345,6 +382,15 @@ NAMED = {
     "mla_decode": (_mla_decode, (r"%apx_mla_decode_attention[.\d]* = ",)),
     "moe_grouped_matmul": (_grouped_matmul(32, 2048),
                            (r"%apx_moe_grouped_matmul[.\d]* = ",)),
+    "flash_window": (_flash_banded(1024),
+                     (r"%apx_flash_attention_window_fwd[.\d]* = ",
+                      r"%apx_flash_attention_window_bwd[.\d]* = ")),
+    # a bare ``jax.grad`` wraps the scope (``transpose_jvp_apx_..._``); under
+    # a rematerialised block, as cell 8's step, the names are the scopes'
+    # own (``test_mellum_train_step_compiles_for_v5e_under_15_gb`` pins them)
+    "moe_grouped_matmul_train": (
+        _grouped_matmul_train, (r"%\w*apx_moe_grouped_matmul_*[.\d]* = ",
+                                r"%\w*apx_moe_grouped_matmul_dw_*[.\d]* = ")),
 }
 
 
@@ -395,6 +441,72 @@ def test_layers_call_one_lowered_flash_kernel_a_direction(chip, no_interpret):
     for direction in ("fwd", "bwd"):
         assert len(re.findall(
             rf"%apx_flash_attention_{direction}[.\d]* = ", hlo)) == 4
+
+
+def test_mellum_train_step_compiles_for_v5e_under_15_gb(chip, no_interpret):
+    """Cell 8's step (``benchmarks/configs/mellum2-12b-ep4-l4.json`` at its
+    published widths, 2 x 8,192 tokens, ``amp`` O2 + FusedAdam through
+    ``make_train_step(has_aux=True)``, per-block recomputation) lowers for
+    the described chip on shapes only and fits: the state is 14 B a
+    parameter (595.2M: 8.33 GB), the step under 15.0 GB; the window layers'
+    kernels and the full layer's carry their own names."""
+    import json
+    import os
+    from apex_tpu import amp
+    from apex_tpu.models import mellum as ml
+    from apex_tpu.optimizers import FusedAdam
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "mellum2-12b-ep4-l4.json")) as f:
+        c = json.load(f)
+    with open(os.path.join(root, "benchmarks", "traffic",
+                           "train-s8192-ep4share.json")) as f:
+        t = json.load(f)
+    yarn = c["rope_parameters"]["full_attention"]
+    cfg = ml.MellumConfig(
+        vocab_size=c["vocab_size"], hidden_size=c["hidden_size"],
+        num_heads=c["num_attention_heads"],
+        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+        moe_intermediate_size=c["moe_intermediate_size"],
+        n_routed_experts=c["published"]["num_experts"],
+        num_experts_per_tok=c["num_experts_per_tok"],
+        layer_types=tuple(c["layer_types"]),
+        sliding_window=c["sliding_window"],
+        first_expert=c["held"]["first_expert"],
+        n_local_experts=c["held"]["local_experts"],
+        rope_theta=float(yarn["rope_theta"]), rope_scaling=tuple(sorted(
+            (k, v) for k, v in yarn.items()
+            if k not in ("rope_type", "rope_theta", "attention_factor"))))
+    amp_model, opt = amp.initialize(
+        lambda p, i: ml.forward(cfg, p, i)[0], FusedAdam(lr=3e-4),
+        opt_level="O2", verbosity=0)
+
+    def init_state(key):
+        params = amp_model.cast_params(ml.init_params(cfg, key))
+        return params, opt.init(params), \
+            opt._amp_stash.loss_scalers[0].state
+
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+    n_params = sum(x.size for x in jax.tree.leaves(state[0]))
+    assert n_params == 595_153_152
+    assert sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves(state)) < 14.01 * n_params
+    step = amp.make_train_step(
+        lambda p, i, l: ml.loss(cfg, p, i, l), opt, has_aux=True)
+    ids = _sds(chip, (t["batch"], t["seq"]), I32)
+    compiled = step._jitted.lower(False, *_place(chip, state), ids,
+                                  ids).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.0e9
+    hlo = compiled.as_text()
+    for name, n in (("apx_flash_attention_window_fwd", 6),
+                    ("apx_flash_attention_window_bwd", 6),
+                    ("apx_flash_attention_fwd", 2),
+                    ("apx_flash_attention_bwd", 2),
+                    ("apx_moe_grouped_matmul_dw", 8),
+                    ("apx_moe_grouped_matmul", 24)):
+        assert len(re.findall(rf"%{name}[.\d]* = ", hlo)) == n, name
 
 
 # ---------------------------------------------------------------------------
