@@ -57,7 +57,8 @@ from apex_tpu.transformer import moe_dropless
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 #: what a layer's expert layer counts, in the loss's ``aux`` and as counters
-MOE_COUNTS = ("assignments_local", "expert_load_max", "experts_touched")
+MOE_COUNTS = ("assignments_local", "expert_load_max", "experts_touched",
+              "rows_moved")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -235,12 +236,14 @@ def loss(cfg: MellumConfig, params, ids, labels, **kw):
 
 def record_step(aux) -> None:
     """A step's expert-layer counts as counters on the attached recorder:
-    ``moe/assignments_local``, ``moe/expert_load_max``,
-    ``moe/experts_touched``, one event a layer (``layer=``). For whoever
-    owns the loop, on an ``aux`` it has FETCHED (this reads the values)."""
+    ``moe/assignments_local`` (first: a reader opens a step at layer 0's),
+    ``moe/expert_load_max``, ``moe/experts_touched``, ``moe/rows_moved``
+    (those of :data:`MOE_COUNTS` that ``aux`` holds), one event a layer
+    (``layer=``). For whoever owns the loop, on an ``aux`` it has FETCHED
+    (this reads the values)."""
     import numpy as np
-    counts = {k: np.asarray(v) for k, v in aux["moe"].items()}
+    counts = {k: np.asarray(aux["moe"][k]) for k in MOE_COUNTS
+              if k in aux["moe"]}
     for layer in range(len(counts[MOE_COUNTS[0]])):
-        for name in MOE_COUNTS:
-            _mon.counter(f"moe/{name}", int(counts[name][layer]),
-                         layer=layer)
+        for name, per_layer in counts.items():
+            _mon.counter(f"moe/{name}", int(per_layer[layer]), layer=layer)

@@ -64,7 +64,7 @@ IMPLS = ("kernel", "reference")
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
-def _resolve_interpret(interpret):
+def resolve_interpret(interpret):
     # the one rule of the Pallas ops (looked up at call time: the compile
     # tests steer it there)
     from apex_tpu.ops.flash_attention import _resolve_interpret as rule
@@ -165,7 +165,7 @@ def grouped_matmul(x, w, tile_group, tiles_used, *, block_m: int,
                           ).astype(x.dtype)
     used = jnp.reshape(tiles_used, (1,)).astype(jnp.int32)
     return _grouped_matmul(x, w, tile_group, used, block_m,
-                           _resolve_interpret(interpret))
+                           resolve_interpret(interpret))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -189,7 +189,7 @@ def _vjp_bwd(block_m, interpret, res, dy):
 _grouped_matmul.defvjp(_vjp_fwd, _vjp_bwd)
 
 
-def _last_used(i, used):
+def last_used(i, used):
     """Tile ``i``, or past the used tiles the last used one: a step there
     stays on the blocks it had, and moves nothing."""
     return jnp.minimum(i, jnp.maximum(used[0] - 1, 0))
@@ -208,7 +208,7 @@ def _call(x, w, tile_group, used, block_m, interpret, transpose_w=False):
         bo = _block_n(k, n)                 # a block of w's columns, whole K
         n_out, w_block = n, (1, k, bo)
     last = n_out // bo - 1
-    step = _last_used
+    step = last_used
 
     def col(i, j, used):
         return jnp.where(i < used[0], j, last)
@@ -246,7 +246,7 @@ def _call_dw(x, dy, w, tile_group, used, block_m, interpret):
     g, k, n = w.shape
     tiles = tile_group.shape[0]
     bn = _block_n(k, n, itemsize=4)         # the float32 accumulator's
-    step = _last_used
+    step = last_used
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -161,13 +161,16 @@ def _pad_lanes(x, width):
 
 def _aux(stats):
     """The engine's ``aux`` from the expert layers' stats: each row's
-    chosen slots ``[t, layers, k]`` and the round's counters."""
+    chosen slots ``[t, layers, k]`` and the round's counters. Not among
+    them: ``rows_moved``, which under the training size is the padded
+    buffer's rows, a constant of the program's shape and no count of a
+    round: the serve programs keep the results they had."""
     if not stats:
         return {}
     return {"rows": {"moe_idx": jnp.stack([s["idx"] for s in stats],
                                           axis=-2)},
             "round": {k: jnp.stack([s[k] for s in stats])
-                      for k in stats[0] if k != "idx"}}
+                      for k in stats[0] if k not in ("idx", "rows_moved")}}
 
 
 def decode_forward(model: LatentServed, ccfg: cache_mod.CacheConfig,

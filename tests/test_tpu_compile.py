@@ -270,6 +270,36 @@ def _grouped_matmul_train(chip):
         _sds(chip, (tiles,), I32), _sds(chip, (), I32))
 
 
+def _moe_rows_sorted_train(chip):
+    """Cell 8's dispatch and, with the scale and the row dot, its combine's
+    cotangent: 16,384 tokens of 2,304 into the 135,168 padded rows."""
+    from apex_tpu.ops import moe_rows
+    rows = 528 * 256
+
+    def fn(x, src, used, scale, ys):
+        xs = moe_rows.sorted_rows(x, src, used, block_m=256, interpret=False)
+        d_ys, dot = moe_rows.sorted_rows(x, src, used, block_m=256,
+                                         scale=scale, dot_with=ys,
+                                         interpret=False)
+        return xs, d_ys, dot
+    return fn, (_sds(chip, (16384, 2304), BF16), _sds(chip, (rows,), I32),
+                _sds(chip, (), I32), _sds(chip, (rows,), F32),
+                _sds(chip, (rows, 2304), BF16))
+
+
+def _moe_rows_tokens_train(chip):
+    """Cell 8's combine (and its dispatch's cotangent): the live rows of
+    135,168 summed into 16,384 tokens x top 8."""
+    from apex_tpu.ops import moe_rows
+
+    def fn(ys, idx, wm, used):
+        return moe_rows.token_rows(ys, idx, wm, used, block_m=256,
+                                   interpret=False)
+    return fn, (_sds(chip, (528 * 256, 2304), BF16),
+                _sds(chip, (16384, 8), I32), _sds(chip, (16384, 8), F32),
+                _sds(chip, (), I32))
+
+
 CASES = {
     "flash_fwd_bwd_b8_s1024": _flash(1024, 8),
     "flash_fwd_bwd_b2_s4096": _flash(4096, 2),
@@ -293,6 +323,8 @@ CASES = {
     "flash_window1024_gqa_fwd_bwd_b2_s8192": _flash_banded(1024),
     "flash_gqa_fwd_bwd_b2_s8192": _flash_banded(None),
     "moe_grouped_matmul_train_16x2304x1792": _grouped_matmul_train,
+    "moe_rows_sorted_train_16384x2304": _moe_rows_sorted_train,
+    "moe_rows_tokens_train_16384x8x2304": _moe_rows_tokens_train,
 }
 
 
@@ -391,6 +423,13 @@ NAMED = {
     "moe_grouped_matmul_train": (
         _grouped_matmul_train, (r"%\w*apx_moe_grouped_matmul_*[.\d]* = ",
                                 r"%\w*apx_moe_grouped_matmul_dw_*[.\d]* = ")),
+    # names of their own: ``moe_grouped_matmul_train_roofline`` reads
+    # ``^apx_moe_grouped_matmul`` and has to keep reading only the matmuls
+    "moe_rows_sorted": (_moe_rows_sorted_train,
+                        (r"%apx_moe_rows_sorted[.\d]* = ",)),
+    "moe_rows_tokens": (_moe_rows_tokens_train,
+                        (r"%apx_moe_rows_tokens[.\d]* = ",
+                         r"%apx_moe_rows_open[.\d]* = ")),
 }
 
 
@@ -497,16 +536,29 @@ def test_mellum_train_step_compiles_for_v5e_under_15_gb(chip, no_interpret):
     compiled = step._jitted.lower(False, *_place(chip, state), ids,
                                   ids).compile()
     mem = compiled.memory_analysis()
-    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
-            + mem.output_size_in_bytes - mem.alias_size_in_bytes) < 15.0e9
+    held = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    # 13.703 GB with the expert layer's rows moved by ``ops/moe_rows``
+    # (14.217 through ``jnp.take``: a ``[131072, 2304]`` float32 temporary
+    # coming back is 1.2 GB)
+    assert held < 13.9e9
     hlo = compiled.as_text()
     for name, n in (("apx_flash_attention_window_fwd", 6),
                     ("apx_flash_attention_window_bwd", 6),
                     ("apx_flash_attention_fwd", 2),
                     ("apx_flash_attention_bwd", 2),
                     ("apx_moe_grouped_matmul_dw", 8),
-                    ("apx_moe_grouped_matmul", 24)):
+                    ("apx_moe_grouped_matmul", 24),
+                    # dispatch forward and recomputed, the combine's
+                    # cotangent; combine forward (its recomputation is dead
+                    # code), the dispatch's cotangent; each of the twenty
+                    # behind the pass that opens its source's rows
+                    ("apx_moe_rows_sorted", 12),
+                    ("apx_moe_rows_tokens", 8),
+                    ("apx_moe_rows_open", 20)):
         assert len(re.findall(rf"%{name}[.\d]* = ", hlo)) == n, name
+    # no gather over the worst-case row buffers is left
+    assert not re.search(r"fusion[.\d]* = bf16\[13(1072|5168),2304\]", hlo)
 
 
 # ---------------------------------------------------------------------------
