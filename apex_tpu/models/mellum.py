@@ -21,6 +21,15 @@ repeated); the expert layer is :func:`apex_tpu.transformer.moe_dropless.
 expert_layer`; the loss is the fused LM-head cross entropy over the rows of
 the vocabulary held here.
 
+**What a block keeps.** Every block runs under ``jax.checkpoint``: the
+backward holds a block's input and runs the block again (the norms, the
+output projection, the whole expert layer with its worst-case row buffers),
+EXCEPT what the flash kernels' backward reads, kept by name: the kernel's
+output and log-sum-exp (``FLASH_OUT``, ``FLASH_LSE`` of
+``ops/flash_attention.py``) and its three operands, the rotated ``q`` and
+``k`` and ``v`` (:data:`QKV`). The forward kernel, the three projections
+and the rotation run once a layer a step.
+
 **A chip's share**, as in ``models/deepseek.py``: ``n_local_experts`` of the
 ``n_routed_experts`` from ``first_expert`` (the router keeps its published
 width; what the absent experts would add is left out and the partial result
@@ -47,15 +56,21 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from apex_tpu.models import deepseek as _ds
 from apex_tpu.monitor import hooks as _mon
 from apex_tpu.monitor import profile as _prof
-from apex_tpu.ops.flash_attention import flash_attention
+from apex_tpu.ops.flash_attention import (FLASH_LSE, FLASH_OUT,
+                                          flash_attention)
 from apex_tpu.ops.lm_head_ce import fused_lm_head_cross_entropy
 from apex_tpu.transformer import moe_dropless
 
 SLIDING, FULL = "sliding_attention", "full_attention"
+#: the name under which ``attention`` tags a layer's rotated queries and keys
+#: and its values (``jax.ad_checkpoint.checkpoint_name``), the flash kernel's
+#: three operands, for the recomputed blocks' policy
+QKV = "mellum_attention_qkv"
 #: what a layer's expert layer counts, in the loss's ``aux`` and as counters
 MOE_COUNTS = ("assignments_local", "expert_load_max", "experts_touched",
               "rows_moved")
@@ -176,15 +191,24 @@ def attention(cfg, p, x, kind: str, *, interpret=None):
             return jnp.dot(x, w).reshape(b, s, count, d).transpose(0, 2, 1, 3)
 
         pos = jnp.arange(s)
-        q = rope(heads(p["q"], n), pos, cfg, kind)
-        k = rope(heads(p["k"], m), pos, cfg, kind)
-        o = flash_attention(q, k, heads(p["v"], m), causal=True,
-                            window=window, scale=d ** -0.5,
-                            interpret=interpret)
+        q, k, v = checkpoint_name(
+            (rope(heads(p["q"], n), pos, cfg, kind),
+             rope(heads(p["k"], m), pos, cfg, kind), heads(p["v"], m)), QKV)
+        o = flash_attention(q, k, v, causal=True, window=window,
+                            scale=d ** -0.5, interpret=interpret)
         return jnp.dot(o.transpose(0, 2, 1, 3).reshape(b, s, n * d), p["o"])
 
 
 # -- the model ---------------------------------------------------------------
+
+@functools.lru_cache(maxsize=None)
+def _kept():
+    """What a recomputed block keeps. ONE object for every layer: JAX caches
+    a jitted call's split into kept and recomputed parts by the policy's
+    identity, so a policy a layer would lower the flash kernel once a layer."""
+    return jax.checkpoint_policies.save_only_these_names(FLASH_OUT, FLASH_LSE,
+                                                         QKV)
+
 
 def _block(cfg, kind, p, x, impl, interpret):
     b, s, h = x.shape
@@ -207,9 +231,13 @@ def hidden(cfg: MellumConfig, params, ids, *, impl: str = "kernel",
     stats = []
     for i, kind in enumerate(cfg.layer_types):
         # the backward keeps a layer's input and runs the layer again: the
-        # expert layer's worst-case row buffers of four layers fit no chip
-        block = jax.checkpoint(functools.partial(
-            _block, cfg, kind, impl=impl, interpret=interpret))
+        # expert layer's worst-case row buffers of four layers fit no chip.
+        # It keeps the flash kernel's operands and its two results too: 304
+        # MB a layer at 2 x 8,192 tokens, which cost 4.7-9.8 ms of kernel
+        # and ~5 ms of projections and rotation a layer to rebuild
+        block = jax.checkpoint(
+            functools.partial(_block, cfg, kind, impl=impl,
+                              interpret=interpret), policy=_kept())
         x, st = block(params[f"layer_{i}"], x)
         stats.append(st)
     aux = {"moe": {k: jnp.stack([st[k] for st in stats])

@@ -62,6 +62,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -1406,6 +1407,12 @@ def _resolve_interpret(interpret):
     return jax.default_backend() != "tpu"
 
 
+#: the forward kernel's two results as ``jax.ad_checkpoint.checkpoint_name``
+#: tags them in the ``custom_vjp``'s forward rule (see :func:`flash_attention`)
+FLASH_OUT = "flash_attention_out"
+FLASH_LSE = "flash_attention_lse"
+
+
 def _fa_fwd(q, k, v, sid_q, sid_kv, bias, seed, causal, scale, dropout_rate,
             block_q, block_k, block_q_bwd, block_k_bwd, interpret,
             window=None):
@@ -1413,6 +1420,9 @@ def _fa_fwd(q, k, v, sid_q, sid_kv, bias, seed, causal, scale, dropout_rate,
     out, lse = _flash_fwd_impl(q, k, v, sid_q, sid_kv, bias, seed,
                                float(scale_v), causal, dropout_rate, block_q,
                                block_k, _resolve_interpret(interpret), window)
+    # outside the jitted call: a names policy sees them; identities otherwise
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, out, lse, sid_q, sid_kv, bias, seed)
 
 
@@ -1993,6 +2003,14 @@ def flash_attention(q, k, v, segment_ids_q=None, segment_ids_kv=None,
                     autotune: Optional[str] = None,
                     window: Optional[int] = None):
     """Fused attention. Returns [b, h, sq, d].
+
+    Under differentiation the forward kernel's output and its log-sum-exp
+    carry the names :data:`FLASH_OUT` (``"flash_attention_out"``) and
+    :data:`FLASH_LSE` (``"flash_attention_lse"``): a block under
+    ``jax.checkpoint`` that keeps these two
+    (``policy=jax.checkpoint_policies.save_only_these_names(FLASH_OUT,
+    FLASH_LSE)``) does not run the forward kernel again in its backward.
+    Without such a policy the names are identities and lower to nothing.
 
     ``k``, ``v`` ``[b, hk, sk, d]`` with ``hk`` = ``h`` or a divisor of it
     (grouped-query attention: query head ``i`` reads key/value head ``i //

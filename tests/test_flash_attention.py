@@ -472,3 +472,69 @@ def test_fmha_shim_does_not_trip_inherited_blocks_warning():
     with pytest.warns(UserWarning, match="govern the BACKWARD"):
         q, k, v = _qkv(sq=32, sk=32)
         flash_attention(q, k, v, block_q=16, block_k=16)
+
+
+# -- the forward's two results carry names (for a caller's jax.checkpoint) --------
+
+#: (query heads, key/value heads, window): plain causal, a sliding window,
+#: grouped key/value heads, both
+NAMED_CALLS = {"causal": (4, 4, None), "windowed": (4, 4, 24),
+               "grouped": (4, 2, None), "grouped_windowed": (4, 2, 24)}
+
+
+@pytest.mark.parametrize("case", list(NAMED_CALLS))
+def test_result_names_are_inert_without_a_policy(case):
+    """``_fa_fwd`` tags the kernel's output and log-sum-exp with
+    ``checkpoint_name``; where no ``jax.checkpoint`` policy names them the
+    call is what it was: outputs and gradients bit-equal to the two jitted
+    kernel calls made by hand, and one forward call in the gradient's
+    jaxpr."""
+    import importlib
+    from apex_tpu.lint.jaxpr_checks import iter_eqns
+    fa = importlib.import_module("apex_tpu.ops.flash_attention")
+    h, hk, window = NAMED_CALLS[case]
+    rng = np.random.RandomState(7)
+    q, do = (jnp.asarray(rng.randn(1, h, 64, 16), jnp.float32)
+             for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(1, hk, 64, 16), jnp.float32)
+            for _ in range(2))
+    blocks = dict(block_q=32, block_k=32, block_q_bwd=32, block_k_bwd=32)
+
+    def call(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               interpret=True, **blocks)
+
+    out, vjp = jax.vjp(call, q, k, v)
+    got = vjp(do)
+
+    scale, seed = 16 ** -0.5, jnp.zeros((1,), jnp.int32)
+    want_out, lse = fa._flash_fwd_impl(q, k, v, None, None, None, seed,
+                                       scale, True, 0.0, 32, 32, True, window)
+    want = fa._flash_bwd_impl(
+        (q, k, v, want_out, lse, None, None, None, seed), do, scale=scale,
+        causal=True, dropout_rate=0.0, block_q=32, block_k=32,
+        interpret=True, window=window)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want_out))
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(call(q, k, v) * do), (0, 1, 2)))(q, k, v)
+    names = [e.params["name"] for e in iter_eqns(jaxpr.jaxpr)
+             if e.primitive.name in ("pjit", "jit")]
+    assert names.count("_flash_fwd_impl") == 1
+    assert names.count("_flash_bwd_impl") == 1
+    tags = [e.params["name"] for e in iter_eqns(jaxpr.jaxpr)
+            if e.primitive.name == "name"]
+    assert sorted(tags) == sorted([fa.FLASH_OUT, fa.FLASH_LSE])
+
+
+def test_result_names_are_exported_and_documented():
+    import importlib
+    fa = importlib.import_module("apex_tpu.ops.flash_attention")
+    assert (fa.FLASH_OUT, fa.FLASH_LSE) == ("flash_attention_out",
+                                            "flash_attention_lse")
+    doc = flash_attention.__doc__
+    for word in ("FLASH_OUT", "FLASH_LSE", fa.FLASH_OUT, fa.FLASH_LSE,
+                 "save_only_these_names"):
+        assert word in doc, word

@@ -199,6 +199,83 @@ def test_bf16_model_is_within_a_bf16_tolerance(monkeypatch):
     assert err < 0.03, err
 
 
+# -- what a recomputed block keeps ----------------------------------------------------
+
+#: the layers of a case: two or more, one of them sliding
+KEPT_CASES = {"sliding_full": (ml.SLIDING, ml.FULL),
+              "sliding_sliding": (ml.SLIDING, ml.SLIDING),
+              "full_sliding_full": (ml.FULL, ml.SLIDING, ml.FULL)}
+
+
+def _kernel_calls(jaxpr):
+    """``{(jitted flash call, its window): count}`` in a jaxpr, and under
+    ``"dot_general"`` / ``"concatenate"`` its matmuls and the rotations'
+    joins of their two halves."""
+    from collections import Counter
+    from apex_tpu.lint.jaxpr_checks import iter_eqns
+    found = Counter()
+    for e in iter_eqns(jaxpr, skip_kernel_bodies=True):
+        if e.primitive.name in ("dot_general", "concatenate"):
+            found[e.primitive.name] += 1
+        if e.primitive.name in ("pjit", "jit") and \
+                e.params["name"].startswith("_flash_"):
+            windowed = any(
+                "flash_attention_window" in str(i.source_info.name_stack)
+                for i in iter_eqns(e.params["jaxpr"].jaxpr))
+            found[e.params["name"], windowed] += 1
+    return found
+
+
+@pytest.mark.parametrize("case", list(KEPT_CASES))
+def test_a_block_keeps_the_flash_kernels_operands_and_results(case,
+                                                              monkeypatch):
+    """``hidden`` names what its recomputed blocks keep: the gradient of
+    the loss calls the forward flash kernel ONCE a layer (twice under a
+    bare ``jax.checkpoint``: the test fails if the names stop being
+    honoured) and each backward kernel once a layer, it multiplies three
+    projections and rotates twice a layer less than the bare block's, and
+    the loss and every gradient leaf are bit-equal to the bare block's."""
+    _small_blocks(monkeypatch)
+    kinds = KEPT_CASES[case]
+    cfg = _cfg(n_local_experts=4, first_expert=2, layer_types=kinds)
+    params = ml.init_params(cfg, jax.random.PRNGKey(0))
+    ids = jax.random.randint(jax.random.PRNGKey(1), (1, 128), 0,
+                             cfg.vocab_size)
+    labels = jnp.roll(ids, -1, axis=1)
+
+    def run(p):
+        return ml.loss(cfg, p, ids, labels, interpret=True)[0]
+
+    def both():
+        return (_kernel_calls(jax.make_jaxpr(jax.grad(run))(params).jaxpr),
+                jax.value_and_grad(run)(params))
+
+    calls, (loss, grads) = both()
+    with monkeypatch.context() as m:
+        real = jax.checkpoint
+        m.setattr(jax, "checkpoint", lambda fn, **kw: real(fn))
+        bare_calls, (bare_loss, bare_grads) = both()
+
+    n_window = kinds.count(ml.SLIDING)
+    n_full = kinds.count(ml.FULL)
+    want = {("_flash_fwd_impl", True): n_window,
+            ("_flash_fwd_impl", False): n_full,
+            ("_flash_bwd_impl", True): n_window,
+            ("_flash_bwd_impl", False): n_full}
+    projections, rotations = (
+        bare_calls.pop(k) - calls.pop(k) for k in ("dot_general",
+                                                   "concatenate"))
+    assert (projections, rotations) == (3 * len(kinds), 2 * len(kinds))
+    assert calls == {k: n for k, n in want.items() if n}
+    assert bare_calls == {(name, w): n * (2 if "fwd" in name else 1)
+                          for (name, w), n in want.items() if n}
+    assert float(loss) == float(bare_loss)
+    for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree.leaves(bare_grads)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=str(path))
+
+
 # -- a chip's share ---------------------------------------------------------------
 
 def test_the_shares_add_up():
