@@ -18,7 +18,7 @@ Each detector encodes one of them:
   inputs (consts): every iteration reduces the same value, so the
   collective is hoistable and the program pays trip-count times the
   wire cost. The trip count in the message multiplies through nested
-  scans exactly like the ``monitor.profile`` analytic walk.
+  scans exactly like the ``monitor.attribution`` analytic walk.
 - **APXJ103 unbalanced ppermute ring** — a ring-decomposed gather or
   scatter (``parallel/overlap.py``'s unrolled collective-matmul hops)
   whose hop count is not a multiple of ``axis_size - 1``: one dropped or
@@ -245,7 +245,7 @@ def check_unreduced_outputs(closed, *, label: str = "<jaxpr>") -> list:
 def _walk_eqns(jaxpr, mult: int = 1, in_scan: bool = False):
     """Yield ``(eqn, ctx)`` for every equation reachable from ``jaxpr``;
     ``ctx`` is ``(trip_multiplier, in_scan_body, owner_jaxpr)``. Scan
-    bodies multiply the trip count through, the monitor.profile
+    bodies multiply the trip count through, the monitor.attribution
     convention."""
     for eqn in jaxpr.eqns:
         yield eqn, (mult, in_scan, jaxpr)

@@ -53,7 +53,7 @@ class BertConfig:
     sequence_parallel: bool = False
     # ``loss`` can fuse the tied LM-head matmul into the cross entropy
     # (``ops.lm_head_ce``; no [b, s, V] logits in HBM). Default False
-    # for BERT by measurement, root-caused r5 (docs/perf.md): the fused
+    # for BERT by measurement, root-caused in round 5: the fused
     # backward pays a 4th full n·V·h dot (logit-tile recompute) while
     # the [n, V] bf16 logits traffic it saves is smaller and largely
     # hidden by XLA's scheduler — standalone at BERT-base shape the

@@ -12,15 +12,15 @@ cross-host merge layer (``monitor.merge``: rank-tagged shards +
 a training-health :class:`Watchdog` (``monitor.health``: NaN/overflow-
 storm/divergence/plateau/starvation/straggler detection as typed
 ``health_event`` records), per-module cost attribution
-(``monitor.profile``: :func:`scope` tags + the analytic jaxpr
-attributor + measured wall-time sampling,
+(``monitor.profile``: :func:`scope` tags; ``monitor.attribution``: the
+analytic jaxpr attributor + measured wall-time sampling,
 ``python -m apex_tpu.monitor profile``), request-level span
 tracing + O(1)-memory log-scale latency histograms (``monitor.spans``:
 the serve SLO evidence layer — per-request queue-wait/prefill/decode
 traces with preempt/re-admit annotations, rendered as the ``serve``
 block of the report), a pull-based Prometheus text-exposition endpoint
 (``monitor.export``: lazily imported, ``python -m apex_tpu.monitor
-export``), MFU/goodput accounting (``monitor.profile.mfu`` over the
+export``), MFU/goodput accounting (``monitor.attribution.mfu`` over the
 analytic FLOPs walk + a per-device-kind peak table), the unified
 memory surface (``monitor.memory``: compiled-footprint attribution,
 the analytic high-water walk charged per ``apx:`` scope, the live
@@ -79,13 +79,12 @@ from apex_tpu.monitor.spans import LogHistogram  # noqa: F401
 # tests/test_layering.py). The tool side loads on first use: a process
 # that only trains or serves never pays for a module that reads dumps,
 # and never for http.server (tests/test_export.py).
-_LAZY_MODULES = ("export", "fleet", "flight", "health", "memory", "merge",
-                 "report", "slo", "timeline", "trace", "xprof")
+_LAZY_MODULES = ("attribution", "export", "flight", "health", "memory",
+                 "merge", "report", "timeline", "trace", "xprof")
 _LAZY_NAMES = {
     "Watchdog": "health", "MemorySampler": "memory",
     **{name: "report" for name in (
-        "aggregate", "load_jsonl", "render_cross_host", "render_fleet",
-        "render_memory", "render_report", "render_serve", "render_steps",
+        "aggregate", "load_jsonl", "render_cross_host", "render_memory", "render_report", "render_serve", "render_steps",
         "selfcheck")},
 }
 

@@ -8,7 +8,6 @@
     python -m apex_tpu.monitor memory [--model gpt|mlp|zero|serve]
                                       [--live] [--json]
     python -m apex_tpu.monitor export run.jsonl [--once [--check]|--port N]
-    python -m apex_tpu.monitor fleet ENDPOINT... [--watch|--once] [--json]
     python -m apex_tpu.monitor selfcheck [--steps N]
 
 ``report`` renders the per-step and aggregate tables from a
@@ -34,11 +33,7 @@ the old ``scripts/profile_gpt.py``). ``export`` renders a recorder
 JSONL dump/stream as Prometheus text exposition — ``--once`` to stdout (``--check``
 additionally parses the output back and asserts scrape == aggregate),
 otherwise served over HTTP with
-the file re-read per scrape. ``fleet`` polls N replica exports — live
-``/metrics`` URLs and/or exposition files — and renders the per-replica
-+ fleet table (counters summed, gauges min/max/sum, histograms merged
-bucket-wise) with SLO burn-rate alerts and autoscale decisions;
-``--once`` exits non-zero when an alert fires.
+the file re-read per scrape.
 ``selfcheck`` records a synthetic 3-step amp run on CPU and asserts
 the dump → report round trip.
 
@@ -179,24 +174,6 @@ def main(argv=None) -> int:
     pe.add_argument("--port", type=int, default=9464)
     pe.add_argument("--addr", default="127.0.0.1")
 
-    pf = sub.add_parser("fleet",
-                        help="poll replica exports; fleet aggregate + "
-                             "SLO burn-rate alerts + scale decisions")
-    pf.add_argument("endpoints", nargs="+",
-                    help="replica /metrics URLs and/or exposition "
-                         "file paths")
-    pf.add_argument("--once", action="store_true",
-                    help="poll once and exit (non-zero when an SLO "
-                         "alert fires) — the default mode")
-    pf.add_argument("--watch", action="store_true",
-                    help="poll repeatedly until interrupted")
-    pf.add_argument("--json", action="store_true",
-                    help="print each poll view as one JSON line")
-    pf.add_argument("--interval", type=float, default=10.0,
-                    help="--watch poll interval seconds")
-    pf.add_argument("--timeout", type=float, default=2.0,
-                    help="per-replica scrape timeout seconds")
-
     ps = sub.add_parser("selfcheck",
                         help="record a synthetic run; assert round-trip")
     ps.add_argument("--steps", type=int, default=3)
@@ -278,10 +255,6 @@ def main(argv=None) -> int:
         from apex_tpu.monitor import export as export_mod
         return export_mod.main(args)
 
-    if args.cmd == "fleet":
-        from apex_tpu.monitor import fleet as fleet_mod
-        return fleet_mod.main(args)
-
     if args.cmd in ("profile", "memory"):
         # the two subcommands that compile a model
         from apex_tpu.utils import compile_cache
@@ -300,7 +273,7 @@ def main(argv=None) -> int:
 
 
 def _run_profile(args) -> int:
-    from apex_tpu.monitor import profile as profile_mod
+    from apex_tpu.monitor import attribution as profile_mod
     from apex_tpu.monitor.recorder import json_safe
 
     step, step_args = profile_mod.demo_train_step(
@@ -340,7 +313,7 @@ def _run_profile(args) -> int:
 def _run_memory(args) -> int:
     from apex_tpu import monitor
     from apex_tpu.monitor import memory as memory_mod
-    from apex_tpu.monitor import profile as profile_mod
+    from apex_tpu.monitor import attribution as profile_mod
     from apex_tpu.monitor.recorder import json_safe
 
     out: dict = {"model": args.model}

@@ -41,8 +41,6 @@ from __future__ import annotations
 
 import re
 import threading
-import time
-from typing import Optional
 
 from apex_tpu.monitor import _state
 
@@ -140,45 +138,29 @@ def _with_blind_spots(snap: dict, dropped, open_spans) -> dict:
     return snap
 
 
-def render_prometheus(snap: dict, replica: Optional[str] = None) -> str:
-    """Prometheus text exposition (0.0.4) for a :func:`snapshot`.
-
-    With ``replica`` set (fleet mode), every sample carries a stable
-    ``replica="<id>"`` label so a fleet aggregator can key samples per
-    replica even after concatenating scrapes, and two scrape-metadata
-    samples lead the document: ``apex_replica_up 1`` (this endpoint
-    rendered, so it is up — the poller writes the 0) and
-    ``apex_scrape_timestamp_seconds`` (render wall time, for last-seen
-    age). ``replica=None`` keeps the output byte-identical to the
-    pre-fleet format — single-process scrapes are unchanged."""
+def render_prometheus(snap: dict) -> str:
+    """Prometheus text exposition (0.0.4) for a :func:`snapshot`."""
     from apex_tpu.monitor.spans import LogHistogram
 
-    rl = f',replica="{replica}"' if replica is not None else ""
-    sole = f'{{replica="{replica}"}}' if replica is not None else ""
     lines: list[str] = []
 
     def emit(name: str, mtype: str, rows):
         lines.append(f"# TYPE {name} {mtype}")
         lines.extend(rows)
 
-    if replica is not None:
-        emit("apex_replica_up", "gauge", [f"apex_replica_up{sole} 1"])
-        emit("apex_scrape_timestamp_seconds", "gauge",
-             [f"apex_scrape_timestamp_seconds{sole} "
-              f"{_fmt_value(time.time())}"])
     for k in sorted(snap.get("counters") or {}):
         n = sanitize(k) + "_total"
-        emit(n, "counter", [f"{n}{sole} {_fmt_value(snap['counters'][k])}"])
+        emit(n, "counter", [f"{n} {_fmt_value(snap['counters'][k])}"])
     for k in sorted(snap.get("gauges") or {}):
         n = sanitize(k)
-        emit(n, "gauge", [f"{n}{sole} {_fmt_value(snap['gauges'][k])}"])
+        emit(n, "gauge", [f"{n} {_fmt_value(snap['gauges'][k])}"])
     for k in sorted(snap.get("timers") or {}):
         t = snap["timers"][k]
         n = sanitize(k) + "_seconds"
         emit(n + "_total", "counter",
-             [f"{n}_total{sole} {_fmt_value(t.get('total_s'))}"])
+             [f"{n}_total {_fmt_value(t.get('total_s'))}"])
         emit(n + "_count", "counter",
-             [f"{n}_count{sole} {_fmt_value(t.get('n'))}"])
+             [f"{n}_count {_fmt_value(t.get('n'))}"])
     for k in sorted(snap.get("histograms") or {}):
         h = LogHistogram.from_snapshot(snap["histograms"][k])
         n = sanitize(k)
@@ -190,10 +172,10 @@ def render_prometheus(snap: dict, replica: Optional[str] = None) -> str:
                 continue
             cum += c
             le = h.bucket_bounds(i)[1]
-            rows.append(f'{n}_bucket{{le="{_fmt_value(le)}"{rl}}} {cum}')
-        rows.append(f'{n}_bucket{{le="+Inf"{rl}}} {h.count}')
-        rows.append(f"{n}_sum{sole} {_fmt_value(h.sum)}")
-        rows.append(f"{n}_count{sole} {h.count}")
+            rows.append(f'{n}_bucket{{le="{_fmt_value(le)}"}} {cum}')
+        rows.append(f'{n}_bucket{{le="+Inf"}} {h.count}')
+        rows.append(f"{n}_sum {_fmt_value(h.sum)}")
+        rows.append(f"{n}_count {h.count}")
         emit(n, "histogram", rows)
     return "\n".join(lines) + ("\n" if lines else "")
 
@@ -230,47 +212,25 @@ def parse_prometheus(text: str) -> dict:
     return out
 
 
-def parse_prometheus_types(text: str) -> dict:
-    """``{metric_name: type}`` from the ``# TYPE`` comment lines of an
-    exposition document. The fleet poller feeds this to
-    ``fleet.classify_samples`` so a gauge whose *name* ends in
-    ``_total`` (``serve/pages_total``) is never misread as a counter —
-    the declared type wins over naming convention."""
-    types: dict = {}
-    for line in text.splitlines():
-        parts = line.strip().split()
-        if len(parts) == 4 and parts[0] == "#" and parts[1] == "TYPE":
-            types[parts[2]] = parts[3]
-    return types
-
-
-def selfcheck_text(text: str, snap: dict,
-                   replica: Optional[str] = None) -> None:
+def selfcheck_text(text: str, snap: dict) -> None:
     """Assert ``text`` (an exposition render of ``snap``) parses and
     its counter/gauge/histogram-count samples equal the snapshot —
-    the ``--check`` CLI mode and the CI export stage. Label-aware:
-    pass ``replica`` to check a fleet-labeled render (every sample is
-    then keyed by its ``replica=`` label, histogram buckets by
-    ``le`` + ``replica`` together)."""
+    the ``--check`` CLI mode and the CI export stage."""
     parsed = parse_prometheus(text)
-    lab = (("replica", str(replica)),) if replica is not None else ()
     for k, v in (snap.get("counters") or {}).items():
-        got = parsed[(sanitize(k) + "_total", lab)]
+        got = parsed[(sanitize(k) + "_total", ())]
         assert got == float(v), (k, got, v)
     for k, v in (snap.get("gauges") or {}).items():
-        got = parsed[(sanitize(k), lab)]
+        got = parsed[(sanitize(k), ())]
         if v is None or (isinstance(v, float) and v != v):
             assert got != got, (k, got, v)
         else:
             assert got == float(v), (k, got, v)
     for k, h in (snap.get("histograms") or {}).items():
         n = sanitize(k)
-        assert parsed[(n + "_count", lab)] == float(h.get("count") or 0), k
-        inf = parsed[(n + "_bucket", tuple(sorted((("le", "+Inf"),) + lab)))]
+        assert parsed[(n + "_count", ())] == float(h.get("count") or 0), k
+        inf = parsed[(n + "_bucket", (("le", "+Inf"),))]
         assert inf == float(h.get("count") or 0), k
-    if replica is not None:
-        assert parsed[("apex_replica_up", lab)] == 1.0
-        assert parsed[("apex_scrape_timestamp_seconds", lab)] > 0
 
 
 class MetricsExporter:
@@ -280,18 +240,14 @@ class MetricsExporter:
     — attach/detach cycles are honored live, and a scrape while
     detached returns an empty (but valid) document. ``port=0`` binds an
     ephemeral port; the bound port is returned by :meth:`start` and
-    kept on ``.port``. ``replica=<id>`` opts the render into fleet
-    labeling (see :func:`render_prometheus`): a stable replica identity
-    the serve engine provides so a ``FleetPoller`` can key samples; it
-    defaults to off so single-process output is unchanged.
+    kept on ``.port``.
     """
 
     def __init__(self, recorder=None, port: int = 9464,
-                 addr: str = "127.0.0.1", replica: Optional[str] = None):
+                 addr: str = "127.0.0.1"):
         self.recorder = recorder
         self.addr = addr
         self.port = int(port)
-        self.replica = replica
         self._srv = None
         self._thread = None
 
@@ -301,8 +257,7 @@ class MetricsExporter:
         return render_prometheus(snapshot(recorder=rec)
                                  if rec is not None else
                                  {"counters": {}, "gauges": {},
-                                  "timers": {}, "histograms": {}},
-                                 replica=self.replica)
+                                  "timers": {}, "histograms": {}})
 
     def start(self) -> int:
         from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -354,35 +309,16 @@ class MetricsExporter:
 
 def serve_engine(engine, *, export_port: int,
                  export_addr: str = "127.0.0.1",
-                 max_steps: int = 100_000,
-                 export_recorder=None, on_export=None,
-                 export_hold: Optional[threading.Event] = None) -> dict:
+                 max_steps: int = 100_000, on_export=None) -> dict:
     """``engine.run()`` with a live metrics surface: a
     :class:`MetricsExporter` serves ``GET /metrics`` (the attached
     recorder's counters/gauges/SLO histograms) for the duration of the
     drain and is stopped after it. ``export_port=0`` binds an ephemeral
-    port.
-
-    Fleet wiring (all host-side; compiled programs untouched): samples
-    carry ``replica="<engine.replica_id>"`` labels; ``export_recorder``
-    pins the exporter to a specific recorder (instead of resolving the
-    attached one per scrape — what the multi-replica harness uses, one
-    concrete recorder per engine thread); ``on_export(engine, port)``
-    fires once the port is bound, the registration hook a
-    :class:`~apex_tpu.monitor.fleet.ReplicaSet` hands in;
-    ``export_hold`` keeps the endpoint scrapeable after the drain until
-    the caller sets the event (bounded by a 60 s guard so a forgotten
-    event cannot hang the engine)."""
-    with MetricsExporter(recorder=export_recorder, port=export_port,
-                         addr=export_addr,
-                         replica=engine.replica_id) as exporter:
+    port; ``on_export(engine, port)`` fires once the port is bound."""
+    with MetricsExporter(port=export_port, addr=export_addr) as exporter:
         if on_export is not None:
             on_export(engine, exporter.port)
-        try:
-            return engine.run(max_steps=max_steps)
-        finally:
-            if export_hold is not None:
-                export_hold.wait(timeout=60.0)
+        return engine.run(max_steps=max_steps)
 
 
 def main(args) -> int:

@@ -92,10 +92,6 @@ HEALTH_EVENT_KINDS = (
     "loader_starvation", "straggler",
     "kv_pool_exhaustion", "eviction_storm", "admission_starvation",
     "hbm_high_water", "memory_leak", "recompile_storm",
-    # fleet-level conditions (apex_tpu.monitor.slo/fleet): SLO error
-    # budget burning too fast, and autoscale decisions derived from
-    # fleet-wide pressure signals
-    "slo_alert", "scale_decision",
 )
 
 # Conditions fatal enough that the process may not get another chance
@@ -236,8 +232,7 @@ class Watchdog:
         ev = rec.emit("health_event", name, value, severity=severity,
                       diagnosis=diagnosis, **details)
         # shadow counter: health firings become scrapeable
-        # (`apex_health_<name>_total` in the Prometheus exposition) —
-        # the fleet autoscale decision engine sums these across replicas
+        # (`apex_health_<name>_total` in the Prometheus exposition)
         rec.counter(f"health/{name}")
         self.events.append(ev)
         if self.on_event is not None:
@@ -606,7 +601,7 @@ class Watchdog:
             wait_row = (waits.get("by_rank") or {}).get(str(rank))
             if wait_row is not None and waits.get("slowest_rank") is not None \
                     and str(waits["slowest_rank"]) == str(rank):
-                diag += (" Its data/host_wait mean is also the fleet max "
+                diag += (" Its data/host_wait mean is also the run's max "
                          f"({wait_row.get('mean_s')}s) — the input "
                          "pipeline is the likely cause.")
             details = {"rank": int(rank), "ratio": ratio, "severity": "warn",

@@ -28,7 +28,7 @@ through one vocabulary, in the mold of ``profile.py``/``spans.py``:
   * at each equation the charge is ``resident + live intermediates +
     this equation's outputs``;
   * sub-jaxprs (pjit/scan/cond/while/custom-vjp — duck-typed, the
-    ``profile.analytic_profile`` recursion pattern) add their internal
+    ``attribution.analytic_profile`` recursion pattern) add their internal
     intermediates ON TOP of the live set at the call site. Unlike
     FLOPs, a scan's peak does NOT multiply by trip count — iterations
     reuse the body's buffers, and the stacked outputs are already
@@ -42,7 +42,7 @@ through one vocabulary, in the mold of ``profile.py``/``spans.py``:
   :class:`~apex_tpu.monitor.spans.LogHistogram`. Platforms whose
   backend returns ``None`` (CPU hosts) degrade to a nominal row — real
   ``jax.live_arrays()`` resident bytes against the
-  ``profile.DEVICE_PEAKS`` table limit (its nominal cpu row: the whole
+  ``attribution.DEVICE_PEAKS`` table limit (its nominal cpu row: the whole
   pipeline is exercisable on CI, and the row is stamped nominal). The
   sampler
   installs the ``jax.monitoring`` compile listeners, so retrace storms
@@ -85,8 +85,9 @@ import threading
 from typing import Callable, Optional
 
 from apex_tpu.monitor import _state
-from apex_tpu.monitor.profile import (UNSCOPED, _aval_bytes, _scope_of,
-                                      _sub_jaxprs, device_peaks)
+from apex_tpu.monitor.attribution import (_aval_bytes, _scope_of,
+                                          _sub_jaxprs, device_peaks)
+from apex_tpu.monitor.profile import UNSCOPED
 
 #: The compiled-breakdown fields read off ``Compiled.memory_analysis()``
 #: (one place, shared with the trace shim).
@@ -96,7 +97,7 @@ _MA_FIELDS = ("argument_size_in_bytes", "output_size_in_bytes",
 
 
 def hbm_limit_for(device_kind: Optional[str] = None) -> Optional[int]:
-    """Per-chip HBM bytes (``profile.device_peaks``), ``None`` when the
+    """Per-chip HBM bytes (``attribution.device_peaks``), ``None`` when the
     kind is unknown: utilization is then not computable."""
     row = device_peaks(device_kind)
     return row[1] if row else None
@@ -341,7 +342,7 @@ def device_memory_snapshot(devices=None, recorder=None) -> list[dict]:
     ``peak_bytes_in_use``, ``bytes_limit`` when present); platforms
     that return ``None`` (CPU hosts) degrade to a NOMINAL row —
     ``jax.live_arrays()`` resident bytes against the
-    ``profile.DEVICE_PEAKS`` table limit, stamped ``"nominal": True``.
+    ``attribution.DEVICE_PEAKS`` table limit, stamped ``"nominal": True``.
     Recorded as ``memory/...`` gauges on the
     attached (or passed) recorder; the headline
     ``memory/hbm_bytes_in_use`` gauge is the max across devices."""

@@ -82,7 +82,6 @@ def aggregate(events: Iterable[dict], header: Optional[dict] = None) -> dict:
                        "memory/hbm_bytes_in_use")
     steps: list[dict] = []
     health: list[dict] = []
-    fleet_polls: list[dict] = []   # FleetPoller per-poll views, in order
     for ev in events:
         kind = ev.get("kind")
         name = ev.get("name", "")
@@ -171,9 +170,6 @@ def aggregate(events: Iterable[dict], header: Optional[dict] = None) -> dict:
                            ("name", "value", "step", "severity",
                             "diagnosis", "gauge", "rank", "t")
                            if ev.get(k) is not None})
-        elif kind == "fleet":
-            # monitor.fleet.FleetPoller poll views; chronological
-            fleet_polls.append(ev)
     out: dict = {}
     if header:
         out["run"] = {k: header.get(k) for k in ("name", "dropped", "meta")
@@ -250,9 +246,6 @@ def aggregate(events: Iterable[dict], header: Optional[dict] = None) -> dict:
     mem = _memory_block(memory_rows, memory_scopes, gauges, gauge_series)
     if mem:
         out["memory"] = mem
-    fl = _fleet_block(fleet_polls)
-    if fl:
-        out["fleet"] = fl
     if health:
         out["health"] = health
     return out
@@ -358,31 +351,6 @@ def _memory_block(memory_rows, memory_scopes, gauges, gauge_series):
                            "last": vals[-1],
                            "trajectory": _downsample(series)}
     return out
-
-
-def _fleet_block(fleet_polls: list[dict]):
-    """The multi-replica view recorded by ``monitor.fleet.FleetPoller``:
-    the LAST poll is the fleet state (replica table, summed counters,
-    min/max/sum gauge views, merged-histogram percentiles); alerts and
-    scale decisions accumulate across all polls so a burn that fired and
-    cleared mid-run still shows."""
-    if not fleet_polls:
-        return None
-    last = fleet_polls[-1]
-    alerts: list[dict] = []
-    decisions: list[dict] = []
-    for ev in fleet_polls:
-        alerts.extend(ev.get("alerts") or [])
-        decisions.extend(ev.get("decisions") or [])
-    return {"polls": len(fleet_polls),
-            "n_replicas": last.get("n_replicas"),
-            "n_up": last.get("value"),
-            "replicas": last.get("replicas") or [],
-            "counters": last.get("counters") or {},
-            "gauges": last.get("gauges") or {},
-            "hist_summary": last.get("hist_summary") or {},
-            "alerts": alerts,
-            "decisions": decisions}
 
 
 def measured_idle_fraction(agg: dict, schedule: str):
@@ -564,53 +532,6 @@ def render_memory(agg: dict, max_rows: int = 30) -> Optional[str]:
     return "\n".join(parts)
 
 
-def render_fleet(agg: dict, max_rows: int = 30) -> Optional[str]:
-    """Render the ``fleet`` block of an :func:`aggregate` result:
-    per-replica up/age table from the last poll, fleet-summed counters,
-    merged-histogram percentiles, and every ``slo_alert`` /
-    ``scale_decision`` accumulated across polls. ``None`` when no fleet
-    polls were recorded. Used by ``render_report`` and the
-    ``python -m apex_tpu.monitor fleet`` CLI docs."""
-    fl = agg.get("fleet")
-    if not fl:
-        return None
-    parts = ["## fleet (multi-replica aggregation)\n"]
-    parts.append(f"replicas up: {fl.get('n_up')}/{fl.get('n_replicas')} "
-                 f"(over {fl.get('polls')} polls)")
-    reps = fl.get("replicas") or []
-    if reps:
-        parts.append("\n| replica | endpoint | up | age s | error |\n"
-                     "|---|---|---|---|---|")
-        for r in reps[:max_rows]:
-            age = r.get("age_s")
-            parts.append(
-                f"| {r.get('replica')} | {r.get('endpoint')} "
-                f"| {r.get('up')} | {_fmt(age) if age is not None else ''} "
-                f"| {r.get('error') or ''} |")
-    ctr = fl.get("counters") or {}
-    if ctr:
-        keep = sorted(ctr)[:max_rows]
-        parts.append("\n| counter (fleet sum) | total |\n|---|---|")
-        for k in keep:
-            parts.append(f"| {k} | {_fmt(ctr[k])} |")
-        if len(ctr) > max_rows:
-            parts.append(f"... ({len(ctr) - max_rows} more counters)")
-    hs = fl.get("hist_summary") or {}
-    for name in sorted(hs):
-        row = hs[name]
-        parts.append(f"{name} (merged): p50 {_fmt(row.get('p50'))}  "
-                     f"p95 {_fmt(row.get('p95'))}  "
-                     f"p99 {_fmt(row.get('p99'))}  "
-                     f"(n={row.get('count')}, mean {_fmt(row.get('mean'))})")
-    for a in (fl.get("alerts") or [])[:max_rows]:
-        parts.append(f"- ALERT **{a.get('slo')}** [{a.get('severity')}] "
-                     f"window={a.get('window')}: {a.get('diagnosis')}")
-    for d in (fl.get("decisions") or [])[:max_rows]:
-        parts.append(f"- DECISION **{d.get('decision')}** "
-                     f"[{d.get('severity')}]: {d.get('rationale')}")
-    return "\n".join(parts)
-
-
 def render_report(events: list[dict], header: Optional[dict] = None,
                   max_rows: int = 50) -> str:
     """Full human-readable report: per-step table + aggregates."""
@@ -634,9 +555,6 @@ def render_report(events: list[dict], header: Optional[dict] = None,
     mem = render_memory(agg, max_rows=max_rows)
     if mem:
         parts.append("\n" + mem)
-    fl = render_fleet(agg, max_rows=max_rows)
-    if fl:
-        parts.append("\n" + fl)
     parts.append("\n## per-step\n")
     parts.append(render_steps(events, max_rows=max_rows))
     if "steps" in agg:

@@ -267,40 +267,6 @@ class LogHistogram:
             h._counts[int(i)] = int(c)
         return h
 
-    @classmethod
-    def merge(cls, *snapshots: dict) -> "LogHistogram":
-        """Bucket-wise sum of :meth:`snapshot` payloads sharing one
-        bucket config — the fleet-aggregation primitive: a percentile
-        of the merged histogram is the percentile of the POOLED sample
-        population (within the same ``10^(1/(2*bpd))`` ~12% band as a
-        single histogram), which averaging per-replica percentiles is
-        not. Mismatched ``lo``/``hi``/``buckets_per_decade`` raise —
-        merging incompatible bucket grids would silently misbucket."""
-        if not snapshots:
-            raise ValueError("merge needs at least one snapshot")
-        cfg = (float(snapshots[0]["lo"]), float(snapshots[0]["hi"]),
-               int(snapshots[0]["buckets_per_decade"]))
-        h = cls(lo=cfg[0], hi=cfg[1], buckets_per_decade=cfg[2])
-        for snap in snapshots:
-            got = (float(snap["lo"]), float(snap["hi"]),
-                   int(snap["buckets_per_decade"]))
-            if got != cfg:
-                raise ValueError(
-                    f"histogram config mismatch: {got} != {cfg} "
-                    "(lo, hi, buckets_per_decade must agree)")
-            h.count += int(snap.get("count", 0))
-            h.sum += float(snap.get("sum", 0.0))
-            h.underflow += int(snap.get("underflow", 0))
-            h.overflow += int(snap.get("overflow", 0))
-            for i, c in (snap.get("counts") or {}).items():
-                h._counts[int(i)] += int(c)
-            mn, mx = snap.get("min"), snap.get("max")
-            if mn is not None:
-                h.min = mn if h.min is None else min(h.min, mn)
-            if mx is not None:
-                h.max = mx if h.max is None else max(h.max, mx)
-        return h
-
 
 def hist_summary(snap: dict, percentiles=(50, 95, 99)) -> dict:
     """Percentile summary of a :meth:`LogHistogram.snapshot` payload

@@ -62,7 +62,7 @@ def annotate(name: str, **metadata):
 
     The named scope rides :func:`apex_tpu.monitor.profile.scope`, so an
     ``annotate``-tagged region also appears as a row in the per-module
-    cost attribution table (``monitor.profile.analytic_profile``)."""
+    cost attribution table (``monitor.attribution.analytic_profile``)."""
     import jax
     from apex_tpu.monitor import profile as _profile
     payload = name if not metadata else \

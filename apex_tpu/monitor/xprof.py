@@ -178,7 +178,7 @@ def top_ops(logdir: str, n: int = 5, host: bool = False) -> List[list]:
 
 
 def format_table(rows: List[dict], max_rows: int = 20) -> str:
-    """Render rows as the markdown table used in docs/perf.md. The share
+    """Render rows as a markdown table, one row an op. The share
     column is computed from the rows' self-times (same policy as
     :func:`top_ops` — xprof's own percent column is unreliable)."""
     total = sum(float(r.get("total_self_time_us") or 0.0)

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu import _compat
 from apex_tpu.amp import fp8
 from apex_tpu.amp.policy import dtype_transparent
 from apex_tpu.tune.vmem import ceil_to as _ceil_to
@@ -160,7 +161,6 @@ def fp8_dequant_matmul(x, q, scale, out_dtype=None, *,
         raise ValueError("fp8_dequant_matmul: pass both block_k and "
                          "block_n, or neither")
     if block_k is None:
-        from apex_tpu.ops.flash_attention import _resolve_interpret
         from apex_tpu.tune import runtime as _tune_rt
         policy = _tune_rt.resolve_policy(autotune)
         if policy != "off" and _fp8_mm_eligible(x, q):
@@ -172,7 +172,7 @@ def fp8_dequant_matmul(x, q, scale, out_dtype=None, *,
                 {"m": m, "k": q.shape[0], "n": q.shape[1],
                  "itemsize": x.dtype.itemsize},
                 x.dtype.name, {}, policy=policy,
-                interpret=_resolve_interpret(interpret))
+                interpret=_compat.resolve_interpret(interpret))
             if cfg is not None:
                 block_k, block_n = cfg["block_k"], cfg["block_n"]
     elif autotune is not None:
@@ -185,7 +185,6 @@ def fp8_dequant_matmul(x, q, scale, out_dtype=None, *,
                 "activation against a 128-aligned 2D e4m3 weight; got "
                 f"x {x.shape} @ q {q.shape} (drop the blocks to use "
                 "the XLA reference)")
-        from apex_tpu.ops.flash_attention import _resolve_interpret
         K, N = q.shape
         block_k = max(128, min(int(block_k), _ceil_to(K, 128)))
         block_n = max(128, min(int(block_n), _ceil_to(N, 128)))
@@ -197,7 +196,7 @@ def fp8_dequant_matmul(x, q, scale, out_dtype=None, *,
             y = _fp8_mm_pallas(x.reshape(m, K), q,
                                jnp.asarray(scale, jnp.float32), out_dt,
                                block_k, block_n,
-                               _resolve_interpret(interpret))
+                               _compat.resolve_interpret(interpret))
         return y.reshape(lead + (N,))
     with _prof.scope("fp8_matmul"):
         return fp8_dequant_matmul_reference(x, q, scale, out_dt)

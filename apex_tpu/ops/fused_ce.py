@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from apex_tpu import _compat
 from apex_tpu.amp.policy import dtype_transparent
 from apex_tpu.tune.vmem import ceil_to as _ceil_to
 
@@ -275,7 +276,6 @@ def softmax_cross_entropy_with_smoothing(logits, labels, smoothing=0.0,
     for d in lead:
         n *= d
     if not explicit:
-        from apex_tpu.ops.flash_attention import _resolve_interpret
         from apex_tpu.tune import runtime as _tune_rt
         policy = _tune_rt.resolve_policy(autotune)
         # no lane-alignment gate on v: the kernels pad ragged vocabs and
@@ -286,7 +286,7 @@ def softmax_cross_entropy_with_smoothing(logits, labels, smoothing=0.0,
                 "xentropy",
                 {"n": n, "v": v, "itemsize": logits.dtype.itemsize},
                 logits.dtype.name, {"smoothing": smoothing > 0.0},
-                policy=policy, interpret=_resolve_interpret(interpret))
+                policy=policy, interpret=_compat.resolve_interpret(interpret))
             if cfg is not None:
                 block_t, block_v = cfg["block_t"], cfg["block_v"]
                 explicit = True
@@ -303,7 +303,6 @@ def softmax_cross_entropy_with_smoothing(logits, labels, smoothing=0.0,
             "fused CE kernel needs [..., V] logits with a leading axis; "
             f"got shape {logits.shape} (drop the block knobs to use the "
             "XLA reference)")
-    from apex_tpu.ops.flash_attention import _resolve_interpret
     block_t, block_v = _pick_ce_blocks(n, v, block_t, block_v,
                                        logits.dtype.itemsize)
     lg = logits.reshape(n, v)
@@ -320,7 +319,7 @@ def softmax_cross_entropy_with_smoothing(logits, labels, smoothing=0.0,
     with _prof.scope("xentropy"):
         loss = _fused_xent(lg, tgt[None], float(smoothing), v,
                            int(block_t), int(block_v),
-                           _resolve_interpret(interpret))
+                           _compat.resolve_interpret(interpret))
         loss = loss[:n].reshape(lead)
         if padding_idx is not None:
             # zero loss AND zero gradient for padding rows: the loss

@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu import _compat
 from apex_tpu._compat import tpu_compiler_params
 from apex_tpu.monitor import profile as _prof
 
@@ -62,13 +63,6 @@ IMPLS = ("kernel", "reference")
 #: double-buffered [K, block_n] weight blocks at K = 7168 need ~15 MB, over
 #: Mosaic's default 16 MB scope with the x and out blocks beside them
 _VMEM_LIMIT = 64 * 1024 * 1024
-
-
-def resolve_interpret(interpret):
-    # the one rule of the Pallas ops (looked up at call time: the compile
-    # tests steer it there)
-    from apex_tpu.ops.flash_attention import _resolve_interpret as rule
-    return rule(interpret)
 
 
 def tile_layout(counts, block_m: int, max_rows: int):
@@ -165,7 +159,7 @@ def grouped_matmul(x, w, tile_group, tiles_used, *, block_m: int,
                           ).astype(x.dtype)
     used = jnp.reshape(tiles_used, (1,)).astype(jnp.int32)
     return _grouped_matmul(x, w, tile_group, used, block_m,
-                           resolve_interpret(interpret))
+                           _compat.resolve_interpret(interpret))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))

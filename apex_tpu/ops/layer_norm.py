@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from apex_tpu import _compat
 from apex_tpu.amp.policy import dtype_transparent
 from apex_tpu.tune.vmem import ceil_to as _ceil_to
 
@@ -253,7 +254,6 @@ def fused_layer_norm_affine(x, weight, bias, normalized_shape, eps=1e-5,
     kernel existed."""
     from apex_tpu.monitor import profile as _prof
     if block_r is None:
-        from apex_tpu.ops.flash_attention import _resolve_interpret
         from apex_tpu.tune import runtime as _tune_rt
         policy = _tune_rt.resolve_policy(autotune)
         if policy != "off" and _ln_kernel_eligible(x, normalized_shape):
@@ -265,7 +265,7 @@ def fused_layer_norm_affine(x, weight, bias, normalized_shape, eps=1e-5,
                 "fused_layer_norm",
                 {"n": n, "h": h, "itemsize": x.dtype.itemsize},
                 x.dtype.name, {}, policy=policy,
-                interpret=_resolve_interpret(interpret))
+                interpret=_compat.resolve_interpret(interpret))
             if cfg is not None:
                 block_r = cfg["block_r"]
     elif autotune is not None:
@@ -278,7 +278,6 @@ def fused_layer_norm_affine(x, weight, bias, normalized_shape, eps=1e-5,
                 "single 128-aligned trailing normalized axis; got "
                 f"normalized_shape={normalized_shape} for input shape "
                 f"{x.shape} (drop block_r to use the XLA reference)")
-        from apex_tpu.ops.flash_attention import _resolve_interpret
         h = x.shape[-1]
         lead = x.shape[:-1]
         n = 1
@@ -296,7 +295,7 @@ def fused_layer_norm_affine(x, weight, bias, normalized_shape, eps=1e-5,
         with _prof.scope("fused_layer_norm"):
             y = _ln_affine_pallas(x2d, weight, bias, float(eps), out_dt,
                                   int(block_r),
-                                  _resolve_interpret(interpret))
+                                  _compat.resolve_interpret(interpret))
         return y[:n].reshape(lead + (h,))
     with _prof.scope("fused_layer_norm"):
         return fused_layer_norm_affine_reference(

@@ -46,8 +46,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu import _compat
 from apex_tpu.monitor import profile as _prof
-from apex_tpu.ops.flash_attention import _resolve_interpret
 
 IMPLS = ("reference", "kernel")
 
@@ -206,7 +206,7 @@ def lightning_prefill(q, k, v, state, slot, start, n_live, *,
     scalars = jnp.stack([jnp.asarray(x, jnp.int32).reshape(())
                          for x in (slot, start, n_live)])
     return tuple(_prefill_call(q, k, v, log_lam, state, scalars, c=c,
-                               interpret=_resolve_interpret(interpret)))
+                               interpret=_compat.resolve_interpret(interpret)))
 
 
 # -- decode --------------------------------------------------------------------
@@ -268,4 +268,4 @@ def lightning_decode(q, k, v, state, active, *, impl: str = "reference",
                          f"eights, got d={d}, H={H}")
     lanes = jnp.broadcast_to(lam[..., None], (B, H, d))
     return tuple(_decode_call(q, k, v, lanes, state, hb=hb,
-                              interpret=_resolve_interpret(interpret)))
+                              interpret=_compat.resolve_interpret(interpret)))

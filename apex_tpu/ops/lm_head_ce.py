@@ -51,8 +51,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from apex_tpu import _compat
 from apex_tpu._compat import tpu_compiler_params
-from apex_tpu.ops.flash_attention import _resolve_interpret
 from apex_tpu.transformer import parallel_state as ps
 
 from apex_tpu.amp.policy import dtype_transparent
@@ -402,7 +402,7 @@ def fused_lm_head_cross_entropy(
                 {"n": n, "v": v_local, "h": h,
                  "itemsize": x.dtype.itemsize},
                 x.dtype.name, {"smoothing": label_smoothing > 0.0},
-                policy=policy, interpret=_resolve_interpret(interpret))
+                policy=policy, interpret=_compat.resolve_interpret(interpret))
             if cfg is not None:
                 block_t, block_v = cfg["block_t"], cfg["block_v"]
     elif autotune is not None:
@@ -425,5 +425,5 @@ def fused_lm_head_cross_entropy(
     with _prof.scope("lm_head_ce"):
         loss = _fused_ce(xf, embedding, tgt[None], label_smoothing,
                          axis_name, block_t, block_v, v_local,
-                         _resolve_interpret(interpret))
+                         _compat.resolve_interpret(interpret))
     return loss[:n].reshape(lead)

@@ -33,16 +33,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu import _compat
 from apex_tpu.monitor import profile as _prof
 
 _NEG_INF = -1e30
-
-
-def _resolve_interpret(interpret):
-    # the one rule of the Pallas ops (looked up at call time: the compile
-    # tests steer it there)
-    from apex_tpu.ops.flash_attention import _resolve_interpret as rule
-    return rule(interpret)
 
 
 def mla_attention_reference(q, latent_pages, block_tables, seq_lens, *,
@@ -128,7 +122,7 @@ def mla_decode_attention(q, latent_pages, block_tables, seq_lens, *,
         raise ValueError(f"latent_pages {latent_pages.shape} does not match "
                          f"q {q.shape}: want [1, num_pages, page_size, "
                          f"{width}]")
-    interpret = _resolve_interpret(interpret)
+    interpret = _compat.resolve_interpret(interpret)
     if not interpret and (width % 128 or value_dim % 128 or page_size % 8
                           or heads % 8):
         raise ValueError(

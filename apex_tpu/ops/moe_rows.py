@@ -55,9 +55,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu import _compat
 from apex_tpu._compat import tpu_compiler_params
 from apex_tpu.monitor import profile as _prof
-from apex_tpu.ops.grouped_matmul import last_used, resolve_interpret
+from apex_tpu.ops.grouped_matmul import last_used
 
 #: tokens a program of :func:`token_rows` sums: ``k`` rows each in flight
 TOKEN_BLOCK = 32
@@ -152,7 +153,7 @@ def open_tiles(ys, tiles_used, *, block_m: int,
     undefined."""
     used = jnp.reshape(tiles_used, (1,)).astype(jnp.int32)
     return _open_tiles_call(ys, used, block_m=block_m,
-                            interpret=resolve_interpret(interpret))
+                            interpret=_compat.resolve_interpret(interpret))
 
 
 def open_rows(x, *, interpret: Optional[bool] = None):
@@ -288,7 +289,7 @@ def sorted_rows(x, src, tiles_used, *, block_m: int, scale=None,
     if (scale is None) != (dot_with is None):
         raise ValueError("scale and dot_with come together")
     used = jnp.reshape(tiles_used, (1,)).astype(jnp.int32)
-    interpret = resolve_interpret(interpret)
+    interpret = _compat.resolve_interpret(interpret)
     return _sorted_call(open_rows(x, interpret=interpret),
                         src.astype(jnp.int32), used, scale, dot_with,
                         h=x.shape[1], dtype=jnp.dtype(x.dtype),
@@ -394,7 +395,7 @@ def token_rows(ys, idx, wm, tiles_used, *, block_m: int, out_dtype=None,
     to ``out_dtype`` (``ys.dtype``). ``idx`` int32 ``[t, k]``, ``wm``
     float32 ``[t, k]`` (a choice whose weight is 0 adds nothing, whatever
     its row holds); ``t`` a multiple of :data:`TOKEN_BLOCK`."""
-    interpret = resolve_interpret(interpret)
+    interpret = _compat.resolve_interpret(interpret)
     rows = open_tiles(ys, tiles_used, block_m=block_m, interpret=interpret)
     return _token_call(rows, idx.astype(jnp.int32), wm.astype(jnp.float32),
                        h=ys.shape[1],

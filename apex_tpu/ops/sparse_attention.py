@@ -24,7 +24,7 @@ the mean of the rows THE POOL holds (the cache's dtype): a chunk's and a
 decode step's agree to the bit. An incomplete key's row is never read.
 
 **Decode** walks the chosen pages with the paged decode kernel of
-``ops.flash_attention`` given a list of pages a (sequence, K|V head): the
+``ops.paged_attention`` given a list of pages a (sequence, K|V head): the
 chosen blocks in ascending order, so that only the last, the query's own,
 is partly live. A row in the dense regime lists all its pages.
 **Prefill** computes the same choice a query token and attends under it as
@@ -45,10 +45,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from apex_tpu import _compat
 from apex_tpu.monitor import profile as _prof
-from apex_tpu.ops.flash_attention import (
-    _NEG_INF, _paged_decode_call, _resolve_interpret, flash_attention,
-    paged_attention_reference)
+from apex_tpu.ops.flash_attention import flash_attention
+from apex_tpu.ops.paged_attention import (
+    _NEG_INF, _paged_decode_call, paged_attention_reference)
 
 _BIG = 1e9
 
@@ -298,7 +299,7 @@ def sparse_decode_attention(q, kv_pages, pages, rows, *, scale: float,
         return _paged_decode_call(
             q, kv_pages, pages.reshape(b * kv, -1), rows, None, None,
             scale=float(scale), hb=1,
-            interpret=_resolve_interpret(interpret), per_head=True)
+            interpret=_compat.resolve_interpret(interpret), per_head=True)
     with _prof.scope("sparse_decode_attention"):
         return jnp.concatenate([
             paged_attention_reference(

@@ -10,7 +10,7 @@ existing subsystems on the decode hot path:
   per-page scales through the :mod:`apex_tpu.amp.fp8` codec (~2x cache
   capacity = ~2x concurrent sequences per chip);
 - the **decode attention kernel**
-  (``ops.flash_attention.paged_decode_attention``): single query per
+  (``ops.paged_attention.paged_decode_attention``): single query per
   sequence reading K/V through the block table, GQA-aware, page size
   resolved explicit > tuned cache > heuristic via :mod:`apex_tpu.tune`
   (the ``decode_attention`` sweep);

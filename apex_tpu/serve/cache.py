@@ -77,7 +77,7 @@ import jax.numpy as jnp
 
 from apex_tpu.amp import fp8 as fp8_mod
 from apex_tpu.monitor import profile as _prof
-from apex_tpu.ops.flash_attention import (paged_kv_write_pages,
+from apex_tpu.ops.paged_attention import (paged_kv_write_pages,
                                           paged_kv_write_rows)
 
 #: heuristic default page size: big enough that one DMA of the decode
@@ -270,7 +270,7 @@ def write_token(cfg: CacheConfig, state: CacheState, layer: int,
     ``k_new``/``v_new``: [b, kv_heads, d]. Pure — runs inside the
     donated decode step.
 
-    ``impl="kernel"`` (``ops.flash_attention.paged_kv_write_rows``) moves
+    ``impl="kernel"`` (``ops.paged_attention.paged_kv_write_rows``) moves
     a group of ``G`` rows a program, their tiles' reads in flight together
     and then their writes; ``G`` follows from the tile's bytes, the decode
     kernel's VMEM budget and ``b``, and is nothing a caller sets. Rows of a

@@ -82,7 +82,6 @@ costs one global read per hook.
 from __future__ import annotations
 
 import dataclasses
-import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -90,6 +89,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from apex_tpu import _compat
 from apex_tpu._compat import shard_map
 from apex_tpu.models.gpt import GPTConfig
 from apex_tpu.monitor import _state as _monitor_state
@@ -105,15 +105,9 @@ from apex_tpu.transformer import parallel_state as ps
 
 
 def _default_impls():
-    on_tpu = jax.default_backend() == "tpu"
+    on_tpu = _compat.on_tpu()
     return (("kernel" if on_tpu else "reference"),
             ("flash" if on_tpu else "reference"))
-
-
-# auto-assigned replica identities ("replica0", "replica1", ...) for
-# engines constructed without an explicit replica_id
-_REPLICA_SEQ = 0
-_REPLICA_SEQ_LOCK = threading.Lock()
 
 
 @dataclasses.dataclass
@@ -152,7 +146,6 @@ class ServeEngine:
                  autotune: Optional[str] = None,
                  record_logits: bool = False,
                  interpret: Optional[bool] = None,
-                 replica_id: Optional[str] = None,
                  spec_k: int = 0,
                  draft_num_layers: Optional[int] = None,
                  draft_cfg: Optional[GPTConfig] = None,
@@ -177,16 +170,6 @@ class ServeEngine:
             # shard_map specs below apply unchanged
             params = model.quantize_weights(params, margin=fp8_weight_margin)
         self.params = params
-        # stable replica identity for fleet telemetry: labels every
-        # sample a metrics exporter renders for this engine and keys it
-        # in a fleet's replica set. Host-side only — never reaches a
-        # compiled program.
-        if replica_id is None:
-            with _REPLICA_SEQ_LOCK:
-                global _REPLICA_SEQ
-                replica_id = f"replica{_REPLICA_SEQ}"
-                _REPLICA_SEQ += 1
-        self.replica_id = str(replica_id)
         self.paged_impl = paged_impl or d_impl
         self.attention_impl = attention_impl or p_impl
         self.interpret = interpret
@@ -913,7 +896,7 @@ class ServeEngine:
         if dt > 0 and toks:
             # tokens/s/chip goodput: completed-token throughput per
             # participating chip (the serve twin of training MFU —
-            # monitor.profile.mfu)
+            # monitor.attribution.mfu)
             _mhooks.gauge("serve/goodput_tokens_per_sec_chip",
                           toks / dt / max(1, self.tp))
         rec = _monitor_state.recorder

@@ -36,9 +36,9 @@ import jax.numpy as jnp
 from apex_tpu.models.gpt import GPTConfig
 from apex_tpu.monitor import profile as _prof
 from apex_tpu.normalization import FusedLayerNorm
-from apex_tpu.ops.flash_attention import (
-    flash_attention, mha_reference, paged_attention_reference,
-    paged_decode_attention)
+from apex_tpu.ops.flash_attention import flash_attention, mha_reference
+from apex_tpu.ops.paged_attention import (
+    paged_attention_reference, paged_decode_attention)
 from apex_tpu.serve import cache as cache_mod
 from apex_tpu.serve import rules as rules_mod
 from apex_tpu.transformer import parallel_state as ps

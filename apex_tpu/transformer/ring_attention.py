@@ -45,8 +45,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from apex_tpu.ops.flash_attention import (
-    _flash_bwd_impl, _flash_fwd_impl, _resolve_interpret)
+from apex_tpu import _compat
+from apex_tpu.ops.flash_attention import _flash_bwd_impl, _flash_fwd_impl
 from apex_tpu.transformer import parallel_state as ps
 from apex_tpu._compat import axis_size as _axis_size
 
@@ -99,7 +99,7 @@ def _ring_fwd_impl(q, k, v, sid_q, sid_kv, seed, axis_name, causal, scale,
     cp, rank, perm = _ring_layout(axis_name)
     b, h, s_local, d = q.shape
     scale_v = d ** -0.5 if scale is None else scale
-    interp = _resolve_interpret(None)
+    interp = _compat.resolve_interpret(None)
     bq = min(block_q or 1024, s_local)
     bk = min(block_k or 1024, s_local)
 
@@ -141,7 +141,7 @@ def _ring_bwd_impl(res, do, axis_name, causal, scale, dropout_rate,
     cp, rank, perm = _ring_layout(axis_name)
     b, h, s_local, d = q.shape
     scale_v = d ** -0.5 if scale is None else scale
-    interp = _resolve_interpret(None)
+    interp = _compat.resolve_interpret(None)
     bq = min(block_q or 1024, s_local)
     bk = min(block_k or 1024, s_local)
 
@@ -354,7 +354,7 @@ def _zz_fwd_impl(q, k, v, sid_q, sid_kv, seed, axis_name, scale,
     b, h, s_local, d = q.shape
     half = s_local // 2
     scale_v = d ** -0.5 if scale is None else scale
-    interp = _resolve_interpret(None)
+    interp = _compat.resolve_interpret(None)
     bq = min(block_q or 1024, half)
     bk = min(block_k or 1024, half)
 
@@ -416,7 +416,7 @@ def _zz_bwd_impl(res, do, axis_name, scale, dropout_rate, block_q, block_k):
     b, h, s_local, d = q.shape
     half = s_local // 2
     scale_v = d ** -0.5 if scale is None else scale
-    interp = _resolve_interpret(None)
+    interp = _compat.resolve_interpret(None)
     bq = min(block_q or 1024, half)
     bk = min(block_k or 1024, half)
 

@@ -41,14 +41,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from apex_tpu import _compat
+
 _LANES = 128
-
-
-def _resolve_interpret(interpret):
-    # ONE interpret-resolution policy for every kernel (lazy: this
-    # module must stay importable before ops finishes initializing)
-    from apex_tpu.ops.flash_attention import _resolve_interpret as _ri
-    return _ri(interpret)
 
 
 def _mtu_kernel(scal_ref, p_ref, g_ref, m_ref, v_ref, o_ref, mo_ref,
@@ -145,7 +140,7 @@ def fused_shard_update(p, g, m, v, step, *, kind: str, lr, betas, eps,
             out_specs=[blk] * 3,
             out_shape=[jax.ShapeDtypeStruct((rows, _LANES),
                                             jnp.float32)] * 3,
-            interpret=_resolve_interpret(interpret),
+            interpret=_compat.resolve_interpret(interpret),
         )(scal, _blocked(p), _blocked(g), _blocked(m), _blocked(v))
 
     def _unblocked(x):

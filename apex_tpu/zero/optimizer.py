@@ -38,6 +38,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from apex_tpu import _compat
 from apex_tpu.zero import comm as _comm
 from apex_tpu.zero.core import ZeroSpec, pad_to_multiple
 from apex_tpu.zero.update import (ShardedAdamState, ShardedLambState,
@@ -135,14 +136,13 @@ class ZeroOptimizer:
         trace time; resolution order and telemetry are the shared
         ``tune.runtime`` contract the flash/LN/CE kernels use."""
         from apex_tpu.tune import runtime as _tune_rt
-        from apex_tpu.zero.fused_update import _resolve_interpret
         policy = _tune_rt.resolve_policy(self.autotune)
         if policy == "off" or n <= 0:
             return None
         return _tune_rt.resolve(
             "multi_tensor_update", {"n": int(n), "itemsize": 4},
             "float32", {"lamb": self.kind == "lamb"}, policy=policy,
-            interpret=_resolve_interpret(None))
+            interpret=_compat.resolve_interpret(None))
 
     # -- dispatch -----------------------------------------------------------
     def init(self, params, spec: ZeroSpec | None = None):

@@ -188,7 +188,8 @@ def test_spatial_bottleneck_matches_unsharded():
 
 def test_contrib_fast_layer_norm_parity_surface():
     """apex.contrib.layer_norm API shim: FastLayerNorm(hidden, eps) ==
-    the one fused LN (the second-LN fold is deliberate, docs/perf.md)."""
+    the one fused LN (apex's second LN is folded into it on purpose: one
+    implementation serves both, ``contrib/layer_norm/__init__.py``)."""
     import jax
     import jax.numpy as jnp
     import numpy as np

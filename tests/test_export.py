@@ -220,56 +220,11 @@ def test_parse_rejects_garbage():
         export.parse_prometheus("not a metric line at all!!!")
 
 
-# ---------------------------------------------------------------------------
-# fleet-labeled exposition (replica= labels + scrape metadata)
-# ---------------------------------------------------------------------------
-
-def test_replica_labeled_render_roundtrip():
-    """A ``replica=`` labeled render carries the scrape-metadata gauges
-    (``apex_replica_up``, ``apex_scrape_timestamp_seconds``), labels
-    every sample (histogram buckets get ``le`` + ``replica`` together),
-    and self-checks label-aware; the unlabeled render stays
-    byte-identical to the golden document."""
-    rec = _mini_recorder()
-    snap = export.snapshot(recorder=rec)
-    text = export.render_prometheus(snap, replica="r7")
-    assert 'apex_replica_up{replica="r7"} 1' in text
-    assert 'apex_scrape_timestamp_seconds{replica="r7"}' in text
-    assert 'apex_serve_preemptions_total{replica="r7"} 3' in text
-    assert 'apex_serve_ttft_ms_bucket{le="10",replica="r7"} 1' in text
-    export.selfcheck_text(text, snap, replica="r7")
-    # declared types survive the round trip — the fleet classifier
-    # depends on them to keep a gauge named *_total a gauge
-    types = export.parse_prometheus_types(text)
-    assert types["apex_serve_preemptions_total"] == "counter"
-    assert types["apex_serve_queue_depth"] == "gauge"
-    assert types["apex_serve_ttft_ms"] == "histogram"
-    assert types["apex_replica_up"] == "gauge"
-    # replica=None output unchanged (the golden contract)
-    assert export.render_prometheus(snap) == GOLDEN
-
-
-def test_exporter_serves_replica_label():
-    rec = _mini_recorder()
-    exporter = export.MetricsExporter(recorder=rec, port=0, replica="rx")
-    port = exporter.start()
-    try:
-        with urllib.request.urlopen(
-                f"http://127.0.0.1:{port}/metrics", timeout=10) as resp:
-            body = resp.read().decode()
-        assert 'apex_replica_up{replica="rx"} 1' in body
-        parsed = export.parse_prometheus(body)
-        assert parsed[("apex_serve_preemptions_total",
-                       (("replica", "rx"),))] == 3
-    finally:
-        exporter.stop()
-
-
 def test_concurrent_scrape_while_writer_emits():
     """A writer thread hammering counters/gauges/histograms while the
     render path snapshots repeatedly: every scrape parses clean and the
     scraped counter is monotone (no torn reads, no exceptions) — the
-    lock-protected snapshot contract the fleet poller leans on."""
+    lock-protected snapshot contract a live scrape leans on."""
     import threading
     rec = monitor.Recorder(traced_hooks=False)
     stop = threading.Event()
@@ -292,11 +247,10 @@ def test_concurrent_scrape_while_writer_emits():
         last = -1.0
         for _ in range(25):
             snap = export.snapshot(recorder=rec)
-            text = export.render_prometheus(snap, replica="w0")
-            export.selfcheck_text(text, snap, replica="w0")
+            text = export.render_prometheus(snap)
+            export.selfcheck_text(text, snap)
             parsed = export.parse_prometheus(text)
-            cur = parsed[("apex_serve_tokens_generated_total",
-                          (("replica", "w0"),))]
+            cur = parsed[("apex_serve_tokens_generated_total", ())]
             assert cur >= last, "scraped counter went backwards"
             last = cur
     finally:

@@ -427,7 +427,7 @@ def test_bwd_two_kernel_fallback_matches_fused(monkeypatch, features):
 
 
 def test_inherited_bwd_blocks_warns_once():
-    """Explicit forward blocks silently governed the backward (ADVICE r5)
+    """Explicit forward blocks silently governed the backward
     — now they warn, once, and only when the backward blocks are left to
     inherit; passing block_q_bwd/block_k_bwd stays silent."""
     import warnings

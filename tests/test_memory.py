@@ -202,17 +202,17 @@ def test_hbm_limit_table_lookup():
 
 def test_program_and_benchmark_agree_on_bf16_peak():
     """The program may not import ``benchmarks/``, so the peaks live
-    twice: ``profile.DEVICE_PEAKS`` for the program's own MFU gauge and
+    twice: ``attribution.DEVICE_PEAKS`` for the program's own MFU gauge and
     ``benchmarks/harness/peaks.py`` for the ledger's rooflines. Every
     ``device_kind`` both list has ONE bf16 peak (run from the root of a
     checkout, as ``tests/test_deepseek.py`` is)."""
-    from apex_tpu.monitor import profile
+    from apex_tpu.monitor import attribution
     from benchmarks.harness import peaks
     shared = [kind for kind in peaks.PEAKS
-              if profile.peak_flops_for(kind) is not None]
+              if attribution.peak_flops_for(kind) is not None]
     assert "TPU v5 lite" in shared
     for kind in shared:
-        assert profile.peak_flops_for(kind) == peaks.PEAKS[kind].bf16_flops
+        assert attribution.peak_flops_for(kind) == peaks.PEAKS[kind].bf16_flops
 
 
 def test_memory_sampler_thread_and_detach():

@@ -572,3 +572,29 @@ def test_pyprof_shim_reexports_monitor():
     assert pyprof.parse.op_stats_from_raw is monitor.xprof.op_stats_from_raw
     assert pyprof.prof.cost_analysis is monitor.trace.cost_analysis
     assert pyprof.nvtx.wrap is monitor.trace.wrap
+
+
+# ---------------------------------------------------------------------------
+# the tool side loads on first use: every listed name has a module behind it
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", monitor._LAZY_MODULES)
+def test_lazy_tool_module_resolves(name):
+    mod = getattr(monitor, name)
+    assert mod.__name__ == f"apex_tpu.monitor.{name}"
+
+
+@pytest.mark.parametrize("name", sorted(monitor._LAZY_NAMES))
+def test_lazy_tool_name_resolves_to_its_home(name):
+    home = getattr(monitor, monitor._LAZY_NAMES[name])
+    assert getattr(monitor, name) is getattr(home, name)
+
+
+def test_cli_has_no_verb_without_a_module(capsys):
+    """``fleet`` went with ``monitor/fleet.py``: the parser refuses it as
+    it refuses any unknown verb, before anything is imported."""
+    from apex_tpu.monitor.__main__ import main
+    with pytest.raises(SystemExit) as ei:
+        main(["fleet", "http://127.0.0.1:1/metrics", "--once"])
+    assert ei.value.code == 2
+    assert "invalid choice: 'fleet'" in capsys.readouterr().err

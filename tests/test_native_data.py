@@ -145,7 +145,7 @@ def test_dataloader_python_fallback_parity(monkeypatch):
 
 
 def test_transform_batch_validates_bounds():
-    """ADVICE r1: oversize crops / out-of-range indices must raise on both
+    """Oversize crops / out-of-range indices must raise on both
     the native and numpy paths (the C ABI would read out of bounds)."""
     images = np.zeros((4, 8, 8, 3), np.uint8)
     idx = np.arange(2)

@@ -1,4 +1,5 @@
-"""Per-module cost attribution (``apex_tpu.monitor.profile``).
+"""Per-module cost attribution (``apex_tpu.monitor.profile`` for the scopes,
+``apex_tpu.monitor.attribution`` for the tools that read them).
 
 Covers the tentpole contract: scope nesting (host path + name-stack
 tagging), analytic vs measured attribution on a tiny model, scan
@@ -16,6 +17,7 @@ import numpy as np
 import pytest
 
 from apex_tpu import monitor
+from apex_tpu.monitor import attribution as attr
 from apex_tpu.monitor import profile as prof
 from apex_tpu.monitor.report import aggregate, load_jsonl
 
@@ -69,7 +71,7 @@ def test_scoped_decorator():
 
 def test_analytic_attribution_charges_innermost_scope():
     g = jax.value_and_grad(_two_layer, argnums=(1, 2))
-    p = prof.analytic_profile(g, *_args())
+    p = attr.analytic_profile(g, *_args())
     rows = p["scopes"]
     assert set(rows) == {"layer1", "head"}
     # fwd+bwd dot flops: layer1 fwd 2*8*16*32 + bwd dx/dw each same
@@ -93,8 +95,8 @@ def test_analytic_scan_multiplies_trip_count():
         return c
 
     x = jnp.ones((8, 16))
-    p1 = prof.analytic_profile(once, x, w)
-    p4 = prof.analytic_profile(scanned, x, w)
+    p1 = attr.analytic_profile(once, x, w)
+    p4 = attr.analytic_profile(scanned, x, w)
     assert p4["scopes"]["blk"]["flops"] == 4 * p1["scopes"]["blk"]["flops"]
 
 
@@ -113,7 +115,7 @@ def test_analytic_collective_bytes_convention():
     fn = shard_map(body, mesh=mesh, in_specs=(P(),), out_specs=P(),
                    check_vma=False)
     x = jnp.ones((4, 8), jnp.float32)
-    p = prof.analytic_profile(fn, x)
+    p = attr.analytic_profile(fn, x)
     row = p["scopes"]["reduce"]
     # operand bytes, the trace-time collective-table convention
     assert row["collective_bytes"] == 4 * 8 * 4
@@ -126,7 +128,7 @@ def test_analytic_unscoped_row_and_coverage():
         with prof.scope("s"):
             return jnp.sum(jnp.tanh(y))
 
-    p = prof.analytic_profile(f, jnp.ones((8, 16)), jnp.ones((16, 16)))
+    p = attr.analytic_profile(f, jnp.ones((8, 16)), jnp.ones((16, 16)))
     assert prof.UNSCOPED in p["scopes"]
     assert 0.0 < p["flops_scope_coverage"] < 1.0
     assert p["unscoped"]["flops"] == p["scopes"][prof.UNSCOPED]["flops"]
@@ -139,18 +141,18 @@ def test_analytic_unscoped_row_and_coverage():
 def test_measured_profile_samples_scope_wall_time():
     g = jax.value_and_grad(_two_layer, argnums=(1, 2))
     rec = monitor.Recorder(name="t")
-    m = prof.measured_profile(g, *_args(), repeats=2, recorder=rec)
+    m = attr.measured_profile(g, *_args(), repeats=2, recorder=rec)
     assert set(m["scopes"]) == {"layer1", "head"}
     for row in m["scopes"].values():
         assert row["n"] == 2
         assert row["total_s"] > 0
     # measured and analytic agree on the scope vocabulary
-    a = prof.analytic_profile(g, *_args())
+    a = attr.analytic_profile(g, *_args())
     assert set(m["scopes"]) == set(a["scopes"])
 
 
 def test_measured_profile_does_not_leak_measure_flag():
-    prof.measured_profile(lambda x: _two_layer(x, *_args()[1:]),
+    attr.measured_profile(lambda x: _two_layer(x, *_args()[1:]),
                           _args()[0], repeats=1)
     rec = monitor.Recorder(name="after")
     with monitor.attached(rec):
@@ -213,7 +215,7 @@ def _tiny_gpt_step():
 
 def test_tiny_gpt_step_scope_coverage_at_least_90pct():
     step, args = _tiny_gpt_step()
-    p = prof.analytic_profile(step, *args)
+    p = attr.analytic_profile(step, *args)
     assert p["flops_scope_coverage"] >= 0.9, (
         p["flops_scope_coverage"], p["unscoped"])
     # the per-module vocabulary is present: TP layer names, the
@@ -290,7 +292,7 @@ def test_record_and_aggregate_profile_block():
     g = jax.value_and_grad(_two_layer, argnums=(1, 2))
     rec = monitor.Recorder(name="t")
     with monitor.attached(rec):
-        p = prof.analytic_profile(g, *_args(), record=True)
+        p = attr.analytic_profile(g, *_args(), record=True)
     buf = io.StringIO()
     rec.dump_jsonl(buf)
     buf.seek(0)
@@ -307,20 +309,20 @@ def test_record_and_aggregate_profile_block():
 
 def test_render_profile_table():
     g = jax.value_and_grad(_two_layer, argnums=(1, 2))
-    p = prof.analytic_profile(g, *_args())
-    table = prof.render_profile(p)
+    p = attr.analytic_profile(g, *_args())
+    table = attr.render_profile(p)
     assert "layer1" in table and "head" in table
     assert "coverage 100.0%" in table
 
 
 def test_kernel_vmem_note_reuses_tune_accounting():
     from apex_tpu.tune import vmem
-    note = prof.kernel_vmem_note("flash_attention_fwd", block_q=128,
+    note = attr.kernel_vmem_note("flash_attention_fwd", block_q=128,
                                  block_k=128, d=64, itemsize=2)
     assert note["vmem_bytes"] == vmem.vmem_estimate(
         "flash_attention_fwd", block_q=128, block_k=128, d=64, itemsize=2)
     assert note["vmem_budget_bytes"] == vmem.FLASH_VMEM_BUDGET
-    assert prof.kernel_vmem_note("nope") is None
+    assert attr.kernel_vmem_note("nope") is None
 
 
 def test_profile_cli_json(capsys):

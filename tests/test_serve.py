@@ -17,7 +17,6 @@ The acceptance contracts of PR 11, each asserted mechanically:
   apex_tpu.tune.
 """
 
-import importlib
 
 import numpy as np
 import pytest
@@ -27,15 +26,13 @@ import jax.numpy as jnp
 
 from apex_tpu import serve
 from apex_tpu.models.gpt import GPT, GPTConfig
-from apex_tpu.ops.flash_attention import (paged_attention_reference,
+from apex_tpu.ops import paged_attention as fa_mod
+from apex_tpu.ops.paged_attention import (paged_attention_reference,
                                           paged_decode_attention)
 from apex_tpu.serve import cache as cache_mod
 from apex_tpu.serve.scheduler import (RUNNING, WAITING, PageAllocator,
                                       Scheduler, Sequence)
 from apex_tpu.transformer import parallel_state as ps
-
-# ``apex_tpu.ops.flash_attention`` the attribute is the function
-fa_mod = importlib.import_module("apex_tpu.ops.flash_attention")
 
 
 # ---------------------------------------------------------------------------
@@ -767,11 +764,11 @@ def test_engine_tp2_parity(params):
 
 
 def test_serve_scopes_in_analytic_profile(params):
-    """monitor.profile attribution: the decode step's cost lands under
+    """monitor.attribution: the decode step's cost lands under
     the serve scope vocabulary (serve_decode / block_i / paged_attn /
     lm_head), so per-request attribution falls out of the existing
     analytic walk."""
-    from apex_tpu.monitor import profile as prof
+    from apex_tpu.monitor import attribution as prof
     from apex_tpu.serve import model as serve_model
     ccfg = cache_mod.CacheConfig(num_layers=CFG.num_layers, kv_heads=2,
                                  head_dim=16, num_pages=4, page_size=8)

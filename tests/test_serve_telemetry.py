@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from apex_tpu import monitor, serve
 from apex_tpu.models.gpt import GPT, GPTConfig
+from apex_tpu.monitor import attribution
 from apex_tpu.monitor import profile as profile_mod
 from apex_tpu.transformer import parallel_state as ps
 
@@ -496,22 +497,22 @@ def test_watchdog_healthy_serve_run_quiet_and_goodput_recorded(params):
 # ---------------------------------------------------------------------------
 
 def test_peak_flops_table_lookup():
-    assert profile_mod.peak_flops_for("TPU v5e") == 197e12
-    assert profile_mod.peak_flops_for("TPU v5 lite") == 197e12
-    assert profile_mod.peak_flops_for("TPU v4") == 275e12
-    assert profile_mod.peak_flops_for("some-future-asic") is None
+    assert attribution.peak_flops_for("TPU v5e") == 197e12
+    assert attribution.peak_flops_for("TPU v5 lite") == 197e12
+    assert attribution.peak_flops_for("TPU v4") == 275e12
+    assert attribution.peak_flops_for("some-future-asic") is None
     # the cpu row exists (nominal; platform-bound units gate its use)
-    assert profile_mod.peak_flops_for("cpu") == 5e10
+    assert attribution.peak_flops_for("cpu") == 5e10
 
 
 def test_mfu_arithmetic_and_guards():
-    row = profile_mod.mfu(1e9, 1e-3, peak=1e12)
+    row = attribution.mfu(1e9, 1e-3, peak=1e12)
     assert row["mfu_pct"] == 100.0
     assert row["achieved_flops_per_sec"] == 1e12
-    assert profile_mod.mfu(1e9, 0.0, peak=1e12) is None
-    assert profile_mod.mfu(0, 1.0, peak=1e12) is None
-    assert profile_mod.mfu(1e9, 1e-3, device_kind="unknown-chip") is None
-    half = profile_mod.mfu(1e9, 1e-3, peak=1e12, n_devices=2)
+    assert attribution.mfu(1e9, 0.0, peak=1e12) is None
+    assert attribution.mfu(0, 1.0, peak=1e12) is None
+    assert attribution.mfu(1e9, 1e-3, device_kind="unknown-chip") is None
+    half = attribution.mfu(1e9, 1e-3, peak=1e12, n_devices=2)
     assert half["mfu_pct"] == 50.0
 
 
@@ -522,7 +523,7 @@ def test_measured_mfu_records_gauges():
     x = jnp.ones((64, 64), jnp.float32)
     rec = monitor.Recorder(traced_hooks=False)
     with monitor.attached(rec):
-        row = profile_mod.measured_mfu(jax.jit(step), (x,), repeats=2,
+        row = attribution.measured_mfu(jax.jit(step), (x,), repeats=2,
                                        record=True)
     assert row["flops"] == 2 * 64 * 64 * 64
     assert row["step_time_s"] > 0

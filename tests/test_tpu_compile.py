@@ -18,7 +18,6 @@ and steer that rule by monkeypatch, here in the test.
 
 import dataclasses
 import functools
-import importlib
 import os
 import re
 
@@ -29,10 +28,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from apex_tpu import _compat
 from apex_tpu.amp import fp8 as fp8_mod
 from apex_tpu.lint.jaxpr_checks import iter_eqns
-from apex_tpu.ops.flash_attention import (_DECODE_BUFFER_BYTES,
-                                          flash_attention,
+from apex_tpu.ops.flash_attention import flash_attention
+from apex_tpu.ops.paged_attention import (_DECODE_BUFFER_BYTES,
                                           paged_decode_attention,
                                           paged_kv_write_rows)
 from apex_tpu.ops.fp8_matmul import fp8_dequant_matmul
@@ -447,11 +447,7 @@ def no_interpret(monkeypatch):
     """The kernels' backend rule, steered for a described chip."""
     def rule(interpret):
         return False if interpret is None else interpret
-    monkeypatch.setattr(
-        importlib.import_module("apex_tpu.ops.flash_attention"),
-        "_resolve_interpret", rule)
-    monkeypatch.setattr(importlib.import_module("apex_tpu.ops.lm_head_ce"),
-                        "_resolve_interpret", rule)
+    monkeypatch.setattr(_compat, "resolve_interpret", rule)
 
 
 def test_layers_call_one_lowered_flash_kernel_a_direction(chip, no_interpret):
@@ -765,7 +761,8 @@ def test_example_train_step_rings_its_reduce_scatters(topo, no_interpret,
 def _compile_serve(chip, smoke, sz, fp8_kv=False):
     """The ``ServeEngine``'s own decode and prefill programs, compiled for
     the described chip with the kernel paths the engine picks on a TPU
-    (named here: on this host its default is the XLA reference), pool
+    (named here: its defaults ask ``_compat.on_tpu``, which no fixture of
+    this file steers, so on this host they are the XLA reference), pool
     donated. Returns the engine and the two executables."""
     from apex_tpu import serve
     from apex_tpu.models import GPT
