@@ -7,10 +7,12 @@ rule its model names (``cfg.routing``, below), keeps the assignments whose
 expert lies in ``[first_expert, first_expert + n_local)``, lays them out by
 expert (``ops.grouped_matmul.tile_layout``), runs gate/up and down as two
 grouped matmuls, gathers each token's rows back weighted, and adds what
-every chip computes alike: the shared expert where the layer has one, and
-for the zero-compute slots a token chose (slots past ``n_routed_experts``:
-identity experts that hold no weights) the sum of their weights times the
-token's own input. No token is dropped, whatever the load.
+every chip computes alike: the shared expert where the layer has one
+(times ``sigmoid(x w)`` where its sub-tree holds an ``out_gate`` ``w`` ``[h,
+1]``), and for the zero-compute slots a token chose (slots past
+``n_routed_experts``: identity experts that hold no weights) the sum of
+their weights times the token's own input. No token is dropped, whatever
+the load.
 
 With ``n_local == n_routed_experts`` that is the whole layer. With fewer
 it is one chip's part of an expert-parallel layer: what the absent experts
@@ -347,8 +349,12 @@ def expert_layer(cfg, p, x, *, active=None, impl: str = "kernel",
         if "shared" in p:
             with _prof.scope("moe_shared"):
                 sh = p["shared"]
-                y = y + gated_mlp(x, sh["gate"], sh["up"],
-                                  sh["down"]).astype(jnp.float32)
+                s = gated_mlp(x, sh["gate"], sh["up"],
+                              sh["down"]).astype(jnp.float32)
+                if "out_gate" in sh:    # Qwen3-Next: a sigmoid gate a token
+                    s = s * jax.nn.sigmoid(
+                        jnp.dot(x, sh["out_gate"]).astype(jnp.float32))
+                y = y + s
         if cfg.zero_expert_num:
             with _prof.scope("moe_zero"):
                 zero = idx >= cfg.n_routed_experts

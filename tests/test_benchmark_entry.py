@@ -25,7 +25,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FILES = ("test_rehearsal.py", "test_deepseek.py", "test_longcat.py",
          "test_reference.py", "test_manifest.py", "test_flops_bytes.py",
          "test_span_reduce.py", "test_minicpm_sala.py",
-         "test_host_round.py", "test_mellum.py")
+         "test_host_round.py", "test_mellum.py", "test_qwen3_next.py")
 
 #: ``test_longcat.py``'s manifest test also asserts that the benchmark has
 #: SIX cells. PR 37 added the seventh and may not edit a benchmark file, so
@@ -40,7 +40,7 @@ DESELECT = {"test_longcat.py": (
 
 @pytest.fixture(scope="module")
 def children():
-    """All ten start together: under ``--dist loadfile`` this file is
+    """All eleven start together: under ``--dist loadfile`` this file is
     one worker's, and run one after another they are three minutes of
     it, the last of them after every other worker has finished."""
     # tier-1's XLA_FLAGS asks for eight devices; without it

@@ -300,6 +300,31 @@ def _moe_rows_tokens_train(chip):
                 _sds(chip, (), I32))
 
 
+
+def _flash_gqa_d256(chip):
+    """Cell 9's full-attention layer: 16 query heads over 2 key/value heads
+    of 256 at 16,384 tokens (the banded grids at 1,024-blocks under their
+    64 MB VMEM scope), forward, dk/dv over a group of 8 and dq."""
+    q = _sds(chip, (1, 16, 16384, 256), BF16)
+    k = _sds(chip, (1, 2, 16384, 256), BF16)
+    fn = _sum_grad(functools.partial(flash_attention, causal=True,
+                                     scale=256 ** -0.5, interpret=False),
+                   (0, 1, 2))
+    return fn, (q, k, k)
+
+
+def _gated_delta_scan(chip):
+    """Cell 9's gated delta rule: 32 heads of 128 x 128 over 16,384 tokens
+    in chunks of 128: what a chunk knows alone (4 chunks a program) and the
+    walk (8 heads a program, the states kept), forward and backward."""
+    from apex_tpu.ops import gated_delta
+    q = _sds(chip, (1, 32, 16384, 128), BF16)
+    g = _sds(chip, (1, 32, 16384), F32)
+    fn = _sum_grad(lambda *a: gated_delta.gated_delta_rule(
+        *a, interpret=False)[0], (0, 1, 2, 3, 4))
+    return fn, (q, q, q, g, g)
+
+
 CASES = {
     "flash_fwd_bwd_b8_s1024": _flash(1024, 8),
     "flash_fwd_bwd_b2_s4096": _flash(4096, 2),
@@ -325,6 +350,8 @@ CASES = {
     "moe_grouped_matmul_train_16x2304x1792": _grouped_matmul_train,
     "moe_rows_sorted_train_16384x2304": _moe_rows_sorted_train,
     "moe_rows_tokens_train_16384x8x2304": _moe_rows_tokens_train,
+    "flash_gqa_d256_fwd_bwd_s16384": _flash_gqa_d256,
+    "gated_delta_scan_fwd_bwd_h32_s16384": _gated_delta_scan,
 }
 
 
@@ -572,6 +599,74 @@ def test_mellum_train_step_compiles_for_v5e_under_15_gb(chip, no_interpret):
         assert _named(hlo, name) == n, name
     # no gather over the worst-case row buffers is left
     assert not re.search(r"fusion[.\d]* = bf16\[13(1072|5168),2304\]", hlo)
+
+
+def _qwen3_next_step(chip, **sizes):
+    """Cell 9's step (``benchmarks/configs/qwen3-next-80b-ep32-l4.json`` at
+    its published widths, 1 x 16,384 tokens, ``amp`` O2 + FusedAdam with the
+    family's ``keep_fp32`` through ``make_train_step(has_aux=True)``,
+    per-block recomputation), compiled for the described chip on shapes
+    only: ``(state's shapes, compiled)``. ``sizes``: other fields of the
+    model's description than the file's."""
+    from apex_tpu import amp
+    from apex_tpu.models import qwen3_next as qn
+    from apex_tpu.optimizers import FusedAdam
+    from benchmarks.families import qwen3_next as family
+    from benchmarks.harness import manifest
+    cfg = dataclasses.replace(family.model_config(manifest.load_config(
+        manifest.load_manifest(), "qwen3-next-80b-ep32-l4")), **sizes)
+    amp_model, opt = amp.initialize(
+        lambda p, i: qn.forward(cfg, p, i)[0], FusedAdam(lr=1e-6),
+        opt_level="O2", verbosity=0, keep_fp32_predicate=qn.keep_fp32)
+
+    def init_state(key):
+        params = amp_model.cast_params(qn.init_params(cfg, key))
+        return params, opt.init(params), \
+            opt._amp_stash.loss_scalers[0].state
+
+    state = jax.eval_shape(init_state, jax.random.PRNGKey(0))
+    step = amp.make_train_step(
+        lambda p, i, l: qn.loss(cfg, p, i, l), opt, has_aux=True)
+    ids = _sds(chip, (1, 16384), I32)
+    return state, step._jitted.lower(False, *_place(chip, state), ids,
+                                     ids).compile()
+
+
+def test_qwen3_next_block_kinds_compile_for_v5e(chip, no_interpret):
+    """Cell 9's step at its widths and two of its layers (one gated-delta,
+    one full): the scan's two forward kernels are there twice (the block
+    rebuilds the chunk states in its backward), its backward kernels once,
+    the full layer's forward flash kernel once (kept by name); the decay
+    leaves stay float32."""
+    state, compiled = _qwen3_next_step(chip, num_layers=2,
+                                       full_attention_interval=2)
+    gdn = state[0]["layer_0"]["gdn"]
+    assert gdn["A_log"].dtype == gdn["dt_bias"].dtype == F32
+    assert gdn["qkvz"].dtype == BF16
+    assert "attn" in state[0]["layer_1"]
+    hlo = compiled.as_text()
+    for name, n in (("apx_gdn_chunk_fwd", 2), ("apx_gdn_scan_fwd", 2),
+                    ("apx_gdn_chunk_bwd", 1), ("apx_gdn_scan_bwd", 1),
+                    ("apx_flash_attention_fwd", 1),
+                    ("apx_flash_attention_bwd", 2),
+                    ("apx_moe_grouped_matmul_dw", 4),
+                    ("apx_moe_grouped_matmul", 12)):
+        assert _named(hlo, name) == n, name
+
+
+@pytest.mark.slow
+def test_qwen3_next_train_step_compiles_for_v5e_under_15_gb(chip,
+                                                            no_interpret):
+    """The whole of cell 9's step fits: the state is 14 B a parameter
+    (424.3M: 5.94 GB), the step 13.57 GB (two minutes of compiling: by
+    hand, ``-m slow``)."""
+    state, compiled = _qwen3_next_step(chip)
+    n_params = sum(x.size for x in jax.tree.leaves(state[0]))
+    assert n_params == 424_340_544
+    assert sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves(state)) < 14.01 * n_params
+    assert _held_bytes(compiled) < 14.0e9
+    assert _named(compiled.as_text(), "apx_gdn_chunk_fwd") == 6
 
 
 def _flash_forwards(hlo):
